@@ -292,9 +292,22 @@ def _pipeline_section(depths=(1, 2, 4), steps: int = 24) -> None:
 
 
 def _sharded_section(devices: int) -> None:
-    """Run the sharded benchmark in a subprocess with forced host devices
-    (the XLA device count must be set before jax imports — this process
-    already initialized the single-device backend)."""
+    """Run the sharded benchmark. On a TPU host it runs in this process
+    over the attached chips (a chip belongs to one process, so a child
+    could not reach them), and is skipped on a one-chip host. On CPU it
+    runs in a subprocess with forced host devices (the XLA device count
+    must be set before jax initializes — this process already did)."""
+    import jax
+
+    if jax.default_backend() != "cpu":
+        n_dev = len(jax.devices())
+        if n_dev < 2:
+            print(f"kernels,sharded_section_skipped,{n_dev} "
+                  f"{jax.default_backend()} device(s): a sharded plan "
+                  f"needs at least 2")
+            return
+        _sharded_worker(min(devices, n_dev))
+        return
     env = dict(os.environ)
     env["XLA_FLAGS"] = (
         f"{env.get('XLA_FLAGS', '')} "

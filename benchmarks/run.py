@@ -37,6 +37,7 @@ from benchmarks import (
     bench_verify,
     roofline,
 )
+from repro.launch.compile_cache import use_compile_cache
 
 SECTIONS = [
     ("Fig 6 — OMAR vs NUM_PE", bench_omar.main),
@@ -44,8 +45,9 @@ SECTIONS = [
     ("Table 8 — STUF", bench_stuf.main),
     ("Table 9 / Fig 8 — energy", bench_energy.main),
     ("Sec 4.2.4 — architectural parameters", bench_arch_params.main),
-    # --devices 4: the sharded-plan section runs in a forced-host-device
-    # subprocess (per-shard imbalance + values/s scaling vs 1 device).
+    # --devices 4: the sharded-plan section (per-shard imbalance +
+    # values/s scaling vs 1 device) — in-process over the chips on a TPU
+    # host, in a forced-host-device subprocess on CPU.
     # --pipeline-depth: the async-serving streaming section (pipelined
     # steps/s vs synchronous at depths 1/2/4).
     ("Kernel schedule metrics",
@@ -119,6 +121,7 @@ def main(argv=None) -> None:
                     help="substring filter on section titles")
     args = ap.parse_args(argv)
     os.makedirs(args.out_dir, exist_ok=True)
+    print(f"[bench] compile cache: {use_compile_cache()}")
 
     failures = []
     for title, fn in SECTIONS:

@@ -27,6 +27,7 @@ import tempfile
 import threading
 import time
 
+import jax
 import numpy as np
 
 from repro.data.pipeline import SpGEMMValueStream
@@ -38,6 +39,9 @@ parser = argparse.ArgumentParser(description="multi-tenant gateway demo")
 parser.add_argument("--bursts", type=int, default=6)
 parser.add_argument("--burst-size", type=int, default=8)
 args = parser.parse_args()
+
+# The compiled Pallas kernel on a TPU host, its interpret mode on CPU.
+KERNEL = "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
 
 
 def pattern(seed, m, k, n, density=0.06):
@@ -64,9 +68,9 @@ gw = SpGEMMGateway(cache=cache, metrics=metrics, max_pipelines=2, depth=2,
 # with the token as the warm-path fast key — a re-register is a cache hit.
 plans = {
     "tenant0/attn": gw.register("tenant0/attn", *pattern(0, 96, 72, 80),
-                                tile=8, group=2, backend="jnp"),
+                                tile=8, group=2, backend=KERNEL),
     "tenant1/mlp": gw.register("tenant1/mlp", *pattern(4, 64, 64, 64, 0.08),
-                               tile=8, group=2, backend="jnp"),
+                               tile=8, group=2, backend=KERNEL),
 }
 streams = {
     tok: SpGEMMValueStream(p.a_pattern, p.b_pattern, seed=7 + i)

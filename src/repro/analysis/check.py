@@ -16,10 +16,10 @@ aliases are reported and pruned.
 Exit status is nonzero if any verification, lint, or audit fails, so CI
 can gate on it directly (the ``spgemm-verify`` job).
 
-``--shards N`` with more shards than visible devices re-executes itself
-with ``--xla_force_host_platform_device_count`` when jax has not been
-imported yet — the same forced-host-device convention as the sharded
-test jobs.
+``--shards N`` with more shards than visible CPU devices re-executes
+itself with ``--xla_force_host_platform_device_count`` when jax has not
+been imported yet — the same forced-host-device convention as the
+sharded test jobs. On an accelerator host it never re-executes.
 """
 from __future__ import annotations
 
@@ -33,14 +33,17 @@ __all__ = ["main"]
 
 
 def _ensure_devices(n: int) -> None:
-    """Force ``n`` visible host devices.
+    """Force ``n`` visible host devices when the platform is the CPU.
 
     jax reads ``XLA_FLAGS`` at backend initialization (lazily, at the
     first device query), so setting the env var here normally suffices
-    even though ``repro`` imports jax at module load. If the backend is
-    somehow already initialized with fewer devices, re-exec once with
-    the flag exported (the flag's presence in the inherited env stops a
-    second re-exec)."""
+    even though ``repro`` imports jax at module load. The flag sizes the
+    CPU platform only: on an accelerator host the devices are the chips,
+    and this process keeps them (a re-exec would have to reach chips this
+    process already holds), so a shortfall is reported by the sharded
+    check instead. If the CPU backend is somehow already initialized with
+    fewer devices, re-exec once with the flag exported (the flag's
+    presence in the inherited env stops a second re-exec)."""
     if n <= 1:
         return
     flags = os.environ.get("XLA_FLAGS", "")
@@ -51,6 +54,8 @@ def _ensure_devices(n: int) -> None:
     )
     import jax
 
+    if jax.default_backend() != "cpu":
+        return
     if len(jax.devices()) < n:
         os.execv(sys.executable,
                  [sys.executable, "-m", "repro.analysis.check",

@@ -6,7 +6,8 @@ Two layers, both execution-free:
 * :func:`lint_kernel_module` — an AST pass over the kernel module's
   source: the accumulation dtype must be fp32 everywhere (the
   ``preferred_element_type`` of the MXU dot and both ``out_shape``
-  dtypes), and the declared ``dimension_semantics`` must match what the
+  dtypes) and the dot's products full f32 (``precision=HIGHEST``), and
+  the declared ``dimension_semantics`` must match what the
   verifier proves — the triple axis is ``"arbitrary"`` (panels are
   revisited by contiguous runs of steps, a sequential dependence), the
   batch axis ``"parallel"`` (distinct elements write disjoint
@@ -22,7 +23,10 @@ Two layers, both execution-free:
   (:func:`repro.core.perfmodel.spgemm_grid_step_vmem`: one A block, one
   B block, one ``group*bm x bn`` output panel, double-buffered) fits the
   :data:`repro.core.perfmodel.TPU_VMEM_BYTES` budget — an oversized
-  (tile, group) is a lint finding *before* any compile attempt.
+  (tile, group) is a lint finding *before* any compile attempt — and the
+  scalar-prefetch schedule fits :data:`repro.core.perfmodel.
+  TPU_SMEM_BYTES` (``kernel.smem-schedule``: too many triples for one
+  ``pallas_call``).
 
 The module lint pins the *source*; the plan lint pins the *instance* —
 together they are the static half of the "Pallas on every numeric path"
@@ -107,15 +111,20 @@ def lint_kernel_module() -> List[Finding]:
     if kern is None:
         _err(findings, "kernel.accum-dtype", "_kernel not found")
     else:
-        pref = None
-        for node in ast.walk(kern):
-            if isinstance(node, ast.keyword) \
-                    and node.arg == "preferred_element_type":
-                pref = _dotted(node.value)
+        kw = {node.arg: _dotted(node.value) for node in ast.walk(kern)
+              if isinstance(node, ast.keyword)
+              and node.arg in ("preferred_element_type", "precision")}
+        pref = kw.get("preferred_element_type")
         if pref != "jnp.float32":
             _err(findings, "kernel.accum-dtype",
                  f"_kernel dot preferred_element_type is {pref!r}, "
                  f"expected jnp.float32")
+        # ... and full-f32 products: Mosaic's default precision for f32
+        # operands is a single bf16 pass.
+        if kw.get("precision") != "jax.lax.Precision.HIGHEST":
+            _err(findings, "kernel.accum-dtype",
+                 f"_kernel dot precision is {kw.get('precision')!r}, "
+                 f"expected jax.lax.Precision.HIGHEST (f32 products)")
     # ... and both pallas_call out_shape dtypes.
     for name in EXPECTED_SEMANTICS:
         fn = fns.get(name)
@@ -161,7 +170,12 @@ def lint_plan_kernel_specs(plan, bsz: int = 2) -> List[Finding]:
     # output panel, double-buffered by the Pallas pipeline) must fit
     # per-core VMEM. An oversized config fails at compile time at best
     # and silently spills at worst — catch it here, statically.
-    from repro.core.perfmodel import TPU_VMEM_BYTES, spgemm_grid_step_vmem
+    from repro.core.perfmodel import (
+        SCHEDULE_SMEM_BYTES_PER_TRIPLE,
+        TPU_SMEM_BYTES,
+        TPU_VMEM_BYTES,
+        spgemm_grid_step_vmem,
+    )
 
     dtype_bytes = int(np.dtype(np.float32).itemsize)
     step_bytes = spgemm_grid_step_vmem(
@@ -184,6 +198,21 @@ def lint_plan_kernel_specs(plan, bsz: int = 2) -> List[Finding]:
         _err(findings, "kernel.block-shape",
              f"B blocks {plan._b_shape} not tiled by (1, {bk}, {bn})")
     a_slot, b_slot, panel, sub_row, start, t_pad = _pad_for(plan)
+    # SMEM budget: the five int32 scalar-prefetch schedule arrays are
+    # resident in scalar memory for the whole grid, so the padded schedule
+    # length bounds what one pallas_call can take (one call per shard on a
+    # sharded plan, each padded to the largest shard).
+    shards = getattr(plan, "_shards", None)
+    t_call = max(sh.num_triples for sh in shards) if shards else t_pad
+    smem_bytes = SCHEDULE_SMEM_BYTES_PER_TRIPLE * t_call
+    if smem_bytes > TPU_SMEM_BYTES:
+        _err(findings, "kernel.smem-schedule",
+             f"scalar-prefetch schedule needs {smem_bytes} B of SMEM "
+             f"({t_call} triples x {SCHEDULE_SMEM_BYTES_PER_TRIPLE} B) but "
+             f"a TPU core has {TPU_SMEM_BYTES} B; the compiler refuses "
+             f"this kernel — use a larger tile or shard the plan over a "
+             f"mesh so each pallas_call holds at most "
+             f"{TPU_SMEM_BYTES // SCHEDULE_SMEM_BYTES_PER_TRIPLE} triples")
     t = np.arange(t_pad)
     # Single grid (t_pad,): index maps t -> (a_s[t],·,·) etc., out panel
     # space n_panels + 1 (the appended dummy).

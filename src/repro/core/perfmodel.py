@@ -28,12 +28,15 @@ __all__ = [
     "GPU_TITAN_X",
     "FPGA_ARRIA10",
     "TPU_V5E_CHIP",
+    "TPU_CHIPS_BY_KIND",
     "stuf",
     "runtime_from_stuf",
     "energy",
     "spgemm_schedule_traffic",
     "spgemm_grid_step_vmem",
     "TPU_VMEM_BYTES",
+    "TPU_SMEM_BYTES",
+    "SCHEDULE_SMEM_BYTES_PER_TRIPLE",
     "roofline_seconds",
     "PAPER_TABLE7_MS",
     "PAPER_TABLE8_STUF",
@@ -76,6 +79,9 @@ FPGA_ARRIA10 = DeviceModel(
 TPU_V5E_CHIP = DeviceModel(
     "tpu-v5e", 940e6, 197e12 / 940e6, 170.0, mem_bandwidth=819e9
 )
+# Chip models by ``jax.devices()[0].device_kind`` (v5e reports "TPU v5
+# lite"). A chip that is not listed has no model: callers raise.
+TPU_CHIPS_BY_KIND: Dict[str, DeviceModel] = {"TPU v5 lite": TPU_V5E_CHIP}
 
 
 def stuf(n_ops: float, device: DeviceModel, runtime_s: float) -> float:
@@ -128,6 +134,14 @@ def spgemm_schedule_traffic(
 # class; see the accelerator guide). The kernel lint budgets grid-step
 # working sets against this.
 TPU_VMEM_BYTES = 16 << 20
+
+# Scalar memory (SMEM) of one v5e core, where the kernel's five int32
+# scalar-prefetch schedule arrays live (20 B per padded triple). Measured
+# by compiling spgemm_scheduled_impl for a described v5e at 200,000
+# triples: "RESOURCE_EXHAUSTED ... Ran out of memory in memory space
+# smem. Used 3.83M of 1.00M" — so one pallas_call holds ~50k triples.
+TPU_SMEM_BYTES = 1 << 20
+SCHEDULE_SMEM_BYTES_PER_TRIPLE = 5 * 4
 
 
 def spgemm_grid_step_vmem(

@@ -367,7 +367,5 @@ def measure_chunk_knee(
         "chunk_sweep": chunk_sweep,
         "knee_bytes": int(knee),
         "suggested_policy_row": [int(knee), int(cache_bytes)],
-        "configured_policy_row": list(
-            _CHUNK_POLICY.get(device, _CHUNK_POLICY["cpu"])
-        ),
+        "configured_policy_row": list(_CHUNK_POLICY[device]),
     }

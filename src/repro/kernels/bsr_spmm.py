@@ -26,7 +26,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = ["bsr_spmm", "plan_bsr"]
 
@@ -110,7 +109,7 @@ def bsr_spmm(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(w_brow, w_bcol, flags, x, w_blocks)

@@ -20,7 +20,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = ["flash_attention"]
 
@@ -136,7 +135,7 @@ def flash_attention(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
     )(q, k, v)

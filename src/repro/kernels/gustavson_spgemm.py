@@ -43,7 +43,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = [
     "compact_csr_indptr_impl",
@@ -82,10 +81,15 @@ def _kernel(
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    # HIGHEST makes the MXU multiply in full f32. Mosaic's default for f32
+    # operands is one bf16 pass: on a TPU v5e a 128x128 f32 dot came out
+    # 2.6e-3 of max off the f64 product (1.2e-7 at HIGHEST), and
+    # 2cubes_sphere A @ A^T 2.8e-3 of max|C| off scipy's f32 product.
     prod = jnp.dot(
         a_ref[0].astype(jnp.float32),
         b_ref[0].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     row0 = sub_row_ref[t] * bm
     cur = o_ref[0, pl.dslice(row0, bm), :]
@@ -165,7 +169,7 @@ def spgemm_scheduled_impl(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_panels + 1, group * bm, bn), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
     )(a_slot, b_slot, panel, sub_row, start, a_blocks, b_blocks)
@@ -251,7 +255,7 @@ def spgemm_scheduled_batch_impl(
         # repro.analysis.verify.check_batch_races). The triple axis stays
         # "arbitrary": panels are revisited across contiguous runs of t,
         # a sequential accumulate dependence.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(a_slot, b_slot, panel, sub_row, start, a_blocks, b_blocks)

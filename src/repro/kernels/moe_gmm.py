@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 __all__ = ["moe_gmm"]
 
@@ -76,7 +75,7 @@ def moe_gmm(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, f), out_dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
         ),
     )(tile_expert, x, w)

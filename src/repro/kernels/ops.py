@@ -26,6 +26,7 @@ from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_gmm import moe_gmm
 from repro.sparse.formats import BCSR, BCSV, CSR
 from repro.spgemm.cache import PlanCache
+from repro.spgemm.executor import kernel_interpret
 from repro.spgemm.plan import SpGEMMPlan, resolve_backend, spgemm_plan
 
 __all__ = [
@@ -130,8 +131,7 @@ def sparse_dense_matmul(
             jnp.asarray(flags),
             n=n,
             tm=tm,
-            interpret=(backend == "pallas_interpret"
-                       or jax.default_backend() != "tpu"),
+            interpret=kernel_interpret(backend),
         )
     else:
         y = ref.bsr_spmm_ref(xp, jnp.asarray(blocks), brow, bcol, n)
@@ -158,8 +158,7 @@ def grouped_matmul(
             tm=tm,
             bd=min(512, d) if d % min(512, d) == 0 else d,
             bf=min(512, f) if f % min(512, f) == 0 else f,
-            interpret=(backend == "pallas_interpret"
-                       or jax.default_backend() != "tpu"),
+            interpret=kernel_interpret(backend),
         )
     return ref.moe_gmm_ref(x, w, np.asarray(tile_expert), tm)
 
@@ -186,8 +185,7 @@ def attention(
         return flash_attention(
             q, k, v,
             causal=causal, window=window, q_offset=q_offset,
-            interpret=(be == "pallas_interpret"
-                       or jax.default_backend() != "tpu"),
+            interpret=kernel_interpret(be),
         ).astype(q.dtype)
     return ref.flash_attention_ref(
         q, k, v, causal=causal, window=window, q_offset=q_offset

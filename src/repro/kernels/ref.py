@@ -41,10 +41,12 @@ def spgemm_scheduled_ref(
     """
     bm = a_blocks.shape[1]
     bn = b_blocks.shape[2]
+    # HIGHEST: full f32 products on a TPU too, like the Pallas kernel.
     prod = jnp.einsum(
         "tij,tjk->tik",
         a_blocks[jnp.asarray(a_slot)].astype(jnp.float32),
         b_blocks[jnp.asarray(b_slot)].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
     )  # [T, bm, bn]
     # Scatter-add each product at its flat panel-row offset: panels laid out
     # as [n_panels * group * bm, bn], triple t starts at row

@@ -13,28 +13,17 @@ expert parallelism on the fastest ICI dimension.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
-    "AXIS_TYPES_SUPPORTED",
     "make_auto_mesh",
     "make_production_mesh",
     "make_host_mesh",
     "make_shard_mesh",
 ]
-
-# jax grew explicit-sharding axis types (jax.sharding.AxisType +
-# jax.make_mesh(axis_types=...)) well after 0.4.x; run with whichever this
-# jax provides — same pattern as kernels/_compat.py's CompilerParams shim.
-_AxisType = getattr(jax.sharding, "AxisType", None)
-AXIS_TYPES_SUPPORTED = (
-    _AxisType is not None
-    and "axis_types" in inspect.signature(jax.make_mesh).parameters
-)
 
 
 def make_auto_mesh(
@@ -43,19 +32,15 @@ def make_auto_mesh(
     *,
     devices: Optional[Sequence] = None,
 ) -> Mesh:
-    """``jax.make_mesh`` with every axis pinned to ``AxisType.Auto`` when
-    this jax supports axis types, and the plain call otherwise.
-
-    On new jax, ``Auto`` is the pre-explicit-sharding behavior, so both
-    branches build the same mesh semantics; callers never touch
-    ``jax.sharding.AxisType`` directly (absent on older jax)."""
+    """``jax.make_mesh`` with every axis pinned to ``AxisType.Auto`` (the
+    pre-explicit-sharding semantics the sharding rules are written for)."""
     axes = tuple(axes)
     kwargs = {}
     if devices is not None:
         kwargs["devices"] = devices
-    if AXIS_TYPES_SUPPORTED:
-        kwargs["axis_types"] = (_AxisType.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), axes, **kwargs)
+    return jax.make_mesh(
+        tuple(shape), axes, axis_types=(AxisType.Auto,) * len(axes), **kwargs
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

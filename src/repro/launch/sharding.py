@@ -20,13 +20,6 @@ from typing import Dict, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # public API since jax 0.6; the experimental module is the old home
-    from jax import shard_map as _shard_map
-    _REPLICATION_KW = "check_vma"  # renamed from check_rep with the move
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REPLICATION_KW = "check_rep"
-
 __all__ = [
     "ShardingRules",
     "default_rules",
@@ -35,19 +28,8 @@ __all__ = [
     "logical_spec",
     "replicated_sharding",
     "shard",
-    "shard_map",
     "named_sharding",
 ]
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``shard_map`` (the jax.shard_map / experimental
-    rename + the ``check_rep`` -> ``check_vma`` kwarg rename, shimmed like
-    ``kernels/_compat.py``). ``check_vma=None`` keeps the jax default."""
-    kwargs = {} if check_vma is None else {_REPLICATION_KW: check_vma}
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
 
 Axis = Union[None, str, Tuple[str, ...]]
 

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.sharding import current_mesh, mesh_axis, shard, shard_map
+from repro.launch.sharding import current_mesh, mesh_axis, shard
 from repro.models.config import ModelConfig
 from repro.models.nn import Param
 from repro.models.mlp import _act
@@ -167,7 +167,7 @@ def moe_forward(
         P(batch_spec, None, None),  # x: batch over data, replicated on model
     )
     out_specs = (P(batch_spec, None, None), P())
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )(p["router"]["w"], ws, x)

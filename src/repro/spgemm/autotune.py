@@ -55,12 +55,13 @@ import dataclasses
 import math
 from typing import Callable, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.core.perfmodel import (
     CPU_XEON_E5_2637,
     DeviceModel,
-    TPU_V5E_CHIP,
+    TPU_CHIPS_BY_KIND,
     roofline_seconds,
     spgemm_schedule_traffic,
 )
@@ -148,9 +149,20 @@ class TunedConfig:
 
 
 def _model_device(backend: str) -> DeviceModel:
-    """The roofline device for candidate ranking. Ordering is all that
-    matters for pruning, so a representative CPU/TPU model suffices."""
-    return TPU_V5E_CHIP if backend == "pallas" else CPU_XEON_E5_2637
+    """The roofline device for candidate ranking. ``"pallas"`` plans rank
+    against the attached chip's model, looked up by ``device_kind`` (an
+    unknown kind is an error, not a default); ``jnp`` and
+    ``pallas_interpret`` plans run on the host and rank against the CPU
+    model."""
+    if backend != "pallas":
+        return CPU_XEON_E5_2637
+    kind = jax.devices()[0].device_kind
+    if kind not in TPU_CHIPS_BY_KIND:
+        raise ValueError(
+            f"no roofline model for device kind {kind!r}; known: "
+            f"{sorted(TPU_CHIPS_BY_KIND)}"
+        )
+    return TPU_CHIPS_BY_KIND[kind]
 
 
 def _tile_ladder(t: int, floor: int = 8, cap: int = 256) -> List[int]:

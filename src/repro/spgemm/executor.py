@@ -83,11 +83,7 @@ from repro.kernels.gustavson_spgemm import (
     spgemm_scheduled_batch_impl,
     spgemm_scheduled_impl,
 )
-from repro.launch.sharding import (
-    leading_sharding,
-    replicated_sharding,
-    shard_map,
-)
+from repro.launch.sharding import leading_sharding, replicated_sharding
 
 __all__ = [
     "CHUNK_BYTES_ENV",
@@ -98,10 +94,12 @@ __all__ = [
     "bind_batch_core",
     "bind_core",
     "kernel_batch_core",
+    "kernel_interpret",
     "kernel_core",
     "numeric_core",
     "numeric_core_batch",
     "resolve_chunk_bytes",
+    "shard_program",
 ]
 
 # Per-backend working-set budget for fusing batch elements into one device
@@ -142,9 +140,12 @@ def resolve_chunk_bytes(chunk_bytes: Optional[int] = None) -> Tuple[int, int]:
     an overridden budget so chunk sizing keeps its shape.
     """
     backend = jax.default_backend()
-    default_set, default_cache = _CHUNK_POLICY.get(
-        backend, _CHUNK_POLICY["cpu"]
-    )
+    if backend not in _CHUNK_POLICY:
+        raise ValueError(
+            f"no batch-fusion chunk policy for jax backend {backend!r}; "
+            f"known: {sorted(_CHUNK_POLICY)}"
+        )
+    default_set, default_cache = _CHUNK_POLICY[backend]
     env = os.environ.get(CHUNK_BYTES_ENV)
     if env is not None:
         per_set = int(env)
@@ -156,6 +157,23 @@ def resolve_chunk_bytes(chunk_bytes: Optional[int] = None) -> Tuple[int, int]:
         raise ValueError(f"chunk bytes must be >= 1, got {per_set}")
     scale = per_set / max(default_set, 1)
     return per_set, max(per_set, int(default_cache * scale))
+
+
+def kernel_interpret(backend: str) -> bool:
+    """The Pallas ``interpret`` flag for a kernel backend.
+
+    ``"pallas_interpret"`` runs the kernel bodies in interpret mode on any
+    platform; ``"pallas"`` compiles the real kernel and therefore needs a
+    TPU — asking for it elsewhere is an error, never a silent fall back to
+    the interpreter. ``"jnp"`` runs no Pallas kernel."""
+    if backend == "pallas" and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"backend='pallas' compiles the Pallas kernel for a TPU, but the "
+            f"jax default backend is {jax.default_backend()!r}; use "
+            f"backend='pallas_interpret' to run the kernel in interpret mode"
+        )
+    return backend == "pallas_interpret"
+
 
 _STATICS = ("n_panels", "group", "backend", "interpret")
 
@@ -406,9 +424,7 @@ class SpGEMMExecutor:
         self.group = schedule.group
         self.a_shape = tuple(a_shape)
         self.b_shape = tuple(b_shape)
-        self._interpret = (
-            backend == "pallas_interpret" or jax.default_backend() != "tpu"
-        )
+        self._interpret = kernel_interpret(backend)
         # Per-set f32 rows the batched schedule touches (panel accumulator
         # + einsum products) — the working-set basis for batch_chunk().
         bm = a_shape[1] if len(a_shape) == 3 else 0
@@ -610,6 +626,171 @@ class SpGEMMExecutor:
         return np.asarray(packed)
 
 
+def shard_program(
+    kind: str, *, mesh: Mesh, axis: str, backend: str, interpret: bool,
+    group: int, a_max: int, p_max: int, a_shape: Tuple[int, ...],
+    b_shape: Tuple[int, ...],
+):
+    """One jitted ``shard_map`` program of the sharded numeric phase.
+
+    ``kind`` names the program: the fused ``run`` / ``run_values`` /
+    ``batch_values`` / ``batch_blocks`` cores, or a pipeline stage
+    (``bind``, ``bind_batch``, ``kernel``, ``kernel_batch``, ``assemble``,
+    ``assemble_batch``). Every input is ``[n_shards, ...]`` stacked over
+    ``axis`` except the replicated B side. Built from shapes and the mesh
+    only, so it can be lowered for devices that are described rather than
+    attached; :class:`ShardedSpGEMMExecutor` caches one per kind."""
+    ax = axis
+    bm, bk = a_shape[1], a_shape[2]
+    # Every shard-local schedule is padded to (t_max, p_max), so on
+    # pallas backends each device runs its own scalar-prefetch grid
+    # over p_max + 1 panels — the same panel count the jnp reference
+    # produces, keeping stage outputs shape-identical across backends.
+    # The shard's own dummy triples target panel p_max (never gathered);
+    # the impl-level dummy p_max + 1 is stripped inside the call.
+
+    def sched_kernel(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
+                     strt):
+        if backend in ("pallas", "pallas_interpret"):
+            return spgemm_scheduled_impl(
+                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt,
+                n_panels=p_max + 1, group=group, interpret=interpret,
+            )
+        return ref.spgemm_scheduled_ref(
+            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
+            p_max + 1, group,
+        )
+
+    def sched_kernel_batch(a_blocks, b_blocks, a_slot, b_slot, panel,
+                           sub_row, strt, bsz):
+        return _run_schedule_batch(
+            a_blocks, b_blocks,
+            (a_slot, b_slot, panel, sub_row, strt)
+            if backend in ("pallas", "pallas_interpret")
+            else (a_slot, b_slot, panel, sub_row),
+            bsz, a_max, b_shape[0],
+            n_panels=p_max + 1, group=group, backend=backend,
+            interpret=interpret,
+        )
+
+    def kernel(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt,
+               gth):
+        panels = sched_kernel(
+            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt
+        )
+        return panels.reshape(-1)[gth]
+
+    def kernel_batch(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
+                     strt, gth, bsz):
+        panels = sched_kernel_batch(
+            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt, bsz
+        )
+        return panels.reshape(bsz, -1)[:, gth]
+
+    out = P(ax)
+    # pallas_call has no shard_map replication rule, so the programs
+    # that contain the kernel disable the replication check on pallas
+    # backends; bind/assemble programs keep the check on.
+    vma = True
+    if kind == "run":
+        def body(a_bl, b_bl, a_slot, b_slot, panel, sub_row, strt, gth):
+            return kernel(a_bl[0], b_bl, a_slot[0], b_slot[0], panel[0],
+                          sub_row[0], strt[0], gth[0])[None]
+        specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax))
+        vma = False
+    elif kind == "run_values":
+        def body(a_vals, b_vals, a_inv, b_inv, a_slot, b_slot, panel,
+                 sub_row, strt, gth):
+            a_bl = _bind(a_vals[0], a_inv[0], (a_max, bm, bk))
+            b_bl = _bind(b_vals, b_inv, b_shape)
+            return kernel(a_bl, b_bl, a_slot[0], b_slot[0], panel[0],
+                          sub_row[0], strt[0], gth[0])[None]
+        specs = (P(ax), P(), P(ax), P(), P(ax), P(ax), P(ax), P(ax),
+                 P(ax), P(ax))
+        vma = False
+    elif kind == "batch_values":
+        def body(a_vals, b_vals, a_inv, b_inv, a_slot, b_slot, panel,
+                 sub_row, strt, gth):
+            bsz = a_vals.shape[1]
+            a_bl = _bind_batch(a_vals[0], a_inv[0], (a_max, bm, bk))
+            b_bl = _bind_batch(b_vals, b_inv, b_shape)
+            return kernel_batch(a_bl, b_bl, a_slot[0], b_slot[0],
+                                panel[0], sub_row[0], strt[0], gth[0],
+                                bsz)[None]
+        specs = (P(ax), P(), P(ax), P(), P(ax), P(ax), P(ax), P(ax),
+                 P(ax), P(ax))
+        vma = False
+    elif kind == "batch_blocks":
+        def body(a_vals, b_vals, a_slot, b_slot, panel, sub_row, strt,
+                 gth):
+            bsz = a_vals.shape[1]
+            a_bl = a_vals[0].reshape((bsz * a_max, bm, bk))
+            b_bl = b_vals.reshape(
+                (bsz * b_shape[0],) + tuple(b_shape[1:]))
+            return kernel_batch(a_bl, b_bl, a_slot[0], b_slot[0],
+                                panel[0], sub_row[0], strt[0], gth[0],
+                                bsz)[None]
+        specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax))
+        vma = False
+    # -- stage-split kinds (the pipeline protocol): same ops as the
+    # fused bodies above, one shard_map program per stage so staging
+    # step s+1 dispatches independently of step s's kernel.
+    elif kind == "bind":
+        def body(a_vals, b_vals, a_inv, b_inv):
+            a_bl = _bind(a_vals[0], a_inv[0], (a_max, bm, bk))
+            b_bl = _bind(b_vals, b_inv, b_shape)
+            return a_bl[None], b_bl
+        specs = (P(ax), P(), P(ax), P())
+        out = (P(ax), P())
+    elif kind == "bind_batch":
+        def body(a_vals, b_vals, a_inv, b_inv):
+            bsz = a_vals.shape[1]
+            a_bl = _bind_batch(a_vals[0], a_inv[0], (a_max, bm, bk))
+            b_bl = _bind_batch(b_vals, b_inv, b_shape)
+            return (
+                a_bl.reshape((bsz, a_max, bm, bk))[None],
+                b_bl.reshape((bsz,) + tuple(b_shape)),
+            )
+        specs = (P(ax), P(), P(ax), P())
+        out = (P(ax), P())
+    elif kind == "kernel":
+        def body(a_bl, b_bl, a_slot, b_slot, panel, sub_row, strt):
+            return sched_kernel(
+                a_bl[0], b_bl, a_slot[0], b_slot[0], panel[0],
+                sub_row[0], strt[0],
+            )[None]
+        specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax))
+        vma = False
+    elif kind == "kernel_batch":
+        def body(a_bl, b_bl, a_slot, b_slot, panel, sub_row, strt):
+            bsz = a_bl.shape[1]
+            return sched_kernel_batch(
+                a_bl[0].reshape((bsz * a_max, bm, bk)),
+                b_bl.reshape((bsz * b_shape[0],) + tuple(b_shape[1:])),
+                a_slot[0], b_slot[0], panel[0], sub_row[0], strt[0],
+                bsz,
+            )[None]
+        specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax))
+        vma = False
+    elif kind == "assemble":
+        def body(panels, gth):
+            return panels[0].reshape(-1)[gth[0]][None]
+        specs = (P(ax), P(ax))
+    elif kind == "assemble_batch":
+        def body(panels, gth):
+            bsz = panels.shape[1] // (p_max + 1)
+            return panels[0].reshape(bsz, -1)[:, gth[0]][None]
+        specs = (P(ax), P(ax))
+    else:  # pragma: no cover - internal
+        raise ValueError(kind)
+
+    if backend not in ("pallas", "pallas_interpret"):
+        vma = True
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=specs, out_specs=out, check_vma=vma,
+    ))
+
+
 class ShardedSpGEMMExecutor:
     """Numeric phase of a mesh-partitioned plan: one ``shard_map`` call.
 
@@ -670,9 +851,7 @@ class ShardedSpGEMMExecutor:
         self.axis = axis
         self.a_shape = tuple(a_shape)
         self.b_shape = tuple(b_shape)
-        self._interpret = (
-            backend == "pallas_interpret" or jax.default_backend() != "tpu"
-        )
+        self._interpret = kernel_interpret(backend)
         self._chunk_policy = resolve_chunk_bytes(chunk_bytes)
         self._shards = list(shards)
         s0 = shards[0].schedule
@@ -834,162 +1013,14 @@ class ShardedSpGEMMExecutor:
     # -- shard_map cores ---------------------------------------------------
 
     def _fn(self, kind: str):
-        if kind in self._fns:
-            return self._fns[kind]
-        ax, group = self.axis, self.group
-        a_max, p_max = self._a_max, self._p_max
-        bm, bk = self.a_shape[1], self.a_shape[2]
-        b_shape = self.b_shape
-        backend, interpret = self.backend, self._interpret
-        # Every shard-local schedule is padded to (t_max, p_max), so on
-        # pallas backends each device runs its own scalar-prefetch grid
-        # over p_max + 1 panels — the same panel count the jnp reference
-        # produces, keeping stage outputs shape-identical across backends.
-        # The shard's own dummy triples target panel p_max (never gathered);
-        # the impl-level dummy p_max + 1 is stripped inside the call.
-
-        def sched_kernel(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
-                         strt):
-            if backend in ("pallas", "pallas_interpret"):
-                return spgemm_scheduled_impl(
-                    a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt,
-                    n_panels=p_max + 1, group=group, interpret=interpret,
-                )
-            return ref.spgemm_scheduled_ref(
-                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
-                p_max + 1, group,
+        fn = self._fns.get(kind)
+        if fn is None:
+            fn = self._fns[kind] = shard_program(
+                kind, mesh=self.mesh, axis=self.axis, backend=self.backend,
+                interpret=self._interpret, group=self.group,
+                a_max=self._a_max, p_max=self._p_max, a_shape=self.a_shape,
+                b_shape=self.b_shape,
             )
-
-        def sched_kernel_batch(a_blocks, b_blocks, a_slot, b_slot, panel,
-                               sub_row, strt, bsz):
-            return _run_schedule_batch(
-                a_blocks, b_blocks,
-                (a_slot, b_slot, panel, sub_row, strt)
-                if backend in ("pallas", "pallas_interpret")
-                else (a_slot, b_slot, panel, sub_row),
-                bsz, a_max, b_shape[0],
-                n_panels=p_max + 1, group=group, backend=backend,
-                interpret=interpret,
-            )
-
-        def kernel(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt,
-                   gth):
-            panels = sched_kernel(
-                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt
-            )
-            return panels.reshape(-1)[gth]
-
-        def kernel_batch(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
-                         strt, gth, bsz):
-            panels = sched_kernel_batch(
-                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt, bsz
-            )
-            return panels.reshape(bsz, -1)[:, gth]
-
-        out = P(ax)
-        # pallas_call has no shard_map replication rule, so the programs
-        # that contain the kernel disable the replication check on pallas
-        # backends; bind/assemble programs keep the jax default.
-        vma: Optional[bool] = None
-        if kind == "run":
-            def body(a_bl, b_bl, a_slot, b_slot, panel, sub_row, strt, gth):
-                return kernel(a_bl[0], b_bl, a_slot[0], b_slot[0], panel[0],
-                              sub_row[0], strt[0], gth[0])[None]
-            specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax))
-            vma = False
-        elif kind == "run_values":
-            def body(a_vals, b_vals, a_inv, b_inv, a_slot, b_slot, panel,
-                     sub_row, strt, gth):
-                a_bl = _bind(a_vals[0], a_inv[0], (a_max, bm, bk))
-                b_bl = _bind(b_vals, b_inv, b_shape)
-                return kernel(a_bl, b_bl, a_slot[0], b_slot[0], panel[0],
-                              sub_row[0], strt[0], gth[0])[None]
-            specs = (P(ax), P(), P(ax), P(), P(ax), P(ax), P(ax), P(ax),
-                     P(ax), P(ax))
-            vma = False
-        elif kind == "batch_values":
-            def body(a_vals, b_vals, a_inv, b_inv, a_slot, b_slot, panel,
-                     sub_row, strt, gth):
-                bsz = a_vals.shape[1]
-                a_bl = _bind_batch(a_vals[0], a_inv[0], (a_max, bm, bk))
-                b_bl = _bind_batch(b_vals, b_inv, b_shape)
-                return kernel_batch(a_bl, b_bl, a_slot[0], b_slot[0],
-                                    panel[0], sub_row[0], strt[0], gth[0],
-                                    bsz)[None]
-            specs = (P(ax), P(), P(ax), P(), P(ax), P(ax), P(ax), P(ax),
-                     P(ax), P(ax))
-            vma = False
-        elif kind == "batch_blocks":
-            def body(a_vals, b_vals, a_slot, b_slot, panel, sub_row, strt,
-                     gth):
-                bsz = a_vals.shape[1]
-                a_bl = a_vals[0].reshape((bsz * a_max, bm, bk))
-                b_bl = b_vals.reshape(
-                    (bsz * b_shape[0],) + tuple(b_shape[1:]))
-                return kernel_batch(a_bl, b_bl, a_slot[0], b_slot[0],
-                                    panel[0], sub_row[0], strt[0], gth[0],
-                                    bsz)[None]
-            specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax))
-            vma = False
-        # -- stage-split kinds (the pipeline protocol): same ops as the
-        # fused bodies above, one shard_map program per stage so staging
-        # step s+1 dispatches independently of step s's kernel.
-        elif kind == "bind":
-            def body(a_vals, b_vals, a_inv, b_inv):
-                a_bl = _bind(a_vals[0], a_inv[0], (a_max, bm, bk))
-                b_bl = _bind(b_vals, b_inv, b_shape)
-                return a_bl[None], b_bl
-            specs = (P(ax), P(), P(ax), P())
-            out = (P(ax), P())
-        elif kind == "bind_batch":
-            def body(a_vals, b_vals, a_inv, b_inv):
-                bsz = a_vals.shape[1]
-                a_bl = _bind_batch(a_vals[0], a_inv[0], (a_max, bm, bk))
-                b_bl = _bind_batch(b_vals, b_inv, b_shape)
-                return (
-                    a_bl.reshape((bsz, a_max, bm, bk))[None],
-                    b_bl.reshape((bsz,) + tuple(b_shape)),
-                )
-            specs = (P(ax), P(), P(ax), P())
-            out = (P(ax), P())
-        elif kind == "kernel":
-            def body(a_bl, b_bl, a_slot, b_slot, panel, sub_row, strt):
-                return sched_kernel(
-                    a_bl[0], b_bl, a_slot[0], b_slot[0], panel[0],
-                    sub_row[0], strt[0],
-                )[None]
-            specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax))
-            vma = False
-        elif kind == "kernel_batch":
-            def body(a_bl, b_bl, a_slot, b_slot, panel, sub_row, strt):
-                bsz = a_bl.shape[1]
-                return sched_kernel_batch(
-                    a_bl[0].reshape((bsz * a_max, bm, bk)),
-                    b_bl.reshape((bsz * b_shape[0],) + tuple(b_shape[1:])),
-                    a_slot[0], b_slot[0], panel[0], sub_row[0], strt[0],
-                    bsz,
-                )[None]
-            specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax))
-            vma = False
-        elif kind == "assemble":
-            def body(panels, gth):
-                return panels[0].reshape(-1)[gth[0]][None]
-            specs = (P(ax), P(ax))
-        elif kind == "assemble_batch":
-            def body(panels, gth):
-                bsz = panels.shape[1] // (p_max + 1)
-                return panels[0].reshape(bsz, -1)[:, gth[0]][None]
-            specs = (P(ax), P(ax))
-        else:  # pragma: no cover - internal
-            raise ValueError(kind)
-
-        if backend not in ("pallas", "pallas_interpret"):
-            vma = None
-        fn = jax.jit(shard_map(
-            body, mesh=self.mesh, in_specs=specs, out_specs=out,
-            check_vma=vma,
-        ))
-        self._fns[kind] = fn
         return fn
 
     # -- public surface (SpGEMMExecutor drop-in) ---------------------------

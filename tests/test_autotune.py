@@ -124,6 +124,29 @@ class TestModelRanking:
         assert all(all(8 <= d <= 256 for d in t) for t, _ in grid)
         assert all(g >= 1 for _, g in grid)
 
+    def test_model_device_by_backend_and_device_kind(self, monkeypatch):
+        import jax
+
+        from repro.core.perfmodel import TPU_V5E_CHIP
+        from repro.spgemm.autotune import _model_device
+
+        assert _model_device("jnp") is CPU_XEON_E5_2637
+        assert _model_device("pallas_interpret") is CPU_XEON_E5_2637
+        # "pallas" ranks against the attached chip: this host's CPU device
+        # has no chip model, which is an error rather than a default.
+        with pytest.raises(ValueError, match="device kind"):
+            _model_device("pallas")
+
+        class Chip:
+            def __init__(self, kind):
+                self.device_kind = kind
+
+        monkeypatch.setattr(jax, "devices", lambda: [Chip("TPU v5 lite")])
+        assert _model_device("pallas") is TPU_V5E_CHIP
+        monkeypatch.setattr(jax, "devices", lambda: [Chip("TPU v4")])
+        with pytest.raises(ValueError, match="TPU v4"):
+            _model_device("pallas")
+
 
 class TestSearch:
     """The fake timer steers the whole search deterministically."""

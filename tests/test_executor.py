@@ -277,8 +277,15 @@ class TestBatchChunkPolicy:
     def test_default_policy_is_backend_table(self):
         from repro.spgemm.executor import _CHUNK_POLICY, resolve_chunk_bytes
         import jax
-        assert resolve_chunk_bytes() == _CHUNK_POLICY.get(
-            jax.default_backend(), _CHUNK_POLICY["cpu"])
+        assert resolve_chunk_bytes() == _CHUNK_POLICY[jax.default_backend()]
+
+    def test_backend_without_policy_row_raises(self, monkeypatch):
+        import jax
+
+        from repro.spgemm.executor import resolve_chunk_bytes
+        monkeypatch.setattr(jax, "default_backend", lambda: "metal")
+        with pytest.raises(ValueError, match="metal"):
+            resolve_chunk_bytes()
 
     def test_constructor_arg_scales_chunk(self):
         from repro.spgemm.executor import SpGEMMExecutor

@@ -192,3 +192,31 @@ class TestShardedPallasDispatch:
         out = forced_devices(_SHARDED_CODE.format(n_shards=n_shards),
                              devices=8)
         assert f"SHARDED_PALLAS_OK {n_shards}" in out
+
+
+class TestCompiledPallasNeedsTpu:
+    """``backend="pallas"`` compiles the real kernel: off a TPU it raises
+    in every executor and kernel entry point — only ``"pallas_interpret"``
+    interprets."""
+
+    def test_single_device_executor_raises(self):
+        with pytest.raises(RuntimeError, match="pallas_interpret"):
+            _element_plan(backend="pallas")
+
+    def test_sharded_executor_raises(self):
+        from repro.launch.mesh import make_shard_mesh
+
+        a = _int_coo(96, 72, 0.06, 0).sum_duplicates()
+        b = _int_coo(72, 80, 0.06, 10).sum_duplicates()
+        with pytest.raises(RuntimeError, match="pallas_interpret"):
+            spgemm_plan(a, b, tile=8, group=2, backend="pallas",
+                        cache=PlanCache(), mesh=make_shard_mesh(1))
+
+    def test_seed_kernel_entry_points_raise(self):
+        from repro.kernels import ops
+
+        x = np.ones((8, 32), np.float32)
+        w = to_bcsv(random_block_sparse(32, 32, (8, 8), 0.5, seed=0),
+                    (8, 8), 1)
+        with pytest.raises(RuntimeError, match="pallas_interpret"):
+            ops.sparse_dense_matmul(x, w, backend="pallas", tm=8)

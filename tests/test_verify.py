@@ -144,6 +144,40 @@ print("SHARDED-VERIFY-OK")
                         cache=PlanCache(), validate="shallow")
 
 
+class TestKernelSmemBudget:
+    """The five int32 scalar-prefetch schedule arrays must fit SMEM."""
+
+    @staticmethod
+    def _dense_plan(n):
+        # Fully dense 8x8 blocks: (n/8)^3 triples.
+        rng = np.random.default_rng(n)
+        a = rng.standard_normal((n, n)).astype(np.float32)
+        return spgemm_plan(a, a, tile=8, group=2, backend="jnp",
+                           cache=PlanCache())
+
+    def test_schedule_under_smem_is_clean(self):
+        from repro.core.perfmodel import (
+            SCHEDULE_SMEM_BYTES_PER_TRIPLE, TPU_SMEM_BYTES)
+
+        plan = self._dense_plan(160)  # 8,000 triples
+        t = plan.schedule.num_triples
+        assert t * SCHEDULE_SMEM_BYTES_PER_TRIPLE < TPU_SMEM_BYTES
+        assert "kernel.smem-schedule" not in _checks(
+            lint_plan_kernel_specs(plan))
+
+    def test_schedule_over_smem_is_an_error(self):
+        from repro.core.perfmodel import (
+            SCHEDULE_SMEM_BYTES_PER_TRIPLE, TPU_SMEM_BYTES)
+
+        plan = self._dense_plan(320)  # 64,000 triples
+        t = plan.schedule.num_triples
+        assert t * SCHEDULE_SMEM_BYTES_PER_TRIPLE > TPU_SMEM_BYTES
+        smem = [f for f in lint_plan_kernel_specs(plan)
+                if f.check == "kernel.smem-schedule"]
+        assert len(smem) == 1 and smem[0].severity == "error"
+        assert f"{t} triples" in smem[0].message
+
+
 class TestScheduleFaultInjection:
     """Each mutation class must be detected by its check family."""
 
