@@ -58,6 +58,13 @@ pipeline of depth *d* is a *d*-deep operand buffer ring.
 stages run exactly the ops of the fused cores (shared helper functions,
 same schedules), so pipelined results are bitwise-equal to the
 synchronous path on both kernel backends.
+
+Every path runs the three stages through the same helpers (``_bind``,
+``_run_schedule``, ``_assemble`` and their batch forms), each under a
+``jax.named_scope`` — ``spgemm.bind``, ``spgemm.kernel``,
+``spgemm.assemble`` — so the device ops of a profiler trace carry their
+stage in the HLO ``op_name`` metadata, whichever jit ran them. Scopes are
+metadata only: no op, fusion or result depends on them.
 """
 from __future__ import annotations
 
@@ -184,16 +191,18 @@ def _run_schedule(
     """Dispatch the scheduled kernel. ``sched`` is the backend's device
     tuple: (a_slot, b_slot, panel, sub_row, start) padded for pallas,
     (a_slot, b_slot, panel, sub_row) raw for jnp."""
-    if backend in ("pallas", "pallas_interpret"):
-        a_slot, b_slot, panel, sub_row, start = sched
-        return spgemm_scheduled_impl(
-            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, start,
-            n_panels=n_panels, group=group, interpret=interpret,
+    with jax.named_scope("spgemm.kernel"):
+        if backend in ("pallas", "pallas_interpret"):
+            a_slot, b_slot, panel, sub_row, start = sched
+            return spgemm_scheduled_impl(
+                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, start,
+                n_panels=n_panels, group=group, interpret=interpret,
+            )
+        a_slot, b_slot, panel, sub_row = sched
+        return ref.spgemm_scheduled_ref(
+            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, n_panels,
+            group,
         )
-    a_slot, b_slot, panel, sub_row = sched
-    return ref.spgemm_scheduled_ref(
-        a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, n_panels, group
-    )
 
 
 def _invert_scatter(scatter: np.ndarray, size: int) -> np.ndarray:
@@ -210,8 +219,19 @@ def _invert_scatter(scatter: np.ndarray, size: int) -> np.ndarray:
 def _bind(vals, inv, shape):
     """Device-side value rebind as one gather through the precomputed
     scatter inverse. Positions outside the pattern read the zero pad."""
-    pad = jnp.concatenate([vals, jnp.zeros(1, vals.dtype)])
-    return pad[inv].reshape(shape)
+    with jax.named_scope("spgemm.bind"):
+        pad = jnp.concatenate([vals, jnp.zeros(1, vals.dtype)])
+        return pad[inv].reshape(shape)
+
+
+def _assemble(panels, gather, bsz=None):
+    """Output assembly: one static gather of the packed C values out of
+    the kernel's panels (``[bsz, nnz_c]`` per batch element when ``bsz``
+    is given)."""
+    with jax.named_scope("spgemm.assemble"):
+        if bsz is None:
+            return panels.reshape(-1)[gather]
+        return panels.reshape(bsz, -1)[:, gather]
 
 
 @functools.partial(jax.jit, static_argnames=_STATICS)
@@ -223,7 +243,7 @@ def numeric_core(
         a_blocks, b_blocks, sched,
         n_panels=n_panels, group=group, backend=backend, interpret=interpret,
     )
-    return panels.reshape(-1)[gather]
+    return _assemble(panels, gather)
 
 
 @functools.partial(
@@ -246,8 +266,9 @@ def _bind_batch(vals, inv, shape):
     """Batched value rebind: one gather per batch row through the shared
     scatter inverse, stacked along the slot axis."""
     bsz = vals.shape[0]
-    pad = jnp.concatenate([vals, jnp.zeros((bsz, 1), vals.dtype)], axis=1)
-    return pad[:, inv].reshape((bsz * shape[0],) + tuple(shape[1:]))
+    with jax.named_scope("spgemm.bind"):
+        pad = jnp.concatenate([vals, jnp.zeros((bsz, 1), vals.dtype)], axis=1)
+        return pad[:, inv].reshape((bsz * shape[0],) + tuple(shape[1:]))
 
 
 def _fold_schedule(sched, bsz, a_slots, b_slots, n_panels):
@@ -276,20 +297,21 @@ def _run_schedule_batch(
     offset-folded schedule through the scatter-add reference. Both return
     panels ``[bsz * n_panels, group*bm, bn]`` with identical per-element
     accumulation order."""
-    if backend in ("pallas", "pallas_interpret"):
-        a_slot, b_slot, panel, sub_row, start = sched
-        panels = spgemm_scheduled_batch_impl(
-            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, start,
-            bsz=bsz, n_panels=n_panels, group=group, interpret=interpret,
+    with jax.named_scope("spgemm.kernel"):
+        if backend in ("pallas", "pallas_interpret"):
+            a_slot, b_slot, panel, sub_row, start = sched
+            panels = spgemm_scheduled_batch_impl(
+                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, start,
+                bsz=bsz, n_panels=n_panels, group=group, interpret=interpret,
+            )
+            return panels.reshape((bsz * n_panels,) + panels.shape[2:])
+        a_slot_b, b_slot_b, panel_b, sub_row_b = _fold_schedule(
+            sched, bsz, a_slots, b_slots, n_panels
         )
-        return panels.reshape((bsz * n_panels,) + panels.shape[2:])
-    a_slot_b, b_slot_b, panel_b, sub_row_b = _fold_schedule(
-        sched, bsz, a_slots, b_slots, n_panels
-    )
-    return ref.spgemm_scheduled_ref(
-        a_blocks, b_blocks, a_slot_b, b_slot_b, panel_b, sub_row_b,
-        bsz * n_panels, group,
-    )
+        return ref.spgemm_scheduled_ref(
+            a_blocks, b_blocks, a_slot_b, b_slot_b, panel_b, sub_row_b,
+            bsz * n_panels, group,
+        )
 
 
 @functools.partial(
@@ -325,7 +347,7 @@ def numeric_core_batch(
         a_blocks, b_blocks, sched, bsz, a_shape[0], b_shape[0],
         n_panels=n_panels, group=group, backend=backend, interpret=interpret,
     )
-    return panels.reshape(bsz, -1)[:, gather]
+    return _assemble(panels, gather, bsz)
 
 
 # -- stage-split cores (the pipeline protocol's jits) ----------------------
@@ -380,14 +402,13 @@ def kernel_batch_core(
 @jax.jit
 def assemble_core(panels, gather):
     """Stage 3: output panels -> packed C values (one static gather)."""
-    return panels.reshape(-1)[gather]
+    return _assemble(panels, gather)
 
 
 @functools.partial(jax.jit, static_argnames=("n_panels",))
 def assemble_batch_core(panels, gather, *, n_panels):
     """Stage 3, batched: per-element gather through the shared map."""
-    bsz = panels.shape[0] // n_panels
-    return panels.reshape(bsz, -1)[:, gather]
+    return _assemble(panels, gather, panels.shape[0] // n_panels)
 
 
 class SpGEMMExecutor:
@@ -649,25 +670,23 @@ def shard_program(
     # The shard's own dummy triples target panel p_max (never gathered);
     # the impl-level dummy p_max + 1 is stripped inside the call.
 
+    def sched(a_slot, b_slot, panel, sub_row, strt):
+        if backend in ("pallas", "pallas_interpret"):
+            return a_slot, b_slot, panel, sub_row, strt
+        return a_slot, b_slot, panel, sub_row
+
     def sched_kernel(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
                      strt):
-        if backend in ("pallas", "pallas_interpret"):
-            return spgemm_scheduled_impl(
-                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt,
-                n_panels=p_max + 1, group=group, interpret=interpret,
-            )
-        return ref.spgemm_scheduled_ref(
-            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
-            p_max + 1, group,
+        return _run_schedule(
+            a_blocks, b_blocks, sched(a_slot, b_slot, panel, sub_row, strt),
+            n_panels=p_max + 1, group=group, backend=backend,
+            interpret=interpret,
         )
 
     def sched_kernel_batch(a_blocks, b_blocks, a_slot, b_slot, panel,
                            sub_row, strt, bsz):
         return _run_schedule_batch(
-            a_blocks, b_blocks,
-            (a_slot, b_slot, panel, sub_row, strt)
-            if backend in ("pallas", "pallas_interpret")
-            else (a_slot, b_slot, panel, sub_row),
+            a_blocks, b_blocks, sched(a_slot, b_slot, panel, sub_row, strt),
             bsz, a_max, b_shape[0],
             n_panels=p_max + 1, group=group, backend=backend,
             interpret=interpret,
@@ -678,14 +697,14 @@ def shard_program(
         panels = sched_kernel(
             a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt
         )
-        return panels.reshape(-1)[gth]
+        return _assemble(panels, gth)
 
     def kernel_batch(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
                      strt, gth, bsz):
         panels = sched_kernel_batch(
             a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt, bsz
         )
-        return panels.reshape(bsz, -1)[:, gth]
+        return _assemble(panels, gth, bsz)
 
     out = P(ax)
     # pallas_call has no shard_map replication rule, so the programs
@@ -774,12 +793,12 @@ def shard_program(
         vma = False
     elif kind == "assemble":
         def body(panels, gth):
-            return panels[0].reshape(-1)[gth[0]][None]
+            return _assemble(panels[0], gth[0])[None]
         specs = (P(ax), P(ax))
     elif kind == "assemble_batch":
         def body(panels, gth):
             bsz = panels.shape[1] // (p_max + 1)
-            return panels[0].reshape(bsz, -1)[:, gth[0]][None]
+            return _assemble(panels[0], gth[0], bsz)[None]
         specs = (P(ax), P(ax))
     else:  # pragma: no cover - internal
         raise ValueError(kind)

@@ -37,12 +37,19 @@ in flight the owning plan refuses buffer teardown
 (``release_values``/``release``/cache eviction raise) — close or drain
 the pipeline first. ``SpGEMMPipeline`` is a context manager; exiting
 discards anything still in flight.
+
+Spans: ``submit`` is one ``spgemm.submit`` span holding the step's
+``spgemm.dispatch``, and ``collect`` opens the step's ``spgemm.collect``;
+both carry the ticket index as ``step`` (``repro.spgemm.plan`` lists the
+spans).
 """
 from __future__ import annotations
 
 import threading
 import weakref
 from typing import Iterable, Iterator, Optional, Tuple, Union
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "PipelineFullError",
@@ -59,16 +66,19 @@ class _Prepared:
     """A validated, host-side-prepared submission (built by
     ``SpGEMMPlan._pipe_check``): execution mode, operands (cast host
     arrays for value modes, staged device arrays for block mode), batch
-    size (``None`` single-shot), and the executes-counter increment."""
+    size (``None`` single-shot), the executes-counter increment, and the
+    host arrays that block mode staged for it (``spgemm.dispatch`` counts
+    their bytes)."""
 
-    __slots__ = ("mode", "a", "b", "batch", "n_execs")
+    __slots__ = ("mode", "a", "b", "batch", "n_execs", "sent")
 
-    def __init__(self, mode, a, b, batch, n_execs):
+    def __init__(self, mode, a, b, batch, n_execs, sent=()):
         self.mode = mode
         self.a = a
         self.b = b
         self.batch = batch
         self.n_execs = n_execs
+        self.sent = sent
 
 
 class _Step:
@@ -203,7 +213,7 @@ class SpGEMMPipeline:
         *after* validation (dispatch or device errors) are stored on the
         ticket and re-raised by ``collect``.
         """
-        with self._lock:
+        with TraceAnnotation("spgemm.submit") as span, self._lock:
             if self._closed:
                 raise RuntimeError("pipeline is closed")
             if len(self._steps) >= self.depth:
@@ -216,9 +226,10 @@ class SpGEMMPipeline:
             self.plan._pipe_begin(prep.n_execs)
             index = self._next
             self._next += 1
+            span.set_metadata(step=index)
             step = _Step(prep)
             try:
-                step.packed = self.plan._pipe_dispatch(prep)
+                step.packed = self.plan._pipe_dispatch(prep, index)
             except Exception as e:
                 # Poisoned step: the slot is held (collect re-raises and
                 # frees it); other in-flight steps are unaffected.
@@ -257,7 +268,7 @@ class SpGEMMPipeline:
         try:
             if step.error is not None:
                 raise step.error
-            return self.plan._pipe_collect(step.prep, step.packed)
+            return self.plan._pipe_collect(step.prep, step.packed, index)
         finally:
             self.plan._pipe_end()
 

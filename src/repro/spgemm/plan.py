@@ -32,9 +32,25 @@ Output convention: C's CSR pattern is *structural* (every element of every
 structurally nonzero C block, trimmed to the true shape), so values that
 compute to exact zero are stored explicitly — the pattern is
 value-independent, which is what makes assembly jittable and batchable.
+
+Host spans: the served numeric path opens ``jax.profiler.TraceAnnotation``
+spans, so a profiler trace shows each product's host work on the same
+clock as its device ops. ``spgemm.execute`` and ``spgemm.execute_batch``
+(``spgemm.submit`` in the pipeline) hold ``spgemm.rebind`` (the host
+scatter of one operand's values into its block array, element plans),
+``spgemm.dispatch`` (H2D plus the jit enqueue) and ``spgemm.collect``,
+whose children are ``spgemm.wait`` (the device finishing) and
+``spgemm.d2h`` (the copy to host); the rest of ``spgemm.collect`` is the
+CSR wrap. A sharded plan's ``execute`` and ``execute_batch`` call an
+executor that blocks and brings C to the host itself: ``spgemm.run``
+covers that call in place of ``spgemm.dispatch``, ``spgemm.wait`` and
+``spgemm.d2h``. Every span carries ``step`` (the product's ``report.executes``,
+or its pipeline index) and its counts as arguments; with no profiler
+running a span records nothing and its arguments are never formatted.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -43,6 +59,7 @@ from typing import Callable, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh
 
 from repro.core.schedule import (
@@ -106,8 +123,7 @@ _REPORT_FIELDS = (
     "pattern_key", "pattern_token", "tile", "group", "backend", "shape",
     "nnz_a", "nnz_b", "nnzb_a", "nnzb_b", "nnzb_c", "num_triples",
     "n_panels", "b_fetches", "block_omar", "schedule_builds", "cache_hits",
-    "executes", "loads", "load_hits", "cache_stats", "config_source",
-    "tuned",
+    "executes", "loads", "load_hits", "config_source", "tuned",
 )
 
 
@@ -146,8 +162,6 @@ class PlanReport:
         # object (1 on a warm restart, 0 on a cold build)
         load_hits: int = 0,  # plan-cache lookups this plan satisfied from
         # the disk tier (the warm-restart acceptance counter)
-        cache_stats: Optional[dict] = None,  # serving PlanCache.stats()
-        # snapshot, refreshed on every spgemm_plan lookup for this plan
         pattern_token: Optional[str] = None,  # caller-supplied fast cache
         # key (spgemm_plan(..., pattern_token=)); echoed so serving
         # callers can audit which token a plan answers to
@@ -177,7 +191,6 @@ class PlanReport:
         self.executes = executes
         self.loads = loads
         self.load_hits = load_hits
-        self.cache_stats = cache_stats
         self.pattern_token = pattern_token
         self.config_source = config_source
         self.tuned = tuned
@@ -272,6 +285,9 @@ class SpGEMMPlan:
         self._b_shape = tuple(b_blocks.shape)
         self._a_dtype = a_blocks.dtype
         self._b_dtype = b_blocks.dtype
+        # Block slots a device bind fills per value set (element plans).
+        self._bind_slots = int(np.prod(self._a_shape)) + int(
+            np.prod(self._b_shape))
         self._m, self._n = out_shape
         self._group = schedule.group
         self._bm = int(a_blocks.shape[1]) if a_blocks.ndim == 3 else 0
@@ -774,70 +790,135 @@ class SpGEMMPlan:
         """Numeric phase only: C = A @ B for fresh values on the planned
         pattern. Zero schedule-construction work; the whole phase (kernel +
         output assembly) runs inside the executor's jit."""
-        packed = self._run_packed(a_vals, b_vals)
-        if packed is None:
-            return self._empty_csr()
-        return self._wrap_packed(np.asarray(packed))
+        with TraceAnnotation("spgemm.execute") as span:
+            packed, step = self._run_packed(a_vals, b_vals)
+            span.set_metadata(step=step)
+            if packed is None:
+                return self._empty_csr()
+            with TraceAnnotation("spgemm.collect", step=step):
+                return self._wrap_packed(self._fetch(packed, step))
+
+    def _fetch(self, packed, step: int, mode: Optional[str] = None):
+        """Packed C values to host: ``spgemm.wait`` until the device has
+        them, then ``spgemm.d2h`` for the copy — through the executor's
+        ``pipe_collect`` for a pipeline ``mode``, which also joins a
+        sharded plan's per-shard segments. The copy is queued before the
+        wait, where a lone ``np.asarray`` queues it too (jax's
+        ``ArrayImpl._value`` starts the copy, then blocks on it), so it
+        still follows the device's last op without a round trip through
+        this thread; ``spgemm.d2h`` is the part of it still to run. A
+        sharded plan's ``run`` results are on the host already."""
+        if not isinstance(packed, jax.Array):
+            return packed
+        packed.copy_to_host_async()
+        with TraceAnnotation("spgemm.wait", step=step):
+            jax.block_until_ready(packed)
+        with TraceAnnotation("spgemm.d2h", step=step,
+                             d2h_bytes=packed.nbytes):
+            if mode is None:
+                return np.asarray(packed)
+            return self._executor.pipe_collect(packed, mode=mode)
+
+    # The span over a call into the executor's run, run_values or
+    # run_batch, which return before the device is done: H2D plus the jit
+    # enqueue.
+    _RUN_SPAN = "spgemm.dispatch"
+
+    def _dispatch_span(self, step: int, *sent, bind_sets: int = 0,
+                       name: str = "spgemm.dispatch"):
+        """``name`` around one call into the executor: the H2D of the host
+        arrays ``sent`` plus the jit enqueue. Where the device binds
+        ``bind_sets`` value sets, ``sent`` is their values, and the span
+        counts the useful values the bind gathers and the block slots it
+        fills (their ratio is the block fill)."""
+        return TraceAnnotation(
+            name, step=step,
+            h2d_bytes=sum(x.nbytes for x in sent),
+            bind_values=sum(x.size for x in sent) if bind_sets else 0,
+            bind_slots=bind_sets * self._bind_slots,
+        )
 
     def _run_packed(self, a_vals=None, b_vals=None):
         """``execute``'s device core: dispatch the numeric phase and return
-        the packed C values *without* materializing them on host (``None``
-        for an empty plan). Single-device plans return a device array —
+        ``(packed C values, step)`` *without* materializing the values on
+        host (``None`` for an empty plan); ``step`` is the product's
+        ``report.executes``. Single-device plans return a device array —
         the handoff ``execute_chain`` keeps resident between stages;
         sharded plans return host arrays (their executor concatenates
         per-shard segments on host by design)."""
-        with self._lock:
-            self._check_released()
-            # report.nnz_* is read only on the scatter (element-plan) path:
-            # block plans keep their lazy count_nonzero report fields
-            # unresolved through executes.
-            if a_vals is not None:
-                self._a_blocks = self._rebind(
-                    a_vals, self._a_blocks, self._a_scatter,
-                    self.report.nnz_a if self._a_scatter is not None else 0,
-                    "a_vals", self._a_shape, self._a_dtype,
+        with contextlib.ExitStack() as spans:
+            with self._lock:
+                self._check_released()
+                # The count the increment below makes: this product's
+                # number.
+                step = self.report.executes + 1
+                # report.nnz_* is read only on the scatter (element-plan)
+                # path: block plans keep their lazy count_nonzero report
+                # fields unresolved through executes.
+                if a_vals is not None:
+                    with _rebind_span(step, "a", self._a_scatter,
+                                      self._a_shape):
+                        self._a_blocks = self._rebind(
+                            a_vals, self._a_blocks, self._a_scatter,
+                            self.report.nnz_a if self._a_scatter is not None
+                            else 0,
+                            "a_vals", self._a_shape, self._a_dtype,
+                        )
+                    self._a_dev = None
+                if b_vals is not None:
+                    with _rebind_span(step, "b", self._b_scatter,
+                                      self._b_shape):
+                        self._b_blocks = self._rebind(
+                            b_vals, self._b_blocks, self._b_scatter,
+                            self.report.nnz_b if self._b_scatter is not None
+                            else 0,
+                            "b_vals", self._b_shape, self._b_dtype,
+                        )
+                    self._b_dev = None
+                if self._a_blocks is None or self._b_blocks is None:
+                    raise ValueError(
+                        "plan values were released (release_values); pass "
+                        "a_vals/b_vals to execute"
+                    )
+                # Element plans called with both value vectors take the
+                # fully fused device path (rebind + kernel + assembly in one
+                # jit): only [nnz] vectors cross to device, not full packed
+                # blocks. The host rebind above still ran, so no-arg
+                # execute() stays current; device block staging is left to
+                # the next such call.
+                fused_values = (
+                    a_vals is not None and b_vals is not None
+                    and self._a_scatter is not None
+                    and self._b_scatter is not None
                 )
-                self._a_dev = None
-            if b_vals is not None:
-                self._b_blocks = self._rebind(
-                    b_vals, self._b_blocks, self._b_scatter,
-                    self.report.nnz_b if self._b_scatter is not None else 0,
-                    "b_vals", self._b_shape, self._b_dtype,
-                )
-                self._b_dev = None
-            if self._a_blocks is None or self._b_blocks is None:
-                raise ValueError(
-                    "plan values were released (release_values); pass "
-                    "a_vals/b_vals to execute"
-                )
-            # Element plans called with both value vectors take the fully
-            # fused device path (rebind + kernel + assembly in one jit):
-            # only [nnz] vectors cross to device, not full packed blocks.
-            # The host rebind above still ran, so no-arg execute() stays
-            # current; device block staging is left to the next such call.
-            fused_values = (
-                a_vals is not None and b_vals is not None
-                and self._a_scatter is not None
-                and self._b_scatter is not None
-            )
+                if fused_values:
+                    a_send = np.asarray(a_vals, dtype=self._a_dtype)
+                    b_send = np.asarray(b_vals, dtype=self._b_dtype)
+                    sent = (a_send, b_send)
+                else:  # the blocks not on the device yet go down below
+                    sent = tuple(host for host, dev in (
+                        (self._a_blocks, self._a_dev),
+                        (self._b_blocks, self._b_dev)) if dev is None)
+                if self._executor is not None:
+                    # Held past the lock, to the end of the executor's call.
+                    spans.enter_context(self._dispatch_span(
+                        step, *sent, bind_sets=int(fused_values),
+                        name=self._RUN_SPAN))
+                if not fused_values:
+                    if self._a_dev is None:
+                        self._a_dev = self._stage_a(self._a_blocks)
+                    if self._b_dev is None:
+                        self._b_dev = self._stage_b(self._b_blocks)
+                    # Snapshot under the lock so a concurrent rebind on
+                    # this shared plan cannot mix one caller's A with
+                    # another's B.
+                    a_dev, b_dev = self._a_dev, self._b_dev
+                self.report.executes += 1
+            if self._executor is None:
+                return None, step
             if fused_values:
-                a_send = np.asarray(a_vals, dtype=self._a_dtype)
-                b_send = np.asarray(b_vals, dtype=self._b_dtype)
-            else:
-                if self._a_dev is None:
-                    self._a_dev = self._stage_a(self._a_blocks)
-                if self._b_dev is None:
-                    self._b_dev = self._stage_b(self._b_blocks)
-                # Snapshot under the lock so a concurrent rebind on this
-                # shared plan cannot mix one caller's A with another's B.
-                a_dev, b_dev = self._a_dev, self._b_dev
-            self.report.executes += 1
-
-        if self._executor is None:
-            return None
-        if fused_values:
-            return self._executor.run_values(a_send, b_send)
-        return self._executor.run(a_dev, b_dev)
+                return self._executor.run_values(a_send, b_send), step
+            return self._executor.run(a_dev, b_dev), step
 
     def _run_packed_chained(self, c_packed):
         """Stage ``s >= 2`` of :func:`execute_chain`: the previous stage's
@@ -896,49 +977,58 @@ class SpGEMMPlan:
         Pallas grid, jnp plans the offset-folded scatter-add reference —
         both bitwise-equal to looping ``execute`` per element.
         """
-        a_vals = np.asarray(a_vals)
-        b_vals = np.asarray(b_vals)
-        rebind = self._a_scatter is not None and self._b_scatter is not None
-        want_a, want_b = self.value_shapes()
-        if a_vals.ndim != len(want_a) + 1 or a_vals.shape[1:] != want_a:
-            raise ValueError(
-                f"a_vals: expected [batch, {', '.join(map(str, want_a))}], "
-                f"got shape {a_vals.shape}"
-            )
-        if b_vals.shape[1:] != want_b or b_vals.shape[0] != a_vals.shape[0]:
-            raise ValueError(
-                f"b_vals: expected [{a_vals.shape[0]}, "
-                f"{', '.join(map(str, want_b))}], got shape {b_vals.shape}"
-            )
-        batch = int(a_vals.shape[0])
-        with self._lock:
-            self._check_released()
-            self.report.executes += batch
-        if batch == 0:
-            return []
-        if self._executor is None:
-            return [self._empty_csr() for _ in range(batch)]
-        # Match execute()'s rebind semantics: values are cast to the plan's
-        # packed dtype.
-        a_vals = a_vals.astype(self._a_dtype, copy=False)
-        b_vals = b_vals.astype(self._b_dtype, copy=False)
-        # Oversized batches are split so the device accumulator working set
-        # stays cache-resident (see SpGEMMExecutor.batch_chunk); each chunk
-        # is still one fused device call.
-        chunk = min(batch, self._executor.batch_chunk())
-        out = []
-        for lo in range(0, batch, chunk):
-            hi = min(lo + chunk, batch)
-            # Host slices go down as-is: the executor owns device layout
-            # (plain jnp.asarray unsharded; per-shard slicing + mesh
-            # placement on sharded plans).
-            packed = np.asarray(
-                self._executor.run_batch(
-                    a_vals[lo:hi], b_vals[lo:hi], rebind=rebind,
+        with TraceAnnotation("spgemm.execute_batch") as span:
+            a_vals = np.asarray(a_vals)
+            b_vals = np.asarray(b_vals)
+            rebind = (self._a_scatter is not None
+                      and self._b_scatter is not None)
+            want_a, want_b = self.value_shapes()
+            if a_vals.ndim != len(want_a) + 1 or a_vals.shape[1:] != want_a:
+                raise ValueError(
+                    f"a_vals: expected "
+                    f"[batch, {', '.join(map(str, want_a))}], "
+                    f"got shape {a_vals.shape}"
                 )
-            )
-            out.extend(self._wrap_packed(packed[i]) for i in range(hi - lo))
-        return out
+            if (b_vals.shape[1:] != want_b
+                    or b_vals.shape[0] != a_vals.shape[0]):
+                raise ValueError(
+                    f"b_vals: expected [{a_vals.shape[0]}, "
+                    f"{', '.join(map(str, want_b))}], got shape {b_vals.shape}"
+                )
+            batch = int(a_vals.shape[0])
+            with self._lock:
+                self._check_released()
+                step = self.report.executes + 1  # the batch's first value set
+                self.report.executes += batch
+            span.set_metadata(step=step, batch=batch)
+            if batch == 0:
+                return []
+            if self._executor is None:
+                return [self._empty_csr() for _ in range(batch)]
+            # Match execute()'s rebind semantics: values are cast to the
+            # plan's packed dtype.
+            a_vals = a_vals.astype(self._a_dtype, copy=False)
+            b_vals = b_vals.astype(self._b_dtype, copy=False)
+            # Oversized batches are split so the device accumulator working
+            # set stays cache-resident (see SpGEMMExecutor.batch_chunk); each
+            # chunk is still one fused device call.
+            chunk = min(batch, self._executor.batch_chunk())
+            out = []
+            for lo in range(0, batch, chunk):
+                hi = min(lo + chunk, batch)
+                # Host slices go down as-is: the executor owns device layout
+                # (plain jnp.asarray unsharded; per-shard slicing + mesh
+                # placement on sharded plans).
+                a, b = a_vals[lo:hi], b_vals[lo:hi]
+                with self._dispatch_span(
+                        step, a, b, bind_sets=hi - lo if rebind else 0,
+                        name=self._RUN_SPAN):
+                    packed = self._executor.run_batch(a, b, rebind=rebind)
+                with TraceAnnotation("spgemm.collect", step=step):
+                    packed = self._fetch(packed, step)
+                    out.extend(self._wrap_packed(packed[i])
+                               for i in range(hi - lo))
+            return out
 
     # -- async serving (the stage-split pipeline surface) ------------------
 
@@ -1020,13 +1110,17 @@ class SpGEMMPlan:
                         "plan values were released (release_values); pass "
                         "a_vals/b_vals to submit"
                     )
+                sent = ()
                 if self._executor is not None:
+                    sent = tuple(host for host, dev in (
+                        (self._a_blocks, self._a_dev),
+                        (self._b_blocks, self._b_dev)) if dev is None)
                     if self._a_dev is None:
                         self._a_dev = self._stage_a(self._a_blocks)
                     if self._b_dev is None:
                         self._b_dev = self._stage_b(self._b_blocks)
                 return _Prepared("blocks", self._a_dev, self._b_dev,
-                                 None, 1)
+                                 None, 1, sent)
         with self._lock:
             self._check_released()
         a_vals = np.asarray(a_vals)
@@ -1054,7 +1148,7 @@ class SpGEMMPlan:
             # executor's device layout) so the caller may reuse buffers.
             return _Prepared(
                 "blocks", self._stage_a(a_vals), self._stage_b(b_vals),
-                None, 1,
+                None, 1, (a_vals, b_vals),
             )
         mode = "batch_values" if rebind else "batch_blocks"
         batch = int(a_vals.shape[0])
@@ -1070,20 +1164,23 @@ class SpGEMMPlan:
         with self._lock:
             self._inflight -= 1
 
-    def _pipe_dispatch(self, prep: _Prepared):
+    def _pipe_dispatch(self, prep: _Prepared, step: int):
         """Dispatch one prepared step's device work (stage -> kernel ->
         assemble) without blocking; returns the packed device result (a
-        list of per-chunk results for batch submissions)."""
+        list of per-chunk results for batch submissions). ``step`` is the
+        pipeline index its spans carry."""
         if self._executor is None or (prep.batch == 0):
             return None
         ex = self._executor
         if prep.batch is None:
-            staged = (
-                (prep.a, prep.b) if prep.mode == "blocks"
-                else ex.pipe_stage(prep.a, prep.b, mode=prep.mode)
-            )
-            panels = ex.pipe_kernel(staged, mode="single")
-            return ex.pipe_assemble(panels, mode="single")
+            if prep.mode == "blocks":  # staged by _pipe_check
+                with self._dispatch_span(step, *prep.sent):
+                    panels = ex.pipe_kernel((prep.a, prep.b), mode="single")
+                    return ex.pipe_assemble(panels, mode="single")
+            with self._dispatch_span(step, prep.a, prep.b, bind_sets=1):
+                staged = ex.pipe_stage(prep.a, prep.b, mode=prep.mode)
+                panels = ex.pipe_kernel(staged, mode="single")
+                return ex.pipe_assemble(panels, mode="single")
         # Batch submissions chunk exactly like execute_batch, so the
         # device accumulator working set stays cache-resident; each chunk
         # is dispatched back-to-back (still zero host blocking).
@@ -1091,28 +1188,32 @@ class SpGEMMPlan:
         out = []
         for lo in range(0, prep.batch, chunk):
             hi = min(lo + chunk, prep.batch)
-            staged = ex.pipe_stage(
-                prep.a[lo:hi], prep.b[lo:hi], mode=prep.mode)
-            panels = ex.pipe_kernel(staged, mode="batch")
-            out.append(ex.pipe_assemble(panels, mode="batch"))
+            a, b = prep.a[lo:hi], prep.b[lo:hi]
+            bind = hi - lo if prep.mode == "batch_values" else 0
+            with self._dispatch_span(step, a, b, bind_sets=bind):
+                staged = ex.pipe_stage(a, b, mode=prep.mode)
+                panels = ex.pipe_kernel(staged, mode="batch")
+                out.append(ex.pipe_assemble(panels, mode="batch"))
         return out
 
-    def _pipe_collect(self, prep: _Prepared, packed):
+    def _pipe_collect(self, prep: _Prepared, packed, step: int):
         """Materialize one dispatched step on host (the blocking D2H) and
-        wrap it in the plan's precomputed CSR structure."""
-        if prep.batch is None:
+        wrap it in the plan's precomputed CSR structure, inside the
+        step's ``spgemm.collect`` span."""
+        with TraceAnnotation("spgemm.collect", step=step):
+            if prep.batch is None:
+                if self._executor is None:
+                    return self._empty_csr()
+                return self._wrap_packed(
+                    self._fetch(packed, step, mode="single"))
             if self._executor is None:
-                return self._empty_csr()
-            return self._wrap_packed(
-                self._executor.pipe_collect(packed, mode="single"))
-        if self._executor is None:
-            return [self._empty_csr() for _ in range(prep.batch)]
-        out = []
-        for chunk_packed in (packed or ()):
-            arr = self._executor.pipe_collect(chunk_packed, mode="batch")
-            out.extend(self._wrap_packed(arr[i])
-                       for i in range(arr.shape[0]))
-        return out
+                return [self._empty_csr() for _ in range(prep.batch)]
+            out = []
+            for chunk_packed in (packed or ()):
+                arr = self._fetch(chunk_packed, step, mode="batch")
+                out.extend(self._wrap_packed(arr[i])
+                           for i in range(arr.shape[0]))
+            return out
 
     # -- teardown ----------------------------------------------------------
 
@@ -1310,6 +1411,10 @@ class ShardedSpGEMMPlan(SpGEMMPlan):
             a_val_bounds=a_val_bounds,
         )
 
+    # Its executor's run, run_values and run_batch block: H2D, the
+    # shard_map program, its wait, and the D2H with the host concat.
+    _RUN_SPAN = "spgemm.run"
+
     def _stage_a(self, blocks: np.ndarray):
         if self._executor is None:  # empty plan: nothing to lay out
             return jnp.array(blocks, copy=True)
@@ -1400,6 +1505,18 @@ def _value_dtype(x):
     if isinstance(x, np.ndarray):
         return x.dtype
     return None
+
+
+def _rebind_span(step: int, operand: str, scatter, shape):
+    """``spgemm.rebind``: the host scatter of one operand's values into
+    its block array, on element plans (``scatter`` set); nothing on block
+    plans, whose packed values are taken as they are."""
+    if scatter is None:
+        return contextlib.nullcontext()
+    return TraceAnnotation(
+        "spgemm.rebind", step=step, operand=operand,
+        values=int(scatter.shape[0]), slots=int(np.prod(shape)),
+    )
 
 
 def _staged_nnz(plan: "SpGEMMPlan", attr: str, field: str):
@@ -1684,7 +1801,6 @@ def spgemm_plan(
             if fresh:
                 # Values were bound by the loader; nothing to rebind.
                 plan.report.pattern_token = str(pattern_token)
-                plan.report.cache_stats = cache.stats()
                 return plan
             if plan is not None:
                 dt_a, dt_b = _value_dtype(a), _value_dtype(b)
@@ -1757,7 +1873,6 @@ def spgemm_plan(
                         f"{type(a).__name__}/{type(b).__name__} — drop "
                         f"pattern_token to take the full conversion path"
                     )
-            plan.report.cache_stats = cache.stats()
             if validate == "deep":
                 _deep_verify(plan)
             return plan
@@ -1793,7 +1908,6 @@ def spgemm_plan(
                 output=output),
         )
         bind_token(plan, key)
-        plan.report.cache_stats = cache.stats()
         if hit:
             with plan._lock:
                 plan.report.cache_hits += 1
@@ -1865,7 +1979,6 @@ def spgemm_plan(
 
     plan, hit = cache.get_or_build(key, build, loader=load)
     bind_token(plan, key)
-    plan.report.cache_stats = cache.stats()
     if hit:
         with plan._lock:
             plan.report.cache_hits += 1
@@ -2039,7 +2152,7 @@ def execute_chain(plans, a_vals=None, b_vals=None) -> CSR:
             raise ValueError("a chain needs at least one plan")
         for s, (p, q) in enumerate(zip(plans, plans[1:])):
             _check_chain_link(p, q, s)
-    packed = plans[0]._run_packed(a_vals, b_vals)
+    packed, _ = plans[0]._run_packed(a_vals, b_vals)
     for stage in plans[1:]:
         packed = stage._run_packed_chained(packed)
     last = plans[-1]
@@ -2147,7 +2260,6 @@ def plan_from_structural_pattern(
         return plan
 
     plan, hit = cache.get_or_build(key, build, loader=load)
-    plan.report.cache_stats = cache.stats()
     if hit:
         with plan._lock:
             plan.report.cache_hits += 1

@@ -341,7 +341,7 @@ class TestChain:
         p1 = spgemm_plan(a, b, tile=8, group=2, backend="jnp", cache=cache,
                          output="compact")
         chain = p1.then(c, cache=cache)
-        packed = chain.plans[0]._run_packed(None, None)
+        packed, _ = chain.plans[0]._run_packed(None, None)
         assert isinstance(packed, jax.Array)  # never left the device
         packed2 = chain.plans[1]._run_packed_chained(packed)
         assert isinstance(packed2, jax.Array)

@@ -138,3 +138,42 @@ def test_per_shard_program_compiles(kind, mesh4, no_compile_cache):
         a_shape=(NNZB, TILE, TILE), b_shape=(NNZB, TILE, TILE),
     )
     _check(fn.lower(*_shard_args(kind, mesh4)).compile())
+
+
+def _scopes(compiled):
+    """The stage scopes named in the compiled program's op_name metadata."""
+    text = compiled.as_text()
+    return {s for s in ("spgemm.bind", "spgemm.kernel", "spgemm.assemble")
+            if f"/{s}/" in text}
+
+
+@pytest.mark.parametrize("program", ["fused", "sharded"])
+def test_compiled_programs_keep_the_stage_scopes(program, one_chip, mesh4,
+                                                 no_compile_cache):
+    """The chip's compiler keeps the executor's named scopes in the HLO
+    ``op_name`` metadata, where the profiler reads each device op's stage
+    (``bench/spans.py``). Small shapes: only the metadata is checked."""
+    t, nnzb, nnz, nnz_c = 64, 8, 1_000, 4_000
+    shape, flat = (nnzb, TILE, TILE), nnzb * TILE * TILE
+    if program == "fused":
+        compiled = numeric_core_values.lower(
+            _sds((nnz,), F32, one_chip), _sds((nnz,), F32, one_chip),
+            _sds((flat,), I32, one_chip), _sds((flat,), I32, one_chip),
+            [_sds((t,), I32, one_chip)] * 5, _sds((nnz_c,), I32, one_chip),
+            a_shape=shape, b_shape=shape, n_panels=4, group=GROUP,
+            backend="pallas", interpret=False,
+        ).compile()
+    else:
+        sep, rep = NamedSharding(mesh4, P("shard")), NamedSharding(mesh4, P())
+        fn = shard_program(
+            "run_values", mesh=mesh4, axis="shard", backend="pallas",
+            interpret=False, group=GROUP, a_max=nnzb, p_max=4,
+            a_shape=shape, b_shape=shape,
+        )
+        compiled = fn.lower(
+            _sds((4, nnz), F32, sep), _sds((nnz,), F32, rep),
+            _sds((4, flat), I32, sep), _sds((flat,), I32, rep),
+            *[_sds((4, t), I32, sep)] * 5, _sds((4, nnz_c), I32, sep),
+        ).compile()
+    assert _scopes(compiled) == {"spgemm.bind", "spgemm.kernel",
+                                 "spgemm.assemble"}
