@@ -279,7 +279,7 @@ def run_sharded(n_shards: int, scale: float = 1.0, backend: str = "pallas",
     ex = plan._executor
     want = [int(d.id) for d in mesh.devices.ravel()]
     constants = {"sched": ex._sched[0], "gather": ex._gather,
-                 "a_inv": ex._a_inv}
+                 "a_scatter": ex._a_scatter}
     staged = ex.pipe_stage(*stream.values_at(0), mode="values")
     packed = ex.pipe_assemble(ex.pipe_kernel(staged, mode="single"),
                               mode="single")

@@ -9,8 +9,9 @@ that pipeline as a *functional core*: a pure, jittable function
 chaining three device-side stages under one ``jax.jit``:
 
 1. **value rebind** (optional, element plans): scatter fresh ``[nnz]`` value
-   vectors into the packed block arrays at the plan's precomputed scatter
-   indices;
+   vectors into zeroed packed block arrays at the plan's precomputed flat
+   scatter indices — one indexed element per value, so the work follows
+   nnz, not the block arrays' slot count;
 2. **the scheduled kernel**: the Pallas block-Gustavson kernel
    (:func:`repro.kernels.gustavson_spgemm.spgemm_scheduled_impl`) or the
    pure-jnp path (:func:`repro.kernels.ref.spgemm_scheduled_ref`);
@@ -28,7 +29,8 @@ jnp an offset-folded schedule so XLA sees the same op shapes as the
 single-set path. The jitted entry points are module-level with static
 config arguments, so plans sharing shapes share executables;
 :class:`SpGEMMExecutor` wraps them with a plan's device-resident constants
-(schedule arrays, scatter indices, gather map — shipped to device once).
+(schedule arrays, ``[nnz]`` scatter indices, gather map — shipped to
+device once).
 
 The same shape-static property is what makes the phase meshable:
 :class:`ShardedSpGEMMExecutor` (the numeric phase of
@@ -69,6 +71,7 @@ metadata only: no op, fusion or result depends on them.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional, Sequence, Tuple
 
@@ -205,23 +208,16 @@ def _run_schedule(
         )
 
 
-def _invert_scatter(scatter: np.ndarray, size: int) -> np.ndarray:
-    """Turn flat scatter indices (``blocks.flat[scatter] = vals``) into a
-    gather map (``blocks.flat = vals_padded[inv]``), with index ``nnz``
-    pointing at a zero pad slot. XLA lowers gathers far better than
-    scatters on CPU, and the inverse is value-independent — computed once
-    at executor build."""
-    inv = np.full(size, scatter.shape[0], np.int32)
-    inv[scatter] = np.arange(scatter.shape[0], dtype=np.int32)
-    return inv
-
-
-def _bind(vals, inv, shape):
-    """Device-side value rebind as one gather through the precomputed
-    scatter inverse. Positions outside the pattern read the zero pad."""
+def _bind(vals, scatter, shape, mode="promise_in_bounds"):
+    """Device-side value rebind: one scatter of the ``[nnz]`` values into
+    a zeroed block array at the plan's flat indices (``blocks.flat[scatter]
+    = vals``), so the device indexes nnz elements, not every slot. The
+    indices are unique by construction; ``mode="drop"`` skips those past
+    the array's end (a shard's elements outside its slot range)."""
     with jax.named_scope("spgemm.bind"):
-        pad = jnp.concatenate([vals, jnp.zeros(1, vals.dtype)])
-        return pad[inv].reshape(shape)
+        blocks = jnp.zeros(math.prod(shape), vals.dtype)
+        return blocks.at[scatter].set(
+            vals, unique_indices=True, mode=mode).reshape(shape)
 
 
 def _assemble(panels, gather, bsz=None):
@@ -250,25 +246,28 @@ def numeric_core(
     jax.jit, static_argnames=_STATICS + ("a_shape", "b_shape")
 )
 def numeric_core_values(
-    a_vals, b_vals, a_inv, b_inv, sched, gather, *,
+    a_vals, b_vals, a_scatter, b_scatter, sched, gather, *,
     a_shape, b_shape, n_panels, group, backend, interpret,
 ):
     """Numeric phase from [nnz] value vectors: rebind + kernel + assembly."""
-    a_blocks = _bind(a_vals, a_inv, a_shape)
-    b_blocks = _bind(b_vals, b_inv, b_shape)
+    a_blocks = _bind(a_vals, a_scatter, a_shape)
+    b_blocks = _bind(b_vals, b_scatter, b_shape)
     return numeric_core(
         a_blocks, b_blocks, sched, gather,
         n_panels=n_panels, group=group, backend=backend, interpret=interpret,
     )
 
 
-def _bind_batch(vals, inv, shape):
-    """Batched value rebind: one gather per batch row through the shared
-    scatter inverse, stacked along the slot axis."""
+def _bind_batch(vals, scatter, shape, mode="promise_in_bounds"):
+    """Batched value rebind: ``[batch, nnz]`` values scattered through the
+    shared ``[nnz]`` map into one zeroed ``[batch, slots * bm * bk]`` array
+    (as :func:`_bind`), returned stacked along the slot axis."""
     bsz = vals.shape[0]
     with jax.named_scope("spgemm.bind"):
-        pad = jnp.concatenate([vals, jnp.zeros((bsz, 1), vals.dtype)], axis=1)
-        return pad[:, inv].reshape((bsz * shape[0],) + tuple(shape[1:]))
+        blocks = jnp.zeros((bsz, math.prod(shape)), vals.dtype)
+        return blocks.at[:, scatter].set(
+            vals, unique_indices=True, mode=mode,
+        ).reshape((bsz * shape[0],) + tuple(shape[1:]))
 
 
 def _fold_schedule(sched, bsz, a_slots, b_slots, n_panels):
@@ -319,7 +318,7 @@ def _run_schedule_batch(
     static_argnames=("a_shape", "b_shape", "rebind") + _STATICS,
 )
 def numeric_core_batch(
-    a_vals, b_vals, a_inv, b_inv, sched, gather, *,
+    a_vals, b_vals, a_scatter, b_scatter, sched, gather, *,
     a_shape, b_shape, rebind, n_panels, group, backend, interpret,
 ):
     """Batched numeric phase over a leading value axis.
@@ -338,8 +337,8 @@ def numeric_core_batch(
     """
     bsz = a_vals.shape[0]
     if rebind:
-        a_blocks = _bind_batch(a_vals, a_inv, a_shape)
-        b_blocks = _bind_batch(b_vals, b_inv, b_shape)
+        a_blocks = _bind_batch(a_vals, a_scatter, a_shape)
+        b_blocks = _bind_batch(b_vals, b_scatter, b_shape)
     else:
         a_blocks = a_vals.reshape((bsz * a_shape[0],) + tuple(a_shape[1:]))
         b_blocks = b_vals.reshape((bsz * b_shape[0],) + tuple(b_shape[1:]))
@@ -359,15 +358,15 @@ def numeric_core_batch(
 
 
 @functools.partial(jax.jit, static_argnames=("shape",))
-def bind_core(vals, inv, *, shape):
+def bind_core(vals, scatter, *, shape):
     """Stage 1 (element plans): [nnz] values -> packed blocks on device."""
-    return _bind(vals, inv, shape)
+    return _bind(vals, scatter, shape)
 
 
 @functools.partial(jax.jit, static_argnames=("shape",))
-def bind_batch_core(vals, inv, *, shape):
+def bind_batch_core(vals, scatter, *, shape):
     """Stage 1, batched: [batch, nnz] values -> stacked packed blocks."""
-    return _bind_batch(vals, inv, shape)
+    return _bind_batch(vals, scatter, shape)
 
 
 @functools.partial(jax.jit, static_argnames=_STATICS)
@@ -480,19 +479,19 @@ class SpGEMMExecutor:
             )
         else:
             self._sched = self._sched_jnp
-        # Rebind maps: scatter indices inverted to gather form at build.
-        self._a_inv = (
-            jnp.asarray(_invert_scatter(a_scatter, int(np.prod(a_shape))))
-            if a_scatter is not None else None
+        # Rebind maps: the plan's [nnz] flat scatter indices, as they are.
+        self._a_scatter = (
+            jnp.asarray(a_scatter, jnp.int32) if a_scatter is not None
+            else None
         )
-        self._b_inv = (
-            jnp.asarray(_invert_scatter(b_scatter, int(np.prod(b_shape))))
-            if b_scatter is not None else None
+        self._b_scatter = (
+            jnp.asarray(b_scatter, jnp.int32) if b_scatter is not None
+            else None
         )
 
     @property
     def can_rebind(self) -> bool:
-        return self._a_inv is not None and self._b_inv is not None
+        return self._a_scatter is not None and self._b_scatter is not None
 
     def set_chunk_bytes(self, chunk_bytes: Optional[int]) -> None:
         """Re-resolve the chunk policy with a new per-set budget.
@@ -566,7 +565,7 @@ class SpGEMMExecutor:
     def run_values(self, a_vals, b_vals) -> jax.Array:
         """[nnz] value vectors -> packed C values, rebind included."""
         return numeric_core_values(
-            a_vals, b_vals, self._a_inv, self._b_inv,
+            a_vals, b_vals, self._a_scatter, self._b_scatter,
             self._sched, self._gather,
             a_shape=self.a_shape, b_shape=self.b_shape,
             n_panels=self.n_panels, group=self.group, backend=self.backend,
@@ -578,7 +577,7 @@ class SpGEMMExecutor:
         backend: the batch-folded Pallas grid on pallas plans)."""
         return numeric_core_batch(
             jnp.asarray(a_vals), jnp.asarray(b_vals),
-            self._a_inv, self._b_inv,
+            self._a_scatter, self._b_scatter,
             self._sched, self._gather,
             a_shape=self.a_shape, b_shape=self.b_shape, rebind=rebind,
             n_panels=self.n_panels, group=self.group, backend=self.backend,
@@ -600,16 +599,16 @@ class SpGEMMExecutor:
         packed blocks without blocking."""
         if mode == "values":
             return (
-                bind_core(jax.device_put(a), self._a_inv,
+                bind_core(jax.device_put(a), self._a_scatter,
                           shape=self.a_shape),
-                bind_core(jax.device_put(b), self._b_inv,
+                bind_core(jax.device_put(b), self._b_scatter,
                           shape=self.b_shape),
             )
         if mode == "batch_values":
             return (
-                bind_batch_core(jax.device_put(a), self._a_inv,
+                bind_batch_core(jax.device_put(a), self._a_scatter,
                                 shape=self.a_shape),
-                bind_batch_core(jax.device_put(b), self._b_inv,
+                bind_batch_core(jax.device_put(b), self._b_scatter,
                                 shape=self.b_shape),
             )
         if mode == "batch_blocks":
@@ -718,21 +717,22 @@ def shard_program(
         specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax))
         vma = False
     elif kind == "run_values":
-        def body(a_vals, b_vals, a_inv, b_inv, a_slot, b_slot, panel,
+        def body(a_vals, b_vals, a_sc, b_sc, a_slot, b_slot, panel,
                  sub_row, strt, gth):
-            a_bl = _bind(a_vals[0], a_inv[0], (a_max, bm, bk))
-            b_bl = _bind(b_vals, b_inv, b_shape)
+            a_bl = _bind(a_vals[0], a_sc[0], (a_max, bm, bk), mode="drop")
+            b_bl = _bind(b_vals, b_sc, b_shape)
             return kernel(a_bl, b_bl, a_slot[0], b_slot[0], panel[0],
                           sub_row[0], strt[0], gth[0])[None]
         specs = (P(ax), P(), P(ax), P(), P(ax), P(ax), P(ax), P(ax),
                  P(ax), P(ax))
         vma = False
     elif kind == "batch_values":
-        def body(a_vals, b_vals, a_inv, b_inv, a_slot, b_slot, panel,
+        def body(a_vals, b_vals, a_sc, b_sc, a_slot, b_slot, panel,
                  sub_row, strt, gth):
             bsz = a_vals.shape[1]
-            a_bl = _bind_batch(a_vals[0], a_inv[0], (a_max, bm, bk))
-            b_bl = _bind_batch(b_vals, b_inv, b_shape)
+            a_bl = _bind_batch(a_vals[0], a_sc[0], (a_max, bm, bk),
+                               mode="drop")
+            b_bl = _bind_batch(b_vals, b_sc, b_shape)
             return kernel_batch(a_bl, b_bl, a_slot[0], b_slot[0],
                                 panel[0], sub_row[0], strt[0], gth[0],
                                 bsz)[None]
@@ -755,17 +755,18 @@ def shard_program(
     # fused bodies above, one shard_map program per stage so staging
     # step s+1 dispatches independently of step s's kernel.
     elif kind == "bind":
-        def body(a_vals, b_vals, a_inv, b_inv):
-            a_bl = _bind(a_vals[0], a_inv[0], (a_max, bm, bk))
-            b_bl = _bind(b_vals, b_inv, b_shape)
+        def body(a_vals, b_vals, a_sc, b_sc):
+            a_bl = _bind(a_vals[0], a_sc[0], (a_max, bm, bk), mode="drop")
+            b_bl = _bind(b_vals, b_sc, b_shape)
             return a_bl[None], b_bl
         specs = (P(ax), P(), P(ax), P())
         out = (P(ax), P())
     elif kind == "bind_batch":
-        def body(a_vals, b_vals, a_inv, b_inv):
+        def body(a_vals, b_vals, a_sc, b_sc):
             bsz = a_vals.shape[1]
-            a_bl = _bind_batch(a_vals[0], a_inv[0], (a_max, bm, bk))
-            b_bl = _bind_batch(b_vals, b_inv, b_shape)
+            a_bl = _bind_batch(a_vals[0], a_sc[0], (a_max, bm, bk),
+                               mode="drop")
+            b_bl = _bind_batch(b_vals, b_sc, b_shape)
             return (
                 a_bl.reshape((bsz, a_max, bm, bk))[None],
                 b_bl.reshape((bsz,) + tuple(b_shape)),
@@ -911,9 +912,10 @@ class ShardedSpGEMMExecutor:
             gather[i, : asm.nnz] = asm.gather
         self._gather = put(gather, self._sep)
 
-        # Rebind maps (element plans): per-shard scatter inverses into the
-        # shard's padded value slice; index e_max is the zero pad slot.
-        self._a_inv = self._b_inv = None
+        # Rebind maps (element plans): per shard, the scatter index of each
+        # element of its padded value slice into its own A block array;
+        # B keeps the plan's plain map, replicated.
+        self._a_scatter = self._b_scatter = None
         self._e_bounds: Optional[np.ndarray] = None
         self._e_max = 1
         if a_scatter is not None and b_scatter is not None:
@@ -922,26 +924,27 @@ class ShardedSpGEMMExecutor:
             self._e_bounds = np.asarray(a_val_bounds, np.int64)
             self._e_max = max(1, int(np.diff(self._e_bounds).max(initial=0)))
             self._nnz_b = int(b_scatter.shape[0])
+            # Elements of A blocks outside the shard's slot range never feed
+            # a triple (no matching B block), and the slice's padding holds
+            # no element: both get a distinct index past the shard's array,
+            # which the bind drops (unique, so the bind's promise holds).
             flat_a = self._a_max * bm * bk
-            a_inv = np.full((self._s, flat_a), self._e_max, np.int32)
+            a_sc = flat_a + np.tile(
+                np.arange(self._e_max, dtype=np.int64), (self._s, 1))
             for i, sh in enumerate(shards):
                 e_lo, e_hi = int(self._e_bounds[i]), int(self._e_bounds[i + 1])
                 pos = a_scatter[e_lo:e_hi] - sh.a_lo * bm * bk
-                # Elements of A blocks outside the shard's slot range never
-                # feed a triple (no matching B block) — skip them.
                 sel = (pos >= 0) & (pos < (sh.a_hi - sh.a_lo) * bm * bk)
-                a_inv[i, pos[sel]] = np.arange(e_hi - e_lo, dtype=np.int32)[sel]
-            self._a_inv = put(a_inv, self._sep)
-            self._b_inv = put(
-                _invert_scatter(b_scatter, int(np.prod(b_shape))), self._rep
-            )
+                a_sc[i, : e_hi - e_lo][sel] = pos[sel]
+            self._a_scatter = put(a_sc.astype(np.int32), self._sep)
+            self._b_scatter = put(np.asarray(b_scatter, np.int32), self._rep)
         self._fns: dict = {}
 
     # -- layout helpers (host side) ---------------------------------------
 
     @property
     def can_rebind(self) -> bool:
-        return self._a_inv is not None and self._b_inv is not None
+        return self._a_scatter is not None and self._b_scatter is not None
 
     def set_chunk_bytes(self, chunk_bytes: Optional[int]) -> None:
         """Re-resolve the chunk policy with a new per-set budget.
@@ -1062,7 +1065,8 @@ class ShardedSpGEMMExecutor:
             self._slice_a_vals(np.asarray(a_vals)), self._sep)
         b_d = jax.device_put(np.asarray(b_vals), self._rep)
         out = np.asarray(self._fn("run_values")(
-            a_sh, b_d, self._a_inv, self._b_inv, *self._sched, self._gather
+            a_sh, b_d, self._a_scatter, self._b_scatter, *self._sched,
+            self._gather,
         ))
         return self._concat(out)
 
@@ -1076,7 +1080,7 @@ class ShardedSpGEMMExecutor:
             a_sh = jax.device_put(self._slice_a_vals(a_vals), self._sep)
             b_d = jax.device_put(b_vals, self._rep)
             out = np.asarray(self._fn("batch_values")(
-                a_sh, b_d, self._a_inv, self._b_inv, *self._sched,
+                a_sh, b_d, self._a_scatter, self._b_scatter, *self._sched,
                 self._gather,
             ))
         else:
@@ -1099,13 +1103,14 @@ class ShardedSpGEMMExecutor:
             a_sh = jax.device_put(
                 self._slice_a_vals(np.asarray(a)), self._sep)
             b_d = jax.device_put(np.asarray(b), self._rep)
-            return self._fn("bind")(a_sh, b_d, self._a_inv, self._b_inv)
+            return self._fn("bind")(a_sh, b_d, self._a_scatter,
+                                    self._b_scatter)
         if mode == "batch_values":
             a_sh = jax.device_put(
                 self._slice_a_vals(np.asarray(a)), self._sep)
             b_d = jax.device_put(np.asarray(b), self._rep)
-            return self._fn("bind_batch")(a_sh, b_d, self._a_inv,
-                                          self._b_inv)
+            return self._fn("bind_batch")(a_sh, b_d, self._a_scatter,
+                                          self._b_scatter)
         if mode == "batch_blocks":
             return (
                 jax.device_put(self._stack_a(np.asarray(a)), self._sep),

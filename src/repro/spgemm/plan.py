@@ -829,8 +829,9 @@ class SpGEMMPlan:
         """``name`` around one call into the executor: the H2D of the host
         arrays ``sent`` plus the jit enqueue. Where the device binds
         ``bind_sets`` value sets, ``sent`` is their values, and the span
-        counts the useful values the bind gathers and the block slots it
-        fills (their ratio is the block fill)."""
+        counts the values the bind scatters and the block slots of the
+        zeroed arrays it scatters them into (their ratio is the block
+        fill)."""
         return TraceAnnotation(
             name, step=step,
             h2d_bytes=sum(x.nbytes for x in sent),

@@ -1,5 +1,7 @@
 """Device-resident numeric executor tests: jittable output assembly,
 vmap-batched execute_batch, and the supporting cache/report satellites."""
+import functools
+
 import numpy as np
 import pytest
 from _compat_hypothesis import given, settings, st
@@ -120,6 +122,141 @@ class TestDeviceAssembly:
         c1, c2 = plan.execute(), plan.execute(a.val, b.val)
         assert c1.indptr is plan.assembly.indptr
         assert c1.indices is c2.indices
+
+
+def _bits(x) -> np.ndarray:
+    """Bit pattern of a float32 array: equality here is bitwise."""
+    return np.ascontiguousarray(np.asarray(x, np.float32)).view(np.uint32)
+
+
+def _host_blocks(plan, a_vals, b_vals):
+    """The host ``_rebind`` of fresh values into fresh block arrays: the
+    reference the device bind must reproduce bit for bit."""
+    return (
+        plan._rebind(a_vals, None, plan._a_scatter, plan.report.nnz_a, "a",
+                     plan._a_shape, plan._a_dtype),
+        plan._rebind(b_vals, None, plan._b_scatter, plan.report.nnz_b, "b",
+                     plan._b_shape, plan._b_dtype),
+    )
+
+
+def _bind_index_shapes(jaxpr):
+    """Shapes of the index operand of every scatter and gather in a traced
+    program, sub-programs (jit, shard_map) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("scatter", "gather"):
+            found.append((eqn.primitive.name, eqn.invars[1].aval.shape))
+        for param in eqn.params.values():
+            sub = getattr(param, "jaxpr", param)  # ClosedJaxpr or Jaxpr
+            if hasattr(sub, "eqns"):
+                found += _bind_index_shapes(sub)
+    return found
+
+
+def _dropped_b_row_pattern(seed):
+    """A (40 x 36) and B (36 x 30) with B's first block row (tile 8) empty:
+    A's blocks in block column 0 feed no triple, so a shard's slot range
+    can leave their elements out."""
+    a = _int_coo(40, 36, 0.15, seed)
+    b = _int_coo(36, 30, 0.15, seed + 1)
+    keep = b.row >= 8
+    b = COO(b.row[keep], b.col[keep], b.val[keep], b.shape)
+    return a, b
+
+
+class TestDeviceBind:
+    """The device bind scatters each operand's [nnz] values into zeroed
+    block arrays; it must equal the host rebind bit for bit."""
+
+    @pytest.mark.parametrize("form", ["single", "batch"])
+    def test_matches_host_rebind(self, form):
+        a, b = _int_coo(40, 36, 0.12, 3), _int_coo(36, 30, 0.12, 4)
+        plan = spgemm_plan(a, b, tile=8, group=2, backend="jnp",
+                           cache=PlanCache())
+        ex = plan._executor
+        rng = np.random.default_rng(5)
+        av = rng.standard_normal((3, plan.report.nnz_a)).astype(np.float32)
+        bv = rng.standard_normal((3, plan.report.nnz_b)).astype(np.float32)
+        if form == "single":
+            got = [ex.pipe_stage(av[0], bv[0], mode="values")]
+        else:
+            ga, gb = ex.pipe_stage(av, bv, mode="batch_values")
+            got = zip(np.asarray(ga).reshape((3,) + plan._a_shape),
+                      np.asarray(gb).reshape((3,) + plan._b_shape))
+        for i, (da, db) in enumerate(got):
+            ha, hb = _host_blocks(plan, av[i], bv[i])
+            assert np.array_equal(_bits(da), _bits(ha)), (form, i)
+            assert np.array_equal(_bits(db), _bits(hb)), (form, i)
+
+    @pytest.mark.parametrize("form", ["values", "batch_values"])
+    def test_sharded_matches_host_rebind_and_drops_outside(self, form):
+        """One shard whose slot range leaves out A's block-column-0 blocks
+        (no triple reads them): their elements get indices past the
+        shard's array and are dropped; the rest equals the host rebind."""
+        from repro.launch.mesh import make_shard_mesh
+
+        a, b = _dropped_b_row_pattern(7)
+        plan = spgemm_plan(a, b, tile=8, group=2, backend="jnp",
+                           cache=PlanCache(), mesh=make_shard_mesh(1))
+        ex = plan._executor
+        flat_a = ex._a_max * 8 * 8
+        a_map = np.asarray(ex._a_scatter)
+        assert a_map.shape == (1, plan.report.nnz_a)
+        assert (a_map >= flat_a).any(), "expected elements to drop"
+        assert len(np.unique(a_map)) == a_map.size
+        rng = np.random.default_rng(8)
+        av = rng.standard_normal((2, plan.report.nnz_a)).astype(np.float32)
+        bv = rng.standard_normal((2, plan.report.nnz_b)).astype(np.float32)
+        if form == "values":
+            ga, gb = ex.pipe_stage(av[0], bv[0], mode="values")
+            got = [(np.asarray(ga)[0], np.asarray(gb))]
+        else:
+            ga, gb = ex.pipe_stage(av, bv, mode="batch_values")
+            got = zip(np.asarray(ga)[0], np.asarray(gb))
+        for i, (da, db) in enumerate(got):
+            ha, hb = _host_blocks(plan, av[i], bv[i])
+            assert np.array_equal(_bits(da), _bits(ex._stack_a(ha)[0])), i
+            assert np.array_equal(_bits(db), _bits(hb)), i
+        # And end to end against the single-device plan.
+        single = spgemm_plan(a, b, tile=8, group=2, backend="jnp",
+                             cache=PlanCache())
+        c, c0 = plan.execute(av[0], bv[0]), single.execute(av[0], bv[0])
+        assert np.array_equal(_bits(c.data), _bits(c0.data))
+
+    @pytest.mark.parametrize("form", ["single", "batch", "sharded"])
+    def test_bind_indexes_nnz_elements_not_slots(self, form):
+        """The bind's only indexed op is a scatter whose index operand has
+        one entry per value: no slot-wide gather map comes back."""
+        import jax
+        from repro.launch.mesh import make_shard_mesh
+        from repro.spgemm.executor import bind_batch_core, bind_core
+
+        a, b = _int_coo(40, 36, 0.12, 3), _int_coo(36, 30, 0.12, 4)
+        mesh = make_shard_mesh(1) if form == "sharded" else None
+        plan = spgemm_plan(a, b, tile=8, group=2, backend="jnp",
+                           cache=PlanCache(), mesh=mesh)
+        ex = plan._executor
+        nnz_a, nnz_b = plan.report.nnz_a, plan.report.nnz_b
+        av = np.ones((2, nnz_a), np.float32)
+        bv = np.ones((2, nnz_b), np.float32)
+        if form == "single":
+            traced = [jax.make_jaxpr(functools.partial(
+                bind_core, shape=shp))(v[0], sc) for v, sc, shp in (
+                    (av, ex._a_scatter, ex.a_shape),
+                    (bv, ex._b_scatter, ex.b_shape))]
+        elif form == "batch":
+            traced = [jax.make_jaxpr(functools.partial(
+                bind_batch_core, shape=shp))(v, sc) for v, sc, shp in (
+                    (av, ex._a_scatter, ex.a_shape),
+                    (bv, ex._b_scatter, ex.b_shape))]
+        else:
+            traced = [jax.make_jaxpr(ex._fn("bind"))(
+                av[:1], bv[0], ex._a_scatter, ex._b_scatter)]
+        found = [f for t in traced for f in _bind_index_shapes(t.jaxpr)]
+        assert found and {name for name, _ in found} == {"scatter"}
+        assert sorted(shape[0] for _, shape in found) == sorted([nnz_a, nnz_b])
+        assert tuple(ex._b_scatter.shape) == (nnz_b,)
 
 
 class TestExecuteBatch:

@@ -225,6 +225,65 @@ print("RAGGED_EMPTY_BLOCK_OK")
 """
 
 
+DROPPED_ELEMENTS = """
+import numpy as np
+import jax
+from repro.sparse.random import random_coo
+from repro.sparse.formats import COO
+from repro.launch.mesh import make_shard_mesh
+from repro.spgemm import PlanCache, spgemm_plan
+
+assert len(jax.devices()) == 4
+# B's first block row (tile 8) is empty, so A's block-column-0 blocks feed
+# no triple: a shard whose slot range starts past them drops their
+# elements in the device bind.
+a = random_coo(120, 64, 0.08, "uniform", seed=31)
+b = random_coo(64, 48, 0.1, "uniform", seed=32)
+keep = b.row >= 8
+b = COO(b.row[keep], b.col[keep], b.val[keep], b.shape)
+single = spgemm_plan(a, b, tile=8, group=2, backend="jnp", cache=PlanCache())
+plan = spgemm_plan(a, b, tile=8, group=2, backend="jnp", cache=PlanCache(),
+                   mesh=make_shard_mesh(4))
+ex = plan._executor
+# Nonzero small integers: exact in float32 under any accumulation order,
+# so the sharded C must equal the single-device C bit for bit.
+rng = np.random.default_rng(33)
+av = rng.choice([-3, -2, -1, 1, 2, 3], plan.report.nnz_a).astype(np.float32)
+bv = rng.choice([-3, -2, -1, 1, 2, 3], plan.report.nnz_b).astype(np.float32)
+a_map = np.asarray(ex._a_scatter)
+flat_a = ex._a_max * 8 * 8
+dropped = 0
+for i in range(4):
+    e_lo, e_hi = int(ex._e_bounds[i]), int(ex._e_bounds[i + 1])
+    dropped += int((a_map[i, : e_hi - e_lo] >= flat_a).sum())
+assert dropped > 0, "expected elements outside a shard's slot range"
+assert all(len(np.unique(row)) == row.size for row in a_map)
+ha = plan._rebind(av, None, plan._a_scatter, plan.report.nnz_a, "a",
+                  plan._a_shape, plan._a_dtype)
+hb = plan._rebind(bv, None, plan._b_scatter, plan.report.nnz_b, "b",
+                  plan._b_shape, plan._b_dtype)
+bits = lambda x: np.ascontiguousarray(np.asarray(x, np.float32)).view(np.uint32)
+ga, gb = ex.pipe_stage(av, bv, mode="values")
+assert np.array_equal(bits(ga), bits(ex._stack_a(ha)))
+assert np.array_equal(bits(gb), bits(hb))
+gba, gbb = ex.pipe_stage(np.stack([av, -av]), np.stack([bv, bv]),
+                         mode="batch_values")
+assert np.array_equal(bits(np.asarray(gba)[:, 0]), bits(ex._stack_a(ha)))
+ha2 = plan._rebind(-av, None, plan._a_scatter, plan.report.nnz_a, "a",
+                   plan._a_shape, plan._a_dtype)
+assert np.array_equal(bits(np.asarray(gba)[:, 1]), bits(ex._stack_a(ha2)))
+assert np.array_equal(bits(gbb), bits(np.stack([hb, hb])))
+c, c0 = plan.execute(av, bv), single.execute(av, bv)
+assert np.array_equal(c.indptr, c0.indptr)
+assert np.array_equal(bits(c.data), bits(c0.data))
+cb = plan.execute_batch(np.stack([av, -av]), np.stack([bv, bv]))
+cb0 = single.execute_batch(np.stack([av, -av]), np.stack([bv, bv]))
+for x, y in zip(cb, cb0):
+    assert np.array_equal(bits(x.data), bits(y.data))
+print("DROPPED_ELEMENTS_OK", dropped)
+"""
+
+
 class TestShardedExecution:
     def test_matches_single_device_on_paper_matrices(self, forced_devices):
         """Acceptance: sharded execute/execute_batch bitwise-equal (jnp
@@ -235,6 +294,13 @@ class TestShardedExecution:
     def test_ragged_empty_and_block_paths(self, forced_devices):
         out = forced_devices(RAGGED_EMPTY_BLOCK, devices=8)
         assert "RAGGED_EMPTY_BLOCK_OK" in out
+
+    def test_bind_drops_elements_outside_shard_slots(self, forced_devices):
+        """Ragged 4-shard bind: elements of A blocks outside a shard's slot
+        range are dropped; the rest equals the host rebind bit for bit,
+        single and batched, and C equals the single-device plan's."""
+        out = forced_devices(DROPPED_ELEMENTS, devices=4)
+        assert "DROPPED_ELEMENTS_OK" in out
 
     def test_single_device_mesh_works_without_forced_devices(self):
         """A 1-device mesh shards trivially in the normal test process."""

@@ -225,23 +225,23 @@ def test_compiled_programs_carry_the_stage_scopes(program):
     """Small jnp-backend programs: only the metadata is checked (the
     chip's compile of the Pallas programs: tests/test_tpu_compile.py)."""
     backend = "jnp"
-    shape, flat = (4, 8, 8), 4 * 8 * 8
+    shape = (4, 8, 8)
     sched = tuple(jnp.zeros(6, jnp.int32) for _ in range(4))
-    inv = jnp.zeros(flat, jnp.int32)
+    scatter = jnp.arange(10, dtype=jnp.int32)  # the bind's [nnz] map
     statics = dict(n_panels=2, group=2, backend=backend, interpret=False)
     if program == "fused":
         found = _scopes_in(numeric_core_values.lower(
-            jnp.ones(10), jnp.ones(10), inv, inv, sched, jnp.zeros(20, jnp.int32),
+            jnp.ones(10), jnp.ones(10), scatter, scatter, sched, jnp.zeros(20, jnp.int32),
             a_shape=shape, b_shape=shape, **statics))
     elif program == "stages":
         blocks = jnp.ones(shape)
-        found = (_scopes_in(bind_core.lower(jnp.ones(10), inv, shape=shape))
+        found = (_scopes_in(bind_core.lower(jnp.ones(10), scatter, shape=shape))
                  | _scopes_in(kernel_core.lower(blocks, blocks, sched, **statics))
                  | _scopes_in(assemble_core.lower(jnp.ones((3, 16, 8)),
                                                   jnp.zeros(20, jnp.int32))))
     elif program == "batch":
         found = _scopes_in(numeric_core_batch.lower(
-            jnp.ones((2, 10)), jnp.ones((2, 10)), inv, inv, sched,
+            jnp.ones((2, 10)), jnp.ones((2, 10)), scatter, scatter, sched,
             jnp.zeros(20, jnp.int32), a_shape=shape, b_shape=shape, rebind=True,
             **statics))
     else:
@@ -250,7 +250,7 @@ def test_compiled_programs_carry_the_stage_scopes(program):
                            interpret=False, group=2, a_max=shape[0], p_max=2,
                            a_shape=shape, b_shape=shape)
         found = _scopes_in(fn.lower(
-            jnp.ones((1, 10)), jnp.ones(10), inv[None], inv,
+            jnp.ones((1, 10)), jnp.ones(10), scatter[None], scatter,
             *(x[None] for x in sched), jnp.zeros(6, jnp.int32)[None],
             jnp.zeros((1, 20), jnp.int32)))
     assert found == set(SCOPES)

@@ -104,12 +104,12 @@ def test_batch_kernel_compiles(one_chip, no_compile_cache):
 
 
 def test_fused_execute_program_compiles(one_chip, no_compile_cache):
-    """The rebind + kernel + compact-assembly jit ``execute(a, b)`` runs."""
-    flat = NNZB * TILE * TILE
+    """The rebind + kernel + compact-assembly jit ``execute(a, b)`` runs;
+    the bind's scatter maps are ``[nnz]``, as the plan passes them."""
     shape = (NNZB, TILE, TILE)
     _check(numeric_core_values.lower(
         _sds((NNZ_A,), F32, one_chip), _sds((NNZ_A,), F32, one_chip),
-        _sds((flat,), I32, one_chip), _sds((flat,), I32, one_chip),
+        _sds((NNZ_A,), I32, one_chip), _sds((NNZ_A,), I32, one_chip),
         [_sds((T,), I32, one_chip)] * 5, _sds((NNZ_C,), I32, one_chip),
         a_shape=shape, b_shape=shape, n_panels=N_PANELS, group=GROUP,
         backend="pallas", interpret=False,
@@ -123,8 +123,7 @@ def _shard_args(kind, mesh):
         return (_sds((4, A_MAX, TILE, TILE), F32, sep),
                 _sds((NNZB, TILE, TILE), F32, rep), *sched)
     return (_sds((4, E_MAX), F32, sep), _sds((NNZ_A,), F32, rep),
-            _sds((4, A_MAX * TILE * TILE), I32, sep),
-            _sds((NNZB * TILE * TILE,), I32, rep), *sched,
+            _sds((4, E_MAX), I32, sep), _sds((NNZ_A,), I32, rep), *sched,
             _sds((4, C_MAX), I32, sep))
 
 
@@ -154,11 +153,11 @@ def test_compiled_programs_keep_the_stage_scopes(program, one_chip, mesh4,
     ``op_name`` metadata, where the profiler reads each device op's stage
     (``bench/spans.py``). Small shapes: only the metadata is checked."""
     t, nnzb, nnz, nnz_c = 64, 8, 1_000, 4_000
-    shape, flat = (nnzb, TILE, TILE), nnzb * TILE * TILE
+    shape = (nnzb, TILE, TILE)
     if program == "fused":
         compiled = numeric_core_values.lower(
             _sds((nnz,), F32, one_chip), _sds((nnz,), F32, one_chip),
-            _sds((flat,), I32, one_chip), _sds((flat,), I32, one_chip),
+            _sds((nnz,), I32, one_chip), _sds((nnz,), I32, one_chip),
             [_sds((t,), I32, one_chip)] * 5, _sds((nnz_c,), I32, one_chip),
             a_shape=shape, b_shape=shape, n_panels=4, group=GROUP,
             backend="pallas", interpret=False,
@@ -172,7 +171,7 @@ def test_compiled_programs_keep_the_stage_scopes(program, one_chip, mesh4,
         )
         compiled = fn.lower(
             _sds((4, nnz), F32, sep), _sds((nnz,), F32, rep),
-            _sds((4, flat), I32, sep), _sds((flat,), I32, rep),
+            _sds((4, nnz), I32, sep), _sds((nnz,), I32, rep),
             *[_sds((4, t), I32, sep)] * 5, _sds((4, nnz_c), I32, sep),
         ).compile()
     assert _scopes(compiled) == {"spgemm.bind", "spgemm.kernel",
