@@ -278,11 +278,10 @@ def run_sharded(n_shards: int, scale: float = 1.0, backend: str = "pallas",
     stream = SpGEMMValueStream(plan.a_pattern, plan.b_pattern, seed=seed + 1)
     ex = plan._executor
     want = [int(d.id) for d in mesh.devices.ravel()]
-    constants = {"sched": ex._sched[0], "gather": ex._gather,
+    constants = {"sched": ex._sched[0][0], "gather": ex._gather,
                  "a_scatter": ex._a_scatter}
     staged = ex.pipe_stage(*stream.values_at(0), mode="values")
-    packed = ex.pipe_assemble(ex.pipe_kernel(staged, mode="single"),
-                              mode="single")
+    packed = ex.pipe_kernel(staged, mode="single")
     placed = {k: _shard_devices(v) for k, v in constants.items()}
     placed["output"] = _shard_devices(packed)
     print(f"shard devices: mesh={want} " + " ".join(

@@ -170,12 +170,8 @@ def lint_plan_kernel_specs(plan, bsz: int = 2) -> List[Finding]:
     # output panel, double-buffered by the Pallas pipeline) must fit
     # per-core VMEM. An oversized config fails at compile time at best
     # and silently spills at worst — catch it here, statically.
-    from repro.core.perfmodel import (
-        SCHEDULE_SMEM_BYTES_PER_TRIPLE,
-        TPU_SMEM_BYTES,
-        TPU_VMEM_BYTES,
-        spgemm_grid_step_vmem,
-    )
+    from repro.core import perfmodel
+    from repro.core.perfmodel import TPU_VMEM_BYTES, spgemm_grid_step_vmem
 
     dtype_bytes = int(np.dtype(np.float32).itemsize)
     step_bytes = spgemm_grid_step_vmem(
@@ -198,21 +194,24 @@ def lint_plan_kernel_specs(plan, bsz: int = 2) -> List[Finding]:
         _err(findings, "kernel.block-shape",
              f"B blocks {plan._b_shape} not tiled by (1, {bk}, {bn})")
     a_slot, b_slot, panel, sub_row, start, t_pad = _pad_for(plan)
-    # SMEM budget: the five int32 scalar-prefetch schedule arrays are
-    # resident in scalar memory for the whole grid, so the padded schedule
-    # length bounds what one pallas_call can take (one call per shard on a
-    # sharded plan, each padded to the largest shard).
-    shards = getattr(plan, "_shards", None)
-    t_call = max(sh.num_triples for sh in shards) if shards else t_pad
-    smem_bytes = SCHEDULE_SMEM_BYTES_PER_TRIPLE * t_call
-    if smem_bytes > TPU_SMEM_BYTES:
+    # SMEM budget: the five int32 scalar-prefetch arrays of a call are
+    # resident in scalar memory for its whole grid, so each slice of the
+    # schedule the executor staged (one pallas_call each, per device on a
+    # sharded plan, padded to the widest shard) may hold at most the
+    # per-call budget.
+    budget = perfmodel.SCHEDULE_TRIPLES_PER_CALL
+    ex = plan._executor
+    calls = ([] if ex is None
+             else [int(piece[0].shape[-1]) for piece in ex._sched])
+    if calls and max(calls) > budget:
         _err(findings, "kernel.smem-schedule",
-             f"scalar-prefetch schedule needs {smem_bytes} B of SMEM "
-             f"({t_call} triples x {SCHEDULE_SMEM_BYTES_PER_TRIPLE} B) but "
-             f"a TPU core has {TPU_SMEM_BYTES} B; the compiler refuses "
-             f"this kernel — use a larger tile or shard the plan over a "
-             f"mesh so each pallas_call holds at most "
-             f"{TPU_SMEM_BYTES // SCHEDULE_SMEM_BYTES_PER_TRIPLE} triples")
+             f"a kernel call runs {max(calls)} triples of the schedule "
+             f"({len(calls)} calls), more than the {budget} whose "
+             f"scalar-prefetch arrays one pallas_call's SMEM holds "
+             f"({perfmodel.TPU_SMEM_BYTES} B less "
+             f"{perfmodel.SCHEDULE_SMEM_MARGIN_BYTES} B, at "
+             f"{perfmodel.SCHEDULE_SMEM_BYTES_PER_TRIPLE} B a triple); the "
+             f"compiler refuses such a call")
     t = np.arange(t_pad)
     # Single grid (t_pad,): index maps t -> (a_s[t],·,·) etc., out panel
     # space n_panels + 1 (the appended dummy).

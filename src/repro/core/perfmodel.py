@@ -37,6 +37,8 @@ __all__ = [
     "TPU_VMEM_BYTES",
     "TPU_SMEM_BYTES",
     "SCHEDULE_SMEM_BYTES_PER_TRIPLE",
+    "SCHEDULE_SMEM_MARGIN_BYTES",
+    "SCHEDULE_TRIPLES_PER_CALL",
     "roofline_seconds",
     "PAPER_TABLE7_MS",
     "PAPER_TABLE8_STUF",
@@ -142,6 +144,16 @@ TPU_VMEM_BYTES = 16 << 20
 # smem. Used 3.83M of 1.00M" — so one pallas_call holds ~50k triples.
 TPU_SMEM_BYTES = 1 << 20
 SCHEDULE_SMEM_BYTES_PER_TRIPLE = 5 * 4
+# SMEM left to the rest of a call. The described-v5e compile of one call
+# at 8,997 blocks and 9,692 panels accepts 52,218 triples and refuses
+# 52,229 ("Used 1.02M of 1.00M smem"); 64 KiB holds 3,066 of headroom
+# below that.
+SCHEDULE_SMEM_MARGIN_BYTES = 64 << 10
+# The most triples one pallas_call takes (49,152): the executor cuts a
+# longer schedule into calls of at most this many, at panel boundaries.
+SCHEDULE_TRIPLES_PER_CALL = (
+    TPU_SMEM_BYTES - SCHEDULE_SMEM_MARGIN_BYTES
+) // SCHEDULE_SMEM_BYTES_PER_TRIPLE
 
 
 def spgemm_grid_step_vmem(

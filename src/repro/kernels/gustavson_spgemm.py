@@ -21,6 +21,16 @@ Scalar prefetch (PrefetchScalarGridSpec) plays the role of the load kernel's
 scheduling side-channel (A_DS of Table 1): slot/panel/sub-row indices are
 resident in SMEM before the grid body runs.
 
+**Schedule split.** The five prefetch arrays of one call must fit the
+core's SMEM, so a schedule longer than
+:data:`repro.core.perfmodel.SCHEDULE_TRIPLES_PER_CALL` runs as several
+calls over contiguous slices of it (:func:`schedule_cuts`), cut at panel
+starts: each panel is accumulated whole, in schedule order, inside one
+call, and every call after the first writes its panels into the panel
+buffer of the call before it (``input_output_aliases``), so the calls
+fill one buffer with no copy. A schedule within the budget is one slice,
+and one call.
+
 **Batched variant** (:func:`spgemm_scheduled_batch_impl`): a value batch is
 folded into the grid as a leading dimension — grid ``(bsz, t_pad)``, with
 the shared triple schedule replicated per batch element through the
@@ -43,11 +53,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import perfmodel
+
 
 __all__ = [
     "compact_csr_indptr_impl",
     "compact_row_counts_impl",
     "pad_schedule_arrays",
+    "schedule_cuts",
     "spgemm_scheduled",
     "spgemm_scheduled_batch",
     "spgemm_scheduled_batch_impl",
@@ -65,11 +78,11 @@ def _kernel(
     # VMEM blocks
     a_ref,  # [1, bm, bk]
     b_ref,  # [1, bk, bn]
-    o_ref,  # [1, G*bm, bn]
-    *,
+    *refs,  # [the earlier call's panel buffer (HBM, aliased),] out panel
     bm: int,
     t_dim: int = 0,
 ):
+    o_ref = refs[-1]  # [1, G*bm, bn]
     # ``t_dim`` is the grid dimension that walks the triple schedule: 0 for
     # the single-set grid ``(t_pad,)``, 1 for the batch-folded grid
     # ``(bsz, t_pad)`` (the schedule is shared across batch elements, so
@@ -129,14 +142,48 @@ def pad_schedule_arrays(
     )
 
 
+def schedule_cuts(start: np.ndarray, budget: int | None = None) -> np.ndarray:
+    """Bounds ``[0, c_1, ..., T]`` of the calls a schedule runs as: the
+    fewest contiguous slices of at most ``budget`` triples (default
+    :data:`repro.core.perfmodel.SCHEDULE_TRIPLES_PER_CALL`), each cut at a
+    panel start (``start == 1``), so no panel spans two calls. A schedule
+    within the budget is one slice; an empty one is one empty slice.
+    Raises ``ValueError`` when one panel alone holds more than ``budget``
+    triples."""
+    if budget is None:
+        budget = perfmodel.SCHEDULE_TRIPLES_PER_CALL
+    t = int(start.shape[0])
+    # Where a slice may end: a panel start, or the end of the schedule.
+    ends = np.append(np.flatnonzero(start), t)
+    cuts = [0]
+    while True:
+        lo = cuts[-1]
+        hi = int(ends[np.searchsorted(ends, lo + budget, side="right") - 1])
+        if hi <= lo and t:
+            raise ValueError(
+                f"a panel at triple {lo} holds more than {budget} triples, "
+                f"more than one pallas_call's SMEM takes")
+        cuts.append(hi)
+        if hi == t:
+            return np.asarray(cuts, np.int64)
+
+
+def _carry(panels):
+    """A call's extra in-spec, operand and alias that write its panels into
+    ``panels``, the buffer of the call before it, in place: the buffer
+    stays in HBM (``pl.ANY``), unread, and aliases the output; the panels
+    this call does not visit keep what they hold. The first call has no
+    buffer to carry."""
+    if panels is None:
+        return [], (), {}
+    # Operand index: five prefetch arrays, A blocks, B blocks, the buffer.
+    return [pl.BlockSpec(memory_space=pl.ANY)], (panels,), {7: 0}
+
+
 def spgemm_scheduled_impl(
     a_blocks: jax.Array,  # [nnzb_a, bm, bk] packed BCSV blocks (stream order)
     b_blocks: jax.Array,  # [nnzb_b, bk, bn] packed BCSR blocks
-    a_slot: jax.Array,  # [T] int32
-    b_slot: jax.Array,  # [T] int32
-    panel: jax.Array,  # [T] int32 (dummy = n_panels)
-    sub_row: jax.Array,  # [T] int32 in [0, group)
-    start: jax.Array,  # [T] int32 {0,1}
+    slices,  # ((a_slot, b_slot, panel, sub_row, start), ...) int32, per call
     *,
     n_panels: int,
     group: int,
@@ -144,35 +191,46 @@ def spgemm_scheduled_impl(
 ) -> jax.Array:
     """Unjitted body of :func:`spgemm_scheduled`.
 
+    ``slices`` is the padded schedule as the calls run it: one tuple of
+    the five int32 arrays per ``pallas_call`` (dummy panel = ``n_panels``),
+    each slice starting a panel (:func:`schedule_cuts`). The calls run in
+    order into one panel buffer.
+
     Exposed so callers that fuse further device work around the kernel
     (``repro.spgemm.executor`` chains it with value rebind and output
     assembly) can place the whole pipeline under one ``jax.jit`` without
     nesting jits. Returns panels [n_panels, group*bm, bn] float32 (dummy
     panel stripped).
     """
-    t_pad = a_slot.shape[0]
     bm, bk = a_blocks.shape[1], a_blocks.shape[2]
     bn = b_blocks.shape[2]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(t_pad,),
-        in_specs=[
-            pl.BlockSpec((1, bm, bk), lambda t, a_s, b_s, p, sr, st: (a_s[t], 0, 0)),
-            pl.BlockSpec((1, bk, bn), lambda t, a_s, b_s, p, sr, st: (b_s[t], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, group * bm, bn), lambda t, a_s, b_s, p, sr, st: (p[t], 0, 0)
-        ),
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, bm=bm),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_panels + 1, group * bm, bn), jnp.float32),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-    )(a_slot, b_slot, panel, sub_row, start, a_blocks, b_blocks)
+    out = None
+    for sched in slices:
+        carry_spec, carried, aliases = _carry(out)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(sched[0].shape[0],),
+            in_specs=[
+                pl.BlockSpec((1, bm, bk),
+                             lambda t, a_s, b_s, p, sr, st: (a_s[t], 0, 0)),
+                pl.BlockSpec((1, bk, bn),
+                             lambda t, a_s, b_s, p, sr, st: (b_s[t], 0, 0)),
+            ] + carry_spec,
+            out_specs=pl.BlockSpec(
+                (1, group * bm, bn), lambda t, a_s, b_s, p, sr, st: (p[t], 0, 0)
+            ),
+        )
+        out = pl.pallas_call(
+            functools.partial(_kernel, bm=bm),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (n_panels + 1, group * bm, bn), jnp.float32),
+            interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+            ),
+            input_output_aliases=aliases,
+        )(*sched, a_blocks, b_blocks, *carried)
     return out[:n_panels]
 
 
@@ -190,18 +248,16 @@ spgemm_scheduled.__doc__ = (
 def spgemm_scheduled_batch_impl(
     a_blocks: jax.Array,  # [bsz * nnzb_a, bm, bk] stacked packed BCSV blocks
     b_blocks: jax.Array,  # [bsz * nnzb_b, bk, bn] stacked packed BCSR blocks
-    a_slot: jax.Array,  # [T_pad] int32, shared across the batch
-    b_slot: jax.Array,  # [T_pad] int32
-    panel: jax.Array,  # [T_pad] int32 (dummy = n_panels)
-    sub_row: jax.Array,  # [T_pad] int32 in [0, group)
-    start: jax.Array,  # [T_pad] int32 {0,1}
+    slices,  # ((a_slot, b_slot, panel, sub_row, start), ...) shared, per call
     *,
     bsz: int,
     n_panels: int,
     group: int,
     interpret: bool = True,
 ) -> jax.Array:
-    """Batch-folded scheduled kernel: one Pallas grid for a value batch.
+    """Batch-folded scheduled kernel: one Pallas grid for a value batch
+    per schedule slice (``slices`` as :func:`spgemm_scheduled_impl` takes
+    them, the calls writing into one panel buffer).
 
     The batch is the leading grid dimension — grid step ``(b, t)`` runs
     triple ``t`` of element ``b`` against that element's slice of the
@@ -216,49 +272,52 @@ def spgemm_scheduled_batch_impl(
     the padding triples, mirroring :func:`spgemm_scheduled_impl`). Returns
     ``[bsz, n_panels, group*bm, bn]`` float32 with the dummies stripped.
     """
-    t_pad = a_slot.shape[0]
     a_slots = a_blocks.shape[0] // bsz
     b_slots = b_blocks.shape[0] // bsz
     bm, bk = a_blocks.shape[1], a_blocks.shape[2]
     bn = b_blocks.shape[2]
     stride = n_panels + 1
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
-        grid=(bsz, t_pad),
-        in_specs=[
-            pl.BlockSpec(
-                (1, bm, bk),
-                lambda b, t, a_s, b_s, p, sr, st: (b * a_slots + a_s[t], 0, 0),
+    out = None
+    for sched in slices:
+        carry_spec, carried, aliases = _carry(out)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(bsz, sched[0].shape[0]),
+            in_specs=[
+                pl.BlockSpec(
+                    (1, bm, bk),
+                    lambda b, t, a_s, b_s, p, sr, st: (b * a_slots + a_s[t], 0, 0),
+                ),
+                pl.BlockSpec(
+                    (1, bk, bn),
+                    lambda b, t, a_s, b_s, p, sr, st: (b * b_slots + b_s[t], 0, 0),
+                ),
+            ] + carry_spec,
+            out_specs=pl.BlockSpec(
+                (1, group * bm, bn),
+                lambda b, t, a_s, b_s, p, sr, st: (b * stride + p[t], 0, 0),
             ),
-            pl.BlockSpec(
-                (1, bk, bn),
-                lambda b, t, a_s, b_s, p, sr, st: (b * b_slots + b_s[t], 0, 0),
+        )
+        out = pl.pallas_call(
+            functools.partial(_kernel, bm=bm, t_dim=1),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (bsz * stride, group * bm, bn), jnp.float32
             ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, group * bm, bn),
-            lambda b, t, a_s, b_s, p, sr, st: (b * stride + p[t], 0, 0),
-        ),
-    )
-    out = pl.pallas_call(
-        functools.partial(_kernel, bm=bm, t_dim=1),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (bsz * stride, group * bm, bn), jnp.float32
-        ),
-        interpret=interpret,
-        # The batch axis is race-free, so it may be declared "parallel":
-        # element b only ever writes output slots b*stride + panel[t] with
-        # panel[t] in [0, n_panels], i.e. inside its private half-open
-        # range [b*stride, (b+1)*stride) — no slot is shared across b
-        # (proven statically per plan by
-        # repro.analysis.verify.check_batch_races). The triple axis stays
-        # "arbitrary": panels are revisited across contiguous runs of t,
-        # a sequential accumulate dependence.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-    )(a_slot, b_slot, panel, sub_row, start, a_blocks, b_blocks)
+            interpret=interpret,
+            # The batch axis is race-free, so it may be declared "parallel":
+            # element b only ever writes output slots b*stride + panel[t]
+            # with panel[t] in [0, n_panels], i.e. inside its private
+            # half-open range [b*stride, (b+1)*stride) — no slot is shared
+            # across b (proven statically per plan by
+            # repro.analysis.verify.check_batch_races). The triple axis
+            # stays "arbitrary": panels are revisited across contiguous
+            # runs of t, a sequential accumulate dependence.
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+            ),
+            input_output_aliases=aliases,
+        )(*sched, a_blocks, b_blocks, *carried)
     return out.reshape(bsz, stride, group * bm, bn)[:, :n_panels]
 
 

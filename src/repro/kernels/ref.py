@@ -30,9 +30,14 @@ def spgemm_scheduled_ref(
     sub_row: jax.Array,  # [T]
     n_panels: int,
     group: int,
+    panels: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Execute the SpGEMM triple schedule densely: for each triple t,
     ``panels[panel[t], sub_row[t]*bm : ..., :] += A[a_slot[t]] @ B[b_slot[t]]``.
+
+    ``panels`` (``[n_panels, group*bm, bn]``, the result of an earlier
+    slice of the same schedule) is the array the products are added into;
+    zeros when it is not given.
 
     Pure jnp on traced arrays — safe to wrap in ``jax.jit`` and to ``vmap``
     over the block operands with a constant schedule (the batched executor
@@ -54,7 +59,10 @@ def spgemm_scheduled_ref(
     row0 = jnp.asarray(panel, jnp.int32) * (group * bm) \
         + jnp.asarray(sub_row, jnp.int32) * bm
     rows = row0[:, None] + jnp.arange(bm, dtype=jnp.int32)[None, :]  # [T, bm]
-    flat = jnp.zeros((n_panels * group * bm, bn), jnp.float32)
+    if panels is None:
+        flat = jnp.zeros((n_panels * group * bm, bn), jnp.float32)
+    else:
+        flat = panels.reshape(n_panels * group * bm, bn)
     flat = flat.at[rows].add(prod)
     return flat.reshape(n_panels, group * bm, bn)
 

@@ -22,13 +22,12 @@ Typical use::
 The numeric phase is device-resident (``repro.spgemm.executor``): value
 rebind, the scheduled kernel, and output assembly run against the symbolic
 phase's precomputed CSR structure — fused under one ``jax.jit`` for
-synchronous executes, and *stage-split* into per-stage jits (H2D +
-rebind -> kernel -> assembly -> collect) behind one interface for the
-async path.
+synchronous executes, and split in two for the async path (H2D +
+rebind -> kernel and assembly -> collect) behind one interface.
 
 **Kernel dispatch** is decided once per plan by its resolved backend and
 honored on *every* numeric path — single execute, ``execute_batch``, the
-pipeline's stage jits, and the per-shard programs inside ``shard_map``::
+pipeline's jits, and the per-shard programs inside ``shard_map``::
 
     backend            x  path          -> scheduled kernel
     -------------------------------------------------------------------

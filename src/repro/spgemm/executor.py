@@ -39,14 +39,12 @@ those constants along a leading shard axis, lays them out over one mesh
 axis, and runs all three stages under a single ``shard_map`` — A
 row-sharded, B replicated, C row-sharded and concatenated on host.
 
-**Stage-split pipeline surface.** Next to the fused cores, each stage is
-also exposed as its own module-level jit (``bind_core`` /
-``kernel_core`` / ``assemble_core`` plus batched variants) and both
-executors carry a four-step pipeline protocol over them::
+**Pipeline surface.** Next to the fused cores, the value bind is also
+exposed as its own module-level jit (``bind_core`` / ``bind_batch_core``)
+and both executors carry a three-step pipeline protocol::
 
     staged = ex.pipe_stage(a, b, mode=...)   # H2D + value rebind dispatch
-    panels = ex.pipe_kernel(staged, mode)    # scheduled kernel dispatch
-    packed = ex.pipe_assemble(panels, mode)  # output-assembly gather
+    packed = ex.pipe_kernel(staged, mode)    # kernel + assembly dispatch
     out    = ex.pipe_collect(packed, mode)   # the ONLY blocking call (D2H)
 
 Every step but ``pipe_collect`` merely *dispatches* device work (JAX
@@ -56,10 +54,26 @@ rebind with ``s``'s kernel — the paper's double-buffered operand fetch,
 expressed functionally: each in-flight step owns its own staged packed
 A/B block arrays on device (per shard on the sharded executor), so a
 pipeline of depth *d* is a *d*-deep operand buffer ring.
-:class:`repro.spgemm.pipeline.SpGEMMPipeline` is that driver. The split
-stages run exactly the ops of the fused cores (shared helper functions,
-same schedules), so pipelined results are bitwise-equal to the
-synchronous path on both kernel backends.
+:class:`repro.spgemm.pipeline.SpGEMMPipeline` stages steps that way.
+``pipe_kernel`` runs the kernel and the assembly as one program, the
+fused core from staged blocks (``numeric_core`` / ``numeric_core_batch``;
+the sharded ``kernel`` / ``batch_blocks`` programs), so the panel array
+is that program's temporary: it never exists as a buffer of its own
+between two programs, and every product's assembly reads it where the
+program's temporaries lie, not wherever the allocator placed that
+product's kernel output. The steps run exactly the ops of the fused
+cores (shared helper functions, same schedules), so pipelined results
+are bitwise-equal to the synchronous path on both kernel backends.
+
+**Schedule split.** One kernel call's schedule has to fit the core's
+SMEM. The executors cut the schedule at build into the fewest slices of
+at most :data:`repro.core.perfmodel.SCHEDULE_TRIPLES_PER_CALL` triples,
+at panel starts (:func:`~repro.kernels.gustavson_spgemm.schedule_cuts`),
+and stage the slices on device; ``_run_schedule`` and
+``_run_schedule_batch`` run one kernel call per slice into one panel
+array, on every path and backend. Each panel is accumulated whole inside
+one call, so the result is bitwise the unsplit one; a schedule within
+the budget is one slice, and lowers to one call.
 
 Every path runs the three stages through the same helpers (``_bind``,
 ``_run_schedule``, ``_assemble`` and their batch forms), each under a
@@ -90,6 +104,7 @@ from repro.kernels import ref
 from repro.kernels.gustavson_spgemm import (
     compact_csr_indptr_impl,
     pad_schedule_arrays,
+    schedule_cuts,
     spgemm_scheduled_batch_impl,
     spgemm_scheduled_impl,
 )
@@ -99,13 +114,9 @@ __all__ = [
     "CHUNK_BYTES_ENV",
     "ShardedSpGEMMExecutor",
     "SpGEMMExecutor",
-    "assemble_batch_core",
-    "assemble_core",
     "bind_batch_core",
     "bind_core",
-    "kernel_batch_core",
     "kernel_interpret",
-    "kernel_core",
     "numeric_core",
     "numeric_core_batch",
     "resolve_chunk_bytes",
@@ -188,24 +199,36 @@ def kernel_interpret(backend: str) -> bool:
 _STATICS = ("n_panels", "group", "backend", "interpret")
 
 
+def _stage_slices(arrays, cuts):
+    """The schedule's device constants: one tuple of ``arrays`` cut to
+    ``[lo, hi)`` per kernel call (the schedule as ``_run_schedule`` and
+    ``_run_schedule_batch`` take it)."""
+    return tuple(
+        tuple(jnp.asarray(x[lo:hi]) for x in arrays)
+        for lo, hi in zip(cuts[:-1], cuts[1:])
+    )
+
+
 def _run_schedule(
     a_blocks, b_blocks, sched, *, n_panels, group, backend, interpret
 ):
-    """Dispatch the scheduled kernel. ``sched`` is the backend's device
-    tuple: (a_slot, b_slot, panel, sub_row, start) padded for pallas,
+    """Dispatch the scheduled kernel, one call per slice of ``sched`` into
+    one panel array. Each slice is the backend's device tuple:
+    (a_slot, b_slot, panel, sub_row, start) padded for pallas,
     (a_slot, b_slot, panel, sub_row) raw for jnp."""
     with jax.named_scope("spgemm.kernel"):
         if backend in ("pallas", "pallas_interpret"):
-            a_slot, b_slot, panel, sub_row, start = sched
             return spgemm_scheduled_impl(
-                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, start,
+                a_blocks, b_blocks, sched,
                 n_panels=n_panels, group=group, interpret=interpret,
             )
-        a_slot, b_slot, panel, sub_row = sched
-        return ref.spgemm_scheduled_ref(
-            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, n_panels,
-            group,
-        )
+        panels = None
+        for a_slot, b_slot, panel, sub_row in sched:
+            panels = ref.spgemm_scheduled_ref(
+                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, n_panels,
+                group, panels,
+            )
+        return panels
 
 
 def _bind(vals, scatter, shape, mode="promise_in_bounds"):
@@ -271,10 +294,11 @@ def _bind_batch(vals, scatter, shape, mode="promise_in_bounds"):
 
 
 def _fold_schedule(sched, bsz, a_slots, b_slots, n_panels):
-    """Fold a value batch into the triple schedule (jnp path): slot/panel
-    indices of all batch elements offset per element, so the batch executes
-    as one ``batch * T``-triple schedule over ``batch * n_panels`` panels
-    while preserving each element's accumulation order exactly."""
+    """Fold a value batch into one slice of the triple schedule (jnp
+    path): slot/panel indices of all batch elements offset per element, so
+    the batch executes as one ``batch * T``-triple schedule over
+    ``batch * n_panels`` panels while preserving each element's
+    accumulation order exactly."""
     a_slot, b_slot, panel, sub_row = sched
     off = jnp.arange(bsz, dtype=jnp.int32)[:, None]
     return (
@@ -290,27 +314,30 @@ def _run_schedule_batch(
     *, n_panels, group, backend, interpret,
 ):
     """Dispatch the batch-folded scheduled kernel over stacked blocks
-    (``[bsz * slots, ...]``). On ``pallas``/``pallas_interpret`` the fold
+    (``[bsz * slots, ...]``), one call per slice of ``sched`` (as
+    :func:`_run_schedule`). On ``pallas``/``pallas_interpret`` the fold
     is the grid itself (:func:`spgemm_scheduled_batch_impl`, grid
-    ``(bsz, t_pad)`` over the padded schedule); on ``jnp`` it is the
-    offset-folded schedule through the scatter-add reference. Both return
+    ``(bsz, t)`` over each padded slice); on ``jnp`` it is the
+    offset-folded slice through the scatter-add reference. Both return
     panels ``[bsz * n_panels, group*bm, bn]`` with identical per-element
     accumulation order."""
     with jax.named_scope("spgemm.kernel"):
         if backend in ("pallas", "pallas_interpret"):
-            a_slot, b_slot, panel, sub_row, start = sched
             panels = spgemm_scheduled_batch_impl(
-                a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, start,
+                a_blocks, b_blocks, sched,
                 bsz=bsz, n_panels=n_panels, group=group, interpret=interpret,
             )
             return panels.reshape((bsz * n_panels,) + panels.shape[2:])
-        a_slot_b, b_slot_b, panel_b, sub_row_b = _fold_schedule(
-            sched, bsz, a_slots, b_slots, n_panels
-        )
-        return ref.spgemm_scheduled_ref(
-            a_blocks, b_blocks, a_slot_b, b_slot_b, panel_b, sub_row_b,
-            bsz * n_panels, group,
-        )
+        panels = None
+        for piece in sched:
+            a_slot_b, b_slot_b, panel_b, sub_row_b = _fold_schedule(
+                piece, bsz, a_slots, b_slots, n_panels
+            )
+            panels = ref.spgemm_scheduled_ref(
+                a_blocks, b_blocks, a_slot_b, b_slot_b, panel_b, sub_row_b,
+                bsz * n_panels, group, panels,
+            )
+        return panels
 
 
 @functools.partial(
@@ -349,12 +376,13 @@ def numeric_core_batch(
     return _assemble(panels, gather, bsz)
 
 
-# -- stage-split cores (the pipeline protocol's jits) ----------------------
+# -- the pipeline's bind (the first step of the protocol) -----------------
 #
-# Module-level like the fused cores, so same-shaped plans share the stage
-# executables too. Each stage runs exactly the ops its slice of the fused
-# core runs (shared helpers, same schedule arrays), which is what keeps
-# pipelined results bitwise-equal to synchronous executes.
+# Module-level like the fused cores, so same-shaped plans share the bind
+# executables too. The bind runs exactly the ops of the fused cores' bind
+# (shared helpers), which is what keeps pipelined results bitwise-equal to
+# synchronous executes; the kernel and the assembly then run as one fused
+# core from the bound blocks.
 
 
 @functools.partial(jax.jit, static_argnames=("shape",))
@@ -365,49 +393,10 @@ def bind_core(vals, scatter, *, shape):
 
 @functools.partial(jax.jit, static_argnames=("shape",))
 def bind_batch_core(vals, scatter, *, shape):
-    """Stage 1, batched: [batch, nnz] values -> stacked packed blocks."""
-    return _bind_batch(vals, scatter, shape)
-
-
-@functools.partial(jax.jit, static_argnames=_STATICS)
-def kernel_core(
-    a_blocks, b_blocks, sched, *, n_panels, group, backend, interpret
-):
-    """Stage 2: packed blocks -> output panels (the scheduled kernel)."""
-    return _run_schedule(
-        a_blocks, b_blocks, sched,
-        n_panels=n_panels, group=group, backend=backend, interpret=interpret,
-    )
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("a_slots", "b_slots") + _STATICS,
-)
-def kernel_batch_core(
-    a_blocks, b_blocks, sched, *, a_slots, b_slots, n_panels, group,
-    backend, interpret,
-):
-    """Stage 2, batched: the batch-folded scheduled kernel over stacked
-    blocks (``[batch * slots, ...]``, as produced by stage 1) — the
-    plan-backend dispatch of :func:`_run_schedule_batch`."""
-    bsz = a_blocks.shape[0] // a_slots
-    return _run_schedule_batch(
-        a_blocks, b_blocks, sched, bsz, a_slots, b_slots,
-        n_panels=n_panels, group=group, backend=backend, interpret=interpret,
-    )
-
-
-@jax.jit
-def assemble_core(panels, gather):
-    """Stage 3: output panels -> packed C values (one static gather)."""
-    return _assemble(panels, gather)
-
-
-@functools.partial(jax.jit, static_argnames=("n_panels",))
-def assemble_batch_core(panels, gather, *, n_panels):
-    """Stage 3, batched: per-element gather through the shared map."""
-    return _assemble(panels, gather, panels.shape[0] // n_panels)
+    """Stage 1, batched: [batch, nnz] values -> packed blocks
+    ``[batch, slots, ...]``, as :func:`numeric_core_batch` takes them."""
+    bsz = vals.shape[0]
+    return _bind_batch(vals, scatter, shape).reshape((bsz,) + tuple(shape))
 
 
 class SpGEMMExecutor:
@@ -460,25 +449,23 @@ class SpGEMMExecutor:
         self._out_rows = int(assembly.shape[0])
         self._indptr_host = np.asarray(assembly.indptr)
         self._row_ids: Optional[jax.Array] = None
-        # The raw (unpadded) schedule tuple serves jnp plans on every path;
-        # pallas plans get the padded 5-tuple below, shared by the single
-        # and batch-folded grids.
-        self._sched_jnp = tuple(
-            jnp.asarray(x) for x in (
-                schedule.a_slot, schedule.b_slot, schedule.panel,
-                schedule.sub_row,
-            )
-        )
+        # The schedule in slices of one kernel call each, cut at panel
+        # starts to fit one call's SMEM. Pallas plans stage the padded
+        # 5-tuple per slice, shared by the single and batch-folded grids;
+        # jnp plans the raw 4-tuple.
+        cuts = schedule_cuts(schedule.start)
+        self.kernel_calls = len(cuts) - 1
+        self.triples = schedule.num_triples
         if backend in ("pallas", "pallas_interpret"):
-            a_slot, b_slot, panel, sub_row, start, _ = pad_schedule_arrays(
+            self._sched = _stage_slices(pad_schedule_arrays(
                 schedule.a_slot, schedule.b_slot, schedule.panel,
                 schedule.sub_row, schedule.start, schedule.n_panels,
-            )
-            self._sched = tuple(
-                jnp.asarray(x) for x in (a_slot, b_slot, panel, sub_row, start)
-            )
+            )[:5], cuts)
         else:
-            self._sched = self._sched_jnp
+            self._sched = _stage_slices((
+                schedule.a_slot, schedule.b_slot, schedule.panel,
+                schedule.sub_row,
+            ), cuts)
         # Rebind maps: the plan's [nnz] flat scatter indices, as they are.
         self._a_scatter = (
             jnp.asarray(a_scatter, jnp.int32) if a_scatter is not None
@@ -584,15 +571,15 @@ class SpGEMMExecutor:
             interpret=self._interpret,
         )
 
-    # -- pipeline protocol (stage-split, non-blocking until collect) -------
+    # -- pipeline protocol (non-blocking until collect) --------------------
     #
     # ``mode`` for pipe_stage: "values" ([nnz] vectors, element plans),
     # "batch_values" ([batch, nnz]), "batch_blocks" ([batch, slots, ...]
     # packed blocks). Single-shot block operands are staged by the plan's
     # ``_stage_a``/``_stage_b`` hooks and enter at pipe_kernel directly.
-    # ``mode`` for kernel/assemble/collect: "single" or "batch". Both
-    # dispatch on the plan's backend (like ``run``/``run_batch``): pallas
-    # plans run the scalar-prefetch grid, batch-folded in batch mode.
+    # ``mode`` for kernel/collect: "single" or "batch". Both dispatch on
+    # the plan's backend (like ``run``/``run_batch``): pallas plans run the
+    # scalar-prefetch grid, batch-folded in batch mode.
 
     def pipe_stage(self, a, b, *, mode: str):
         """H2D transfer + value-rebind dispatch; returns staged device
@@ -613,33 +600,25 @@ class SpGEMMExecutor:
             )
         if mode == "batch_blocks":
             return (
-                jnp.asarray(a).reshape((-1,) + self.a_shape[1:]),
-                jnp.asarray(b).reshape((-1,) + self.b_shape[1:]),
+                jnp.asarray(a).reshape((-1,) + self.a_shape),
+                jnp.asarray(b).reshape((-1,) + self.b_shape),
             )
         raise ValueError(f"unknown stage mode {mode!r}")  # pragma: no cover
 
     def pipe_kernel(self, staged, *, mode: str):
-        """Scheduled-kernel dispatch over staged blocks; non-blocking."""
+        """Kernel and assembly over staged blocks, one program (the fused
+        core from blocks: ``run``'s, or ``run_batch``'s with
+        ``rebind=False``); returns packed C values without blocking."""
         a_blocks, b_blocks = staged
         if mode == "single":
-            return kernel_core(
-                a_blocks, b_blocks, self._sched,
-                n_panels=self.n_panels, group=self.group,
-                backend=self.backend, interpret=self._interpret,
-            )
-        return kernel_batch_core(
-            a_blocks, b_blocks, self._sched,
-            a_slots=self.a_shape[0], b_slots=self.b_shape[0],
-            n_panels=self.n_panels, group=self.group,
-            backend=self.backend, interpret=self._interpret,
+            return self.run(a_blocks, b_blocks)
+        return numeric_core_batch(
+            a_blocks, b_blocks, self._a_scatter, self._b_scatter,
+            self._sched, self._gather,
+            a_shape=self.a_shape, b_shape=self.b_shape, rebind=False,
+            n_panels=self.n_panels, group=self.group, backend=self.backend,
+            interpret=self._interpret,
         )
-
-    def pipe_assemble(self, panels, *, mode: str):
-        """Output-assembly gather dispatch; non-blocking."""
-        if mode == "single":
-            return assemble_core(panels, self._gather)
-        return assemble_batch_core(panels, self._gather,
-                                   n_panels=self.n_panels)
 
     def pipe_collect(self, packed, *, mode: str) -> np.ndarray:
         """Materialize packed C values on host (the only blocking step)."""
@@ -653,107 +632,88 @@ def shard_program(
 ):
     """One jitted ``shard_map`` program of the sharded numeric phase.
 
-    ``kind`` names the program: the fused ``run`` / ``run_values`` /
-    ``batch_values`` / ``batch_blocks`` cores, or a pipeline stage
-    (``bind``, ``bind_batch``, ``kernel``, ``kernel_batch``, ``assemble``,
-    ``assemble_batch``). Every input is ``[n_shards, ...]`` stacked over
-    ``axis`` except the replicated B side. Built from shapes and the mesh
-    only, so it can be lowered for devices that are described rather than
-    attached; :class:`ShardedSpGEMMExecutor` caches one per kind."""
+    ``kind`` names the program: the fused cores from staged blocks
+    (``kernel``, ``batch_blocks``: the kernel and the assembly, which
+    ``run`` / ``run_batch`` and the pipeline's ``pipe_kernel`` run) or from
+    values (``run_values``, ``batch_values``), or the pipeline's bind
+    (``bind``, ``bind_batch``). Every input is ``[n_shards, ...]`` stacked over
+    ``axis`` except the replicated B side; the schedule ``sched`` is one
+    argument, a tuple of slices of five ``[n_shards, t]`` arrays, one
+    kernel call each. Built from shapes and the mesh only, so it can be
+    lowered for devices that are described rather than attached;
+    :class:`ShardedSpGEMMExecutor` caches one per kind."""
     ax = axis
     bm, bk = a_shape[1], a_shape[2]
-    # Every shard-local schedule is padded to (t_max, p_max), so on
-    # pallas backends each device runs its own scalar-prefetch grid
-    # over p_max + 1 panels — the same panel count the jnp reference
-    # produces, keeping stage outputs shape-identical across backends.
-    # The shard's own dummy triples target panel p_max (never gathered);
-    # the impl-level dummy p_max + 1 is stripped inside the call.
+    # Every shard-local schedule slice is padded to its widest shard and
+    # to p_max panels, so on pallas backends each device runs its own
+    # scalar-prefetch grids over p_max + 1 panels — the same panel count
+    # the jnp reference produces, keeping stage outputs shape-identical
+    # across backends. The shard's own dummy triples target panel p_max
+    # (never gathered); the impl-level dummy p_max + 1 is stripped inside
+    # the call.
 
-    def sched(a_slot, b_slot, panel, sub_row, strt):
+    def local(sched):
+        """This device's rows of the stacked slices, as the backend's
+        kernel takes them (jnp reads no start flags)."""
+        rows = tuple(tuple(x[0] for x in piece) for piece in sched)
         if backend in ("pallas", "pallas_interpret"):
-            return a_slot, b_slot, panel, sub_row, strt
-        return a_slot, b_slot, panel, sub_row
+            return rows
+        return tuple(piece[:4] for piece in rows)
 
-    def sched_kernel(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
-                     strt):
-        return _run_schedule(
-            a_blocks, b_blocks, sched(a_slot, b_slot, panel, sub_row, strt),
+    def kernel(a_blocks, b_blocks, sched, gth):
+        panels = _run_schedule(
+            a_blocks, b_blocks, local(sched),
             n_panels=p_max + 1, group=group, backend=backend,
             interpret=interpret,
-        )
-
-    def sched_kernel_batch(a_blocks, b_blocks, a_slot, b_slot, panel,
-                           sub_row, strt, bsz):
-        return _run_schedule_batch(
-            a_blocks, b_blocks, sched(a_slot, b_slot, panel, sub_row, strt),
-            bsz, a_max, b_shape[0],
-            n_panels=p_max + 1, group=group, backend=backend,
-            interpret=interpret,
-        )
-
-    def kernel(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt,
-               gth):
-        panels = sched_kernel(
-            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt
         )
         return _assemble(panels, gth)
 
-    def kernel_batch(a_blocks, b_blocks, a_slot, b_slot, panel, sub_row,
-                     strt, gth, bsz):
-        panels = sched_kernel_batch(
-            a_blocks, b_blocks, a_slot, b_slot, panel, sub_row, strt, bsz
+    def kernel_batch(a_blocks, b_blocks, sched, gth, bsz):
+        panels = _run_schedule_batch(
+            a_blocks, b_blocks, local(sched), bsz, a_max, b_shape[0],
+            n_panels=p_max + 1, group=group, backend=backend,
+            interpret=interpret,
         )
         return _assemble(panels, gth, bsz)
 
     out = P(ax)
     # pallas_call has no shard_map replication rule, so the programs
     # that contain the kernel disable the replication check on pallas
-    # backends; bind/assemble programs keep the check on.
+    # backends; the bind programs keep the check on.
     vma = True
-    if kind == "run":
-        def body(a_bl, b_bl, a_slot, b_slot, panel, sub_row, strt, gth):
-            return kernel(a_bl[0], b_bl, a_slot[0], b_slot[0], panel[0],
-                          sub_row[0], strt[0], gth[0])[None]
-        specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax))
+    if kind == "kernel":
+        def body(a_bl, b_bl, sched, gth):
+            return kernel(a_bl[0], b_bl, sched, gth[0])[None]
+        specs = (P(ax), P(), P(ax), P(ax))
         vma = False
     elif kind == "run_values":
-        def body(a_vals, b_vals, a_sc, b_sc, a_slot, b_slot, panel,
-                 sub_row, strt, gth):
+        def body(a_vals, b_vals, a_sc, b_sc, sched, gth):
             a_bl = _bind(a_vals[0], a_sc[0], (a_max, bm, bk), mode="drop")
             b_bl = _bind(b_vals, b_sc, b_shape)
-            return kernel(a_bl, b_bl, a_slot[0], b_slot[0], panel[0],
-                          sub_row[0], strt[0], gth[0])[None]
-        specs = (P(ax), P(), P(ax), P(), P(ax), P(ax), P(ax), P(ax),
-                 P(ax), P(ax))
+            return kernel(a_bl, b_bl, sched, gth[0])[None]
+        specs = (P(ax), P(), P(ax), P(), P(ax), P(ax))
         vma = False
     elif kind == "batch_values":
-        def body(a_vals, b_vals, a_sc, b_sc, a_slot, b_slot, panel,
-                 sub_row, strt, gth):
+        def body(a_vals, b_vals, a_sc, b_sc, sched, gth):
             bsz = a_vals.shape[1]
             a_bl = _bind_batch(a_vals[0], a_sc[0], (a_max, bm, bk),
                                mode="drop")
             b_bl = _bind_batch(b_vals, b_sc, b_shape)
-            return kernel_batch(a_bl, b_bl, a_slot[0], b_slot[0],
-                                panel[0], sub_row[0], strt[0], gth[0],
-                                bsz)[None]
-        specs = (P(ax), P(), P(ax), P(), P(ax), P(ax), P(ax), P(ax),
-                 P(ax), P(ax))
+            return kernel_batch(a_bl, b_bl, sched, gth[0], bsz)[None]
+        specs = (P(ax), P(), P(ax), P(), P(ax), P(ax))
         vma = False
     elif kind == "batch_blocks":
-        def body(a_vals, b_vals, a_slot, b_slot, panel, sub_row, strt,
-                 gth):
+        def body(a_vals, b_vals, sched, gth):
             bsz = a_vals.shape[1]
             a_bl = a_vals[0].reshape((bsz * a_max, bm, bk))
             b_bl = b_vals.reshape(
                 (bsz * b_shape[0],) + tuple(b_shape[1:]))
-            return kernel_batch(a_bl, b_bl, a_slot[0], b_slot[0],
-                                panel[0], sub_row[0], strt[0], gth[0],
-                                bsz)[None]
-        specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax), P(ax))
+            return kernel_batch(a_bl, b_bl, sched, gth[0], bsz)[None]
+        specs = (P(ax), P(), P(ax), P(ax))
         vma = False
-    # -- stage-split kinds (the pipeline protocol): same ops as the
-    # fused bodies above, one shard_map program per stage so staging
-    # step s+1 dispatches independently of step s's kernel.
+    # -- the pipeline's bind kinds: the fused bodies' bind, one shard_map
+    # program of its own so staging step s+1 dispatches independently of
+    # step s's kernel (which then runs as ``kernel`` / ``batch_blocks``).
     elif kind == "bind":
         def body(a_vals, b_vals, a_sc, b_sc):
             a_bl = _bind(a_vals[0], a_sc[0], (a_max, bm, bk), mode="drop")
@@ -773,34 +733,6 @@ def shard_program(
             )
         specs = (P(ax), P(), P(ax), P())
         out = (P(ax), P())
-    elif kind == "kernel":
-        def body(a_bl, b_bl, a_slot, b_slot, panel, sub_row, strt):
-            return sched_kernel(
-                a_bl[0], b_bl, a_slot[0], b_slot[0], panel[0],
-                sub_row[0], strt[0],
-            )[None]
-        specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax))
-        vma = False
-    elif kind == "kernel_batch":
-        def body(a_bl, b_bl, a_slot, b_slot, panel, sub_row, strt):
-            bsz = a_bl.shape[1]
-            return sched_kernel_batch(
-                a_bl[0].reshape((bsz * a_max, bm, bk)),
-                b_bl.reshape((bsz * b_shape[0],) + tuple(b_shape[1:])),
-                a_slot[0], b_slot[0], panel[0], sub_row[0], strt[0],
-                bsz,
-            )[None]
-        specs = (P(ax), P(), P(ax), P(ax), P(ax), P(ax), P(ax))
-        vma = False
-    elif kind == "assemble":
-        def body(panels, gth):
-            return _assemble(panels[0], gth[0])[None]
-        specs = (P(ax), P(ax))
-    elif kind == "assemble_batch":
-        def body(panels, gth):
-            bsz = panels.shape[1] // (p_max + 1)
-            return _assemble(panels[0], gth[0], bsz)[None]
-        specs = (P(ax), P(ax))
     else:  # pragma: no cover - internal
         raise ValueError(kind)
 
@@ -809,6 +741,28 @@ def shard_program(
     return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=specs, out_specs=out, check_vma=vma,
     ))
+
+
+def _stack_shard_slices(shards, t_max, p_max):
+    """The stacked shard schedules (:func:`stack_shard_schedules`) in
+    slices of one kernel call each: every shard's schedule is cut at its
+    own panel starts (:func:`schedule_cuts`), and slice ``k`` stacks each
+    shard's ``k``-th piece, padded with dummy triples to the widest piece
+    (all dummies on a shard with fewer pieces). One piece per shard gives
+    the stacked schedule as it is."""
+    full = stack_shard_schedules(shards, t_max, p_max)
+    cuts = [schedule_cuts(sh.schedule.start) for sh in shards]
+    fill = (0, 0, p_max, 0, 1)  # a dummy triple, as the stacking pads
+    out = []
+    for k in range(max(len(c) for c in cuts) - 1):
+        spans = [(c[k], c[k + 1]) if k + 1 < len(c) else (0, 0) for c in cuts]
+        width = max(1, max(hi - lo for lo, hi in spans))
+        piece = tuple(np.full((len(shards), width), f, np.int32) for f in fill)
+        for i, (lo, hi) in enumerate(spans):
+            for dst, src in zip(piece, full):
+                dst[i, : hi - lo] = src[i, lo:hi]
+        out.append(piece)
+    return tuple(out)
 
 
 class ShardedSpGEMMExecutor:
@@ -901,10 +855,13 @@ class ShardedSpGEMMExecutor:
         # Stacked, padded schedule [n_shards, t_max] incl. per-shard start
         # flags (stack_shard_schedules): pads execute a real (block 0) x
         # (block 0) matmul into the dummy panel p_max, which no gather
-        # reads; start=1 on pads keeps the pallas accumulator clean.
+        # reads; start=1 on pads keeps the pallas accumulator clean. Staged
+        # in slices of one kernel call each (_stack_shard_slices).
+        slices = _stack_shard_slices(shards, self._t_max, self._p_max)
+        self.kernel_calls = len(slices)
+        self.triples = sum(sh.num_triples for sh in shards)
         self._sched = tuple(
-            put(x, self._sep)
-            for x in stack_shard_schedules(shards, self._t_max, self._p_max)
+            tuple(put(x, self._sep) for x in piece) for piece in slices
         )
         gdtype = np.result_type(*(asm.gather.dtype for asm in assemblies))
         gather = np.zeros((self._s, self._c_max), gdtype)
@@ -1054,7 +1011,7 @@ class ShardedSpGEMMExecutor:
         (the sharded plan's device staging hooks).
         """
         out = np.asarray(
-            self._fn("run")(a_staged, b_staged, *self._sched, self._gather)
+            self._fn("kernel")(a_staged, b_staged, self._sched, self._gather)
         )
         return self._concat(out)
 
@@ -1065,7 +1022,7 @@ class ShardedSpGEMMExecutor:
             self._slice_a_vals(np.asarray(a_vals)), self._sep)
         b_d = jax.device_put(np.asarray(b_vals), self._rep)
         out = np.asarray(self._fn("run_values")(
-            a_sh, b_d, self._a_scatter, self._b_scatter, *self._sched,
+            a_sh, b_d, self._a_scatter, self._b_scatter, self._sched,
             self._gather,
         ))
         return self._concat(out)
@@ -1080,14 +1037,14 @@ class ShardedSpGEMMExecutor:
             a_sh = jax.device_put(self._slice_a_vals(a_vals), self._sep)
             b_d = jax.device_put(b_vals, self._rep)
             out = np.asarray(self._fn("batch_values")(
-                a_sh, b_d, self._a_scatter, self._b_scatter, *self._sched,
+                a_sh, b_d, self._a_scatter, self._b_scatter, self._sched,
                 self._gather,
             ))
         else:
             a_sh = jax.device_put(self._stack_a(a_vals), self._sep)
             b_d = jax.device_put(b_vals, self._rep)
             out = np.asarray(self._fn("batch_blocks")(
-                a_sh, b_d, *self._sched, self._gather
+                a_sh, b_d, self._sched, self._gather
             ))
         return self._concat(out)
 
@@ -1119,15 +1076,12 @@ class ShardedSpGEMMExecutor:
         raise ValueError(f"unknown stage mode {mode!r}")  # pragma: no cover
 
     def pipe_kernel(self, staged, *, mode: str):
-        """Per-shard scheduled-kernel dispatch (one shard_map program)."""
+        """Per-shard kernel and assembly over staged blocks, one shard_map
+        program (``run``'s, or ``run_batch``'s from blocks); returns the
+        stacked packed C values without blocking."""
         a_bl, b_bl = staged
-        kind = "kernel" if mode == "single" else "kernel_batch"
-        return self._fn(kind)(a_bl, b_bl, *self._sched)
-
-    def pipe_assemble(self, panels, *, mode: str):
-        """Per-shard output-assembly gather dispatch."""
-        kind = "assemble" if mode == "single" else "assemble_batch"
-        return self._fn(kind)(panels, self._gather)
+        kind = "kernel" if mode == "single" else "batch_blocks"
+        return self._fn(kind)(a_bl, b_bl, self._sched, self._gather)
 
     def pipe_collect(self, packed, *, mode: str) -> np.ndarray:
         """Blocking D2H + per-shard pad trim + host concatenation."""

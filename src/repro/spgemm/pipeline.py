@@ -1,5 +1,5 @@
-"""Pipelined async SpGEMM serving: submit/collect over the stage-split
-executor.
+"""Pipelined async SpGEMM serving: submit/collect over the executor's
+pipeline protocol.
 
 FSpGEMM's throughput trick (PAPER Sec. 4) is operand double-buffering:
 while one partial product computes, the next rows' operands are already
@@ -9,8 +9,8 @@ stall in host form — rebind, H2D, kernel, assembly, and D2H serialized
 per step. :class:`SpGEMMPipeline` removes it:
 
 * ``submit(a_vals, b_vals)`` *dispatches* a step — H2D staging + value
-  rebind, the scheduled kernel, and output assembly, each its own device
-  program (``repro.spgemm.executor``'s ``pipe_*`` protocol) — and returns
+  rebind as one device program, the scheduled kernel and output assembly
+  as another (``repro.spgemm.executor``'s ``pipe_*`` protocol) — and returns
   a :class:`SpGEMMTicket` immediately. Nothing blocks: JAX async dispatch
   queues the programs, so step ``s + 1``'s staging overlaps step ``s``'s
   kernel, and each in-flight step owns its own staged packed A/B block
@@ -24,8 +24,8 @@ per step. :class:`SpGEMMPipeline` removes it:
   ``stream(value_iter)`` / ``__iter__`` manage the bound for you,
   yielding ordered results.
 
-Results are **bitwise-equal** to sequential ``execute`` calls: the stage
-jits run exactly the fused cores' ops, and submission is stateless with
+Results are **bitwise-equal** to sequential ``execute`` calls: the
+pipeline's jits run exactly the fused cores' ops, and submission is stateless with
 respect to the plan's staged values (like ``execute_batch``), so a
 pipelined stream of N steps reproduces N synchronous executes exactly —
 on element, block, batched, and sharded plans.

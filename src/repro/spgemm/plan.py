@@ -124,6 +124,7 @@ _REPORT_FIELDS = (
     "nnz_a", "nnz_b", "nnzb_a", "nnzb_b", "nnzb_c", "num_triples",
     "n_panels", "b_fetches", "block_omar", "schedule_builds", "cache_hits",
     "executes", "loads", "load_hits", "config_source", "tuned",
+    "kernel_calls",
 )
 
 
@@ -171,6 +172,8 @@ class PlanReport:
         # "env-override" (REPRO_SPGEMM_CHUNK_BYTES wins regardless)
         tuned: Optional[dict] = None,  # TunedConfig.to_meta() snapshot of
         # the applied tuned config (None when untuned)
+        kernel_calls: int = 0,  # kernel calls one product runs: the
+        # schedule's slices that one call's SMEM holds (0: no executor)
     ):
         self._pattern_key = pattern_key
         self._nnz_a = nnz_a
@@ -194,6 +197,7 @@ class PlanReport:
         self.pattern_token = pattern_token
         self.config_source = config_source
         self.tuned = tuned
+        self.kernel_calls = kernel_calls
 
     @property
     def pattern_key(self) -> str:
@@ -330,6 +334,8 @@ class SpGEMMPlan:
             if schedule.num_triples and self.assembly.nnz
             else None
         )
+        if self._executor is not None:
+            report.kernel_calls = self._executor.kernel_calls
         # Device block values are staged lazily (first execute) so building
         # a plan never pays H2D for values that are immediately rebound.
         self._a_dev = None
@@ -824,19 +830,23 @@ class SpGEMMPlan:
     # enqueue.
     _RUN_SPAN = "spgemm.dispatch"
 
-    def _dispatch_span(self, step: int, *sent, bind_sets: int = 0,
-                       name: str = "spgemm.dispatch"):
-        """``name`` around one call into the executor: the H2D of the host
-        arrays ``sent`` plus the jit enqueue. Where the device binds
-        ``bind_sets`` value sets, ``sent`` is their values, and the span
-        counts the values the bind scatters and the block slots of the
-        zeroed arrays it scatters them into (their ratio is the block
-        fill)."""
+    def _dispatch_span(self, step: int, *sent, sets: int = 1,
+                       bind_sets: int = 0, name: str = "spgemm.dispatch"):
+        """``name`` around one call into the executor for ``sets`` value
+        sets: the H2D of the host arrays ``sent`` plus the jit enqueue.
+        Where the device binds ``bind_sets`` value sets, ``sent`` is their
+        values, and the span counts the values the bind scatters and the
+        block slots of the zeroed arrays it scatters them into (their
+        ratio is the block fill). ``kernel_calls`` is the Pallas calls the
+        call dispatches (the schedule's slices), ``triples`` the block
+        triples it runs."""
         return TraceAnnotation(
             name, step=step,
             h2d_bytes=sum(x.nbytes for x in sent),
             bind_values=sum(x.size for x in sent) if bind_sets else 0,
             bind_slots=bind_sets * self._bind_slots,
+            kernel_calls=self._executor.kernel_calls,
+            triples=sets * self._executor.triples,
         )
 
     def _run_packed(self, a_vals=None, b_vals=None):
@@ -1022,7 +1032,8 @@ class SpGEMMPlan:
                 # placement on sharded plans).
                 a, b = a_vals[lo:hi], b_vals[lo:hi]
                 with self._dispatch_span(
-                        step, a, b, bind_sets=hi - lo if rebind else 0,
+                        step, a, b, sets=hi - lo,
+                        bind_sets=hi - lo if rebind else 0,
                         name=self._RUN_SPAN):
                     packed = self._executor.run_batch(a, b, rebind=rebind)
                 with TraceAnnotation("spgemm.collect", step=step):
@@ -1031,7 +1042,7 @@ class SpGEMMPlan:
                                for i in range(hi - lo))
             return out
 
-    # -- async serving (the stage-split pipeline surface) ------------------
+    # -- async serving (the executor's pipeline surface) -------------------
 
     def pipeline(self, depth: Optional[int] = None) -> SpGEMMPipeline:
         """A bounded-depth submit/collect pipeline over this plan.
@@ -1166,8 +1177,8 @@ class SpGEMMPlan:
             self._inflight -= 1
 
     def _pipe_dispatch(self, prep: _Prepared, step: int):
-        """Dispatch one prepared step's device work (stage -> kernel ->
-        assemble) without blocking; returns the packed device result (a
+        """Dispatch one prepared step's device work (stage -> kernel and
+        assembly) without blocking; returns the packed device result (a
         list of per-chunk results for batch submissions). ``step`` is the
         pipeline index its spans carry."""
         if self._executor is None or (prep.batch == 0):
@@ -1176,12 +1187,10 @@ class SpGEMMPlan:
         if prep.batch is None:
             if prep.mode == "blocks":  # staged by _pipe_check
                 with self._dispatch_span(step, *prep.sent):
-                    panels = ex.pipe_kernel((prep.a, prep.b), mode="single")
-                    return ex.pipe_assemble(panels, mode="single")
+                    return ex.pipe_kernel((prep.a, prep.b), mode="single")
             with self._dispatch_span(step, prep.a, prep.b, bind_sets=1):
                 staged = ex.pipe_stage(prep.a, prep.b, mode=prep.mode)
-                panels = ex.pipe_kernel(staged, mode="single")
-                return ex.pipe_assemble(panels, mode="single")
+                return ex.pipe_kernel(staged, mode="single")
         # Batch submissions chunk exactly like execute_batch, so the
         # device accumulator working set stays cache-resident; each chunk
         # is dispatched back-to-back (still zero host blocking).
@@ -1191,10 +1200,10 @@ class SpGEMMPlan:
             hi = min(lo + chunk, prep.batch)
             a, b = prep.a[lo:hi], prep.b[lo:hi]
             bind = hi - lo if prep.mode == "batch_values" else 0
-            with self._dispatch_span(step, a, b, bind_sets=bind):
+            with self._dispatch_span(step, a, b, sets=hi - lo,
+                                     bind_sets=bind):
                 staged = ex.pipe_stage(a, b, mode=prep.mode)
-                panels = ex.pipe_kernel(staged, mode="batch")
-                out.append(ex.pipe_assemble(panels, mode="batch"))
+                out.append(ex.pipe_kernel(staged, mode="batch"))
         return out
 
     def _pipe_collect(self, prep: _Prepared, packed, step: int):

@@ -40,8 +40,8 @@ class TestGustavsonSpGEMM:
             sch.n_panels)
         panels = spgemm_scheduled(
             jnp.asarray(a.blocks), jnp.asarray(b.blocks),
-            jnp.asarray(a_slot), jnp.asarray(b_slot), jnp.asarray(panel),
-            jnp.asarray(sub_row), jnp.asarray(start),
+            ((jnp.asarray(a_slot), jnp.asarray(b_slot), jnp.asarray(panel),
+              jnp.asarray(sub_row), jnp.asarray(start)),),
             n_panels=sch.n_panels, group=group, interpret=True)
         oracle = ref.spgemm_scheduled_ref(
             jnp.asarray(a.blocks), jnp.asarray(b.blocks),

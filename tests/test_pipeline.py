@@ -137,6 +137,35 @@ class TestBitwiseEquality:
 
 
 class TestPipelineSemantics:
+    def test_kernel_and_assembly_run_as_one_program(self, monkeypatch):
+        """After the bind, a step runs the fused core from the bound
+        blocks: the kernel and the assembly in one program, so the panel
+        array is never a buffer passed between two programs."""
+        from repro.spgemm import executor
+
+        plan = _element_plan(seed=61)
+        stream = SpGEMMValueStream(plan.a_pattern, plan.b_pattern, seed=3)
+        vals = [stream.values_at(s) for s in range(4)]
+        seq = [plan.execute(*v) for v in vals[:2]]
+        batch = plan.execute_batch(np.stack([v[0] for v in vals[2:]]),
+                                   np.stack([v[1] for v in vals[2:]]))
+        calls = []
+        for name in ("numeric_core", "numeric_core_batch"):
+            real = getattr(executor, name)
+
+            def spy(*args, _real=real, _name=name, **kwargs):
+                calls.append(_name)
+                return _real(*args, **kwargs)
+
+            monkeypatch.setattr(executor, name, spy)
+        with plan.pipeline(depth=2) as pipe:
+            out = list(pipe.stream(vals[:2]))
+            got = pipe.submit(np.stack([v[0] for v in vals[2:]]),
+                              np.stack([v[1] for v in vals[2:]])).result()
+        assert calls == ["numeric_core", "numeric_core", "numeric_core_batch"]
+        for c_seq, c_pipe in zip(seq + batch, out + got):
+            _assert_same_csr(c_seq, c_pipe)
+
     def test_out_of_order_collect(self):
         plan = _element_plan(seed=41)
         stream = SpGEMMValueStream(plan.a_pattern, plan.b_pattern, seed=2)

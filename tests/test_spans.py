@@ -23,12 +23,12 @@ sys.path.insert(0, ROOT)
 
 from bench import spans, trace  # noqa: E402
 from bench.matrices import hpcg27  # noqa: E402
+from repro.core import perfmodel  # noqa: E402
 from repro.sparse.formats import COO  # noqa: E402
 from repro.spgemm import PlanCache, spgemm_plan  # noqa: E402
 from repro.spgemm.executor import (  # noqa: E402
-    assemble_core,
     bind_core,
-    kernel_core,
+    numeric_core,
     numeric_core_batch,
     numeric_core_values,
     shard_program,
@@ -187,6 +187,44 @@ def test_execute_batch_spans(plan, tmp_path):
         _check_collect(found, collect)
 
 
+def test_dispatch_and_run_count_kernel_calls_and_triples(plan, tmp_path):
+    """``spgemm.dispatch`` and a sharded plan's ``spgemm.run`` carry the
+    Pallas calls the product dispatches and the block triples it runs:
+    one call on the plan within the budget, one a slice on a plan whose
+    schedule is cut to a third of it."""
+    t = plan.schedule.num_triples
+    a_vals, b_vals = _values(plan, 8)
+    found = _traced(tmp_path, lambda: plan.execute(a_vals, b_vals))
+    args = _one(found, "spgemm.dispatch")[3]
+    assert (args["kernel_calls"], args["triples"]) == (1, t)
+    panel_end = np.append(np.flatnonzero(plan.schedule.start), t)
+    budget = max(int(np.diff(panel_end).max()), t // 3)
+    a = COO(plan.a_pattern.row, plan.a_pattern.col, a_vals, plan.a_pattern.shape)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(perfmodel, "SCHEDULE_TRIPLES_PER_CALL", budget)
+        split = spgemm_plan(a, a, tile=16, group=2, backend="pallas_interpret",
+                            output="compact", cache=PlanCache())
+        sharded = spgemm_plan(a, a, tile=16, group=2, backend="pallas_interpret",
+                              output="compact", cache=PlanCache(),
+                              mesh=Mesh(np.array(jax.devices()[:1]), ("shard",)))
+    calls = split.report.kernel_calls
+    assert calls >= 3 and sharded.report.kernel_calls == calls
+    found = _traced(tmp_path, lambda: split.execute(a_vals, a_vals))
+    args = _one(found, "spgemm.dispatch")[3]
+    assert (args["kernel_calls"], args["triples"]) == (calls, t)
+    batch = np.stack([a_vals, b_vals])
+    found = _traced(tmp_path, lambda: split.execute_batch(batch, batch))
+    args = _one(found, "spgemm.dispatch")[3]
+    assert (args["kernel_calls"], args["triples"]) == (calls, 2 * t)
+    found = _traced(tmp_path, lambda: list(split.execute_stream(
+        [(a_vals, a_vals)] * 2, depth=2)))
+    assert [(sp[3]["kernel_calls"], sp[3]["triples"]) for sp in found
+            if sp[0] == "spgemm.dispatch"] == [(calls, t)] * 2
+    found = _traced(tmp_path, lambda: sharded.execute(a_vals, a_vals))
+    args = _one(found, "spgemm.run")[3]
+    assert (args["kernel_calls"], args["triples"]) == (calls, t)
+
+
 def test_spans_reduce_to_the_layer_metrics(plan, tmp_path):
     """The readers of ``bench/metrics`` find the spans of a real CPU trace
     (the CPU has no device plane, so device scopes read nothing)."""
@@ -226,7 +264,8 @@ def test_compiled_programs_carry_the_stage_scopes(program):
     chip's compile of the Pallas programs: tests/test_tpu_compile.py)."""
     backend = "jnp"
     shape = (4, 8, 8)
-    sched = tuple(jnp.zeros(6, jnp.int32) for _ in range(4))
+    piece = tuple(jnp.zeros(6, jnp.int32) for _ in range(4))
+    sched = (piece,)  # the schedule as one call's slice
     scatter = jnp.arange(10, dtype=jnp.int32)  # the bind's [nnz] map
     statics = dict(n_panels=2, group=2, backend=backend, interpret=False)
     if program == "fused":
@@ -236,9 +275,8 @@ def test_compiled_programs_carry_the_stage_scopes(program):
     elif program == "stages":
         blocks = jnp.ones(shape)
         found = (_scopes_in(bind_core.lower(jnp.ones(10), scatter, shape=shape))
-                 | _scopes_in(kernel_core.lower(blocks, blocks, sched, **statics))
-                 | _scopes_in(assemble_core.lower(jnp.ones((3, 16, 8)),
-                                                  jnp.zeros(20, jnp.int32))))
+                 | _scopes_in(numeric_core.lower(blocks, blocks, sched,
+                                                 jnp.zeros(20, jnp.int32), **statics)))
     elif program == "batch":
         found = _scopes_in(numeric_core_batch.lower(
             jnp.ones((2, 10)), jnp.ones((2, 10)), scatter, scatter, sched,
@@ -251,6 +289,6 @@ def test_compiled_programs_carry_the_stage_scopes(program):
                            a_shape=shape, b_shape=shape)
         found = _scopes_in(fn.lower(
             jnp.ones((1, 10)), jnp.ones(10), scatter[None], scatter,
-            *(x[None] for x in sched), jnp.zeros(6, jnp.int32)[None],
+            (tuple(x[None] for x in piece) + (jnp.zeros(6, jnp.int32)[None],),),
             jnp.zeros((1, 20), jnp.int32)))
     assert found == set(SCOPES)

@@ -165,17 +165,35 @@ class TestKernelSmemBudget:
         assert "kernel.smem-schedule" not in _checks(
             lint_plan_kernel_specs(plan))
 
-    def test_schedule_over_smem_is_an_error(self):
+    def test_schedule_over_smem_is_an_error(self, monkeypatch):
+        """A staged kernel call over the per-call budget is an error: the
+        plan's one call of 8,000 triples, judged against a budget of
+        5,000 set after it was built."""
+        from repro.core import perfmodel
+
+        plan = self._dense_plan(160)  # 8,000 triples, one call
+        assert plan.report.kernel_calls == 1
+        monkeypatch.setattr(perfmodel, "SCHEDULE_TRIPLES_PER_CALL", 5_000)
+        smem = [f for f in lint_plan_kernel_specs(plan)
+                if f.check == "kernel.smem-schedule"]
+        assert len(smem) == 1 and smem[0].severity == "error"
+        assert "8000 triples" in smem[0].message
+
+    def test_schedule_over_one_call_is_split_and_clean(self):
+        """64,000 triples are more than one call's SMEM holds: the
+        executor runs them as two calls, each within the budget."""
         from repro.core.perfmodel import (
-            SCHEDULE_SMEM_BYTES_PER_TRIPLE, TPU_SMEM_BYTES)
+            SCHEDULE_SMEM_BYTES_PER_TRIPLE, SCHEDULE_TRIPLES_PER_CALL,
+            TPU_SMEM_BYTES)
 
         plan = self._dense_plan(320)  # 64,000 triples
         t = plan.schedule.num_triples
         assert t * SCHEDULE_SMEM_BYTES_PER_TRIPLE > TPU_SMEM_BYTES
-        smem = [f for f in lint_plan_kernel_specs(plan)
-                if f.check == "kernel.smem-schedule"]
-        assert len(smem) == 1 and smem[0].severity == "error"
-        assert f"{t} triples" in smem[0].message
+        assert plan.report.kernel_calls == 2
+        calls = [piece[0].shape[0] for piece in plan._executor._sched]
+        assert sum(calls) == t and max(calls) <= SCHEDULE_TRIPLES_PER_CALL
+        assert "kernel.smem-schedule" not in _checks(
+            lint_plan_kernel_specs(plan))
 
 
 class TestScheduleFaultInjection:
