@@ -40,6 +40,11 @@ with numpy only:
    no duplicates), and bitwise re-derivable from the block assembly and
    the compact pattern via
    :func:`~repro.core.schedule.build_compact_map`.
+7. **Run-table coverage** (plans whose executor assembles run by run,
+   :mod:`repro.kernels.assemble_runs`) — decoded as the kernel reads it,
+   the run table writes every position of the active output map exactly
+   once, each from the panel slot the map's gather names, and no run
+   reads the dummy pad panel or past the end of a 128-lane panel row.
 
 Plans also surface configuration-provenance warnings here: a persisted
 tuned config whose symbolic facts no longer match the plan
@@ -59,10 +64,13 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from repro.core.schedule import (
+    LANES,
     AssemblyMap,
+    AssemblyRuns,
     SpGEMMSchedule,
     build_assembly_map,
     build_compact_map,
+    decode_assembly_runs,
     shards_from_bounds,
     shards_to_bounds,
 )
@@ -72,6 +80,7 @@ __all__ = [
     "Finding",
     "PlanVerificationError",
     "VerifyReport",
+    "check_assembly_runs",
     "check_compact",
     "verify_plan",
 ]
@@ -614,6 +623,67 @@ def check_compact(
                      "plan-wide compact map")
 
 
+def check_assembly_runs(
+    runs: AssemblyRuns,
+    gather: np.ndarray,
+    findings: List[Finding],
+    label: str = "runs",
+) -> None:
+    """Family 7: the run table against the map it was built from. Every
+    run is decoded as the kernel reads it; the values they place must
+    cover ``[0, nnz)`` exactly once, each from ``gather``'s slot, and no
+    run may read the dummy pad panel or wrap past a lane row's end (the
+    kernel's lane rotation would bring that row's first lanes)."""
+    gather = np.asarray(gather)
+    nnz = int(gather.shape[0])
+    if runs.nnz != nnz:
+        _err(findings, f"{label}.size",
+             f"run table holds {runs.nnz} values, the map {nnz}")
+        return
+    d = decode_assembly_runs(runs)
+    length = d["length"]
+    if (length < 1).any() or (d["lane"] + length > LANES).any():
+        i = int(np.argmax((length < 1) | (d["lane"] + length > LANES)))
+        _err(findings, f"{label}.lane-row",
+             f"run {i} ({int(length[i])} values from lane "
+             f"{int(d['lane'][i])}) is not inside one 128-lane row")
+        return
+    if (d["panel"] >= runs.n_panels).any() \
+            or (d["row"] >= runs.lane_rows).any():
+        i = int(np.argmax((d["panel"] >= runs.n_panels)
+                          | (d["row"] >= runs.lane_rows)))
+        _err(findings, f"{label}.pad-panel-read",
+             f"run {i} reads panel {int(d['panel'][i])} row "
+             f"{int(d['row'][i])}, outside the {runs.n_panels} real panels")
+        return
+    per_block = runs.block_rows * LANES
+    last = d["dest"] + length - 1
+    if (d["dest"] < 0).any() or (last >= nnz).any() \
+            or (d["dest"] // per_block != last // per_block).any():
+        _err(findings, f"{label}.bounds",
+             "a run writes outside its output block or past the map's end")
+        return
+    step = (np.arange(int(length.sum()))
+            - np.repeat(np.cumsum(length) - length, length))
+    dest = np.repeat(d["dest"], length) + step
+    times = np.bincount(dest, minlength=nnz)
+    if (times == 0).any():
+        _err(findings, f"{label}.dropped",
+             f"{int((times == 0).sum())} output value(s) no run writes, "
+             f"the first at {int(np.argmax(times == 0))}")
+    if (times > 1).any():
+        _err(findings, f"{label}.duplicate",
+             f"{int((times > 1).sum())} output value(s) written by more "
+             f"than one run, the first at {int(np.argmax(times > 1))}")
+    src = np.repeat(d["src"], length) + step
+    wrong = src != gather[dest]
+    if wrong.any():
+        i = int(np.argmax(wrong))
+        _err(findings, f"{label}.source",
+             f"output value {int(dest[i])} is placed from panel slot "
+             f"{int(src[i])}, the map gathers {int(gather[dest[i]])}")
+
+
 def _rebuild_cross_check(plan, findings: List[Finding]) -> None:
     """Re-derive the assembly map from the plan's own schedule and compare
     bitwise — the strongest corruption detector for persisted artifacts
@@ -672,6 +742,10 @@ def verify_plan(
     if getattr(plan, "compact", None) is not None:
         checks.append("compact")
         check_compact(plan, findings)
+    runs = getattr(getattr(plan, "_executor", None), "assembly_runs", None)
+    if runs is not None:
+        checks.append("runs")
+        check_assembly_runs(runs, plan._active().gather, findings)
     if rebuild_check:
         checks.append("assembly.rebuild")
         _rebuild_cross_check(plan, findings)

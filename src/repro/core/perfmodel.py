@@ -39,6 +39,8 @@ __all__ = [
     "SCHEDULE_SMEM_BYTES_PER_TRIPLE",
     "SCHEDULE_SMEM_MARGIN_BYTES",
     "SCHEDULE_TRIPLES_PER_CALL",
+    "ASSEMBLY_SMEM_BYTES_PER_ITEM",
+    "assembly_items_per_call",
     "roofline_seconds",
     "PAPER_TABLE7_MS",
     "PAPER_TABLE8_STUF",
@@ -154,6 +156,18 @@ SCHEDULE_SMEM_MARGIN_BYTES = 64 << 10
 SCHEDULE_TRIPLES_PER_CALL = (
     TPU_SMEM_BYTES - SCHEDULE_SMEM_MARGIN_BYTES
 ) // SCHEDULE_SMEM_BYTES_PER_TRIPLE
+# The run-wise assembly kernel's SMEM: three int32 scalar-prefetch arrays
+# a grid step (item), and one output block's runs, two int32 words each,
+# double-buffered.
+ASSEMBLY_SMEM_BYTES_PER_ITEM = 3 * 4
+
+
+def assembly_items_per_call(width: int) -> int:
+    """The most items one run-wise assembly call takes beside its
+    double-buffered run-table blocks of ``width`` runs."""
+    table = 2 * (2 * width * 4)
+    return (TPU_SMEM_BYTES - SCHEDULE_SMEM_MARGIN_BYTES - table) \
+        // ASSEMBLY_SMEM_BYTES_PER_ITEM
 
 
 def spgemm_grid_step_vmem(

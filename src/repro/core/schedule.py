@@ -40,13 +40,17 @@ from repro.sparse.formats import BCSR, BCSV
 
 __all__ = [
     "AssemblyMap",
+    "AssemblyRuns",
+    "LANES",
     "ScheduleShard",
     "SpGEMMSchedule",
     "assembly_from_arrays",
     "assembly_to_arrays",
     "build_assembly_map",
+    "build_assembly_runs",
     "build_compact_map",
     "build_spgemm_schedule",
+    "decode_assembly_runs",
     "partition_spgemm_schedule",
     "schedule_from_arrays",
     "schedule_to_arrays",
@@ -392,6 +396,183 @@ def build_compact_map(
     return AssemblyMap(
         assembly.gather[pos], indptr, cols.astype(np.int32), (m, n),
     )
+
+
+LANES = 128  # the TPU's lane width: the row length of the run-wise view
+
+# Field widths of a packed run (see :class:`AssemblyRuns`).
+_RUN_ROW_BITS = 16
+_RUN_DEST_BITS = 15
+_RUN_SHIFT_BITS = 7
+
+
+@dataclasses.dataclass
+class AssemblyRuns:
+    """An assembly map as runs of contiguous values, the form the run-wise
+    assembly kernel (:mod:`repro.kernels.assemble_runs`) places.
+
+    Viewed flat as rows of 128 lanes (*lane rows*), the kernel's panels
+    hold each C row's values of one block column side by side, so
+    ``gather`` is mostly stretches of consecutive indices. A *run* is a
+    maximal stretch inside one lane row, cut where the output enters its
+    next *block* of ``block_rows`` x 128 values. Each block's runs are read
+    from *tiles*: ``tile_panels`` consecutive panels times ``tile_rows``
+    consecutive lane rows of each, tile ``chunk * row_tiles + row_tile``.
+    One *item* (one grid step of the kernel) places the runs of one block
+    that come from one tile; items are sorted by block, then tile.
+
+    ``table[b]`` holds block ``b``'s runs, items in order and each item's
+    runs in output order, as two packed int32 words: ``table[b, j]`` =
+    ``panel_in_tile << 16 | row_in_tile`` and ``table[b, width + j]`` =
+    ``offset_in_block | lane_shift << 15 | length << 22``, where the
+    run's first source lane rotated by ``lane_shift`` lands on its output
+    lane. Item ``i``'s runs are ``table[item_block[i]]`` from the end of
+    the item before it in the same block (0 for a block's first item) to
+    ``item_end[i]``.
+    """
+
+    item_block: np.ndarray  # [n_items] int32 output block of each item
+    item_tile: np.ndarray  # [n_items] int32 source tile of each item
+    item_end: np.ndarray  # [n_items] int32 end of its runs in its block
+    table: np.ndarray  # [n_blocks, 2 * width] int32 packed runs
+    n_runs: int
+    nnz: int
+    n_panels: int
+    lane_rows: int  # lane rows per panel
+    tile_panels: int
+    tile_rows: int
+    block_rows: int
+
+    @property
+    def width(self) -> int:
+        return int(self.table.shape[1]) // 2
+
+    @property
+    def row_tiles(self) -> int:
+        return self.lane_rows // self.tile_rows
+
+    @property
+    def n_blocks(self) -> int:
+        return int(self.table.shape[0])
+
+    def nbytes(self) -> int:
+        return (self.item_block.nbytes + self.item_tile.nbytes
+                + self.item_end.nbytes + self.table.nbytes)
+
+
+def build_assembly_runs(
+    gather: np.ndarray,
+    *,
+    n_panels: int,
+    lane_rows: int,
+    tile_panels: int,
+    tile_rows: int,
+    block_rows: int,
+) -> AssemblyRuns:
+    """The run table of an assembly map's ``gather`` (see
+    :class:`AssemblyRuns`), over ``n_panels`` panels of ``lane_rows``
+    lane rows each. ``tile_rows`` divides ``lane_rows``, and a block holds
+    at most ``2**15`` values. Pure numpy, from the gather alone."""
+    if lane_rows % tile_rows or block_rows * LANES > 1 << _RUN_DEST_BITS \
+            or tile_rows > 1 << _RUN_ROW_BITS:
+        raise ValueError(
+            f"bad run geometry: {tile_rows} tile rows of {lane_rows}, "
+            f"{block_rows} block rows")
+    g = np.asarray(gather, np.int64)
+    nnz = int(g.shape[0])
+    geometry = dict(
+        nnz=nnz, n_panels=int(n_panels), lane_rows=int(lane_rows),
+        tile_panels=int(tile_panels), tile_rows=int(tile_rows),
+        block_rows=int(block_rows))
+    if not nnz:
+        none = np.zeros(0, np.int32)
+        return AssemblyRuns(none, none, none, np.zeros((0, 1024), np.int32),
+                            n_runs=0, **geometry)
+    per_block = block_rows * LANES
+    n_blocks = -(-nnz // per_block)
+    row_tiles = lane_rows // tile_rows
+    n_tiles = -(-n_panels // tile_panels) * row_tiles
+    # A run starts where the source is not the next lane of the same lane
+    # row, or where the output enters a new block.
+    cut = np.ones(nnz, bool)
+    cut[1:] = (g[1:] != g[:-1] + 1) | (g[1:] % LANES == 0)
+    cut[::per_block] = True
+    start = np.flatnonzero(cut)
+    del cut
+    length = np.diff(np.append(start, nnz))
+    src = g[start]
+    lane_row, lane = src // LANES, src % LANES
+    panel, row = lane_row // lane_rows, lane_row % lane_rows
+    block, offset = start // per_block, start % per_block
+    tile = (panel // tile_panels) * row_tiles + row // tile_rows
+    # Runs are in output order, so a stable sort by (block, tile) keeps
+    # each item's runs in output order.
+    key = block * n_tiles + tile
+    order = np.argsort(key, kind="stable")
+    key = key[order]
+    n_runs = int(key.shape[0])
+    first = np.ones(n_runs, bool)
+    first[1:] = key[1:] != key[:-1]
+    item_first = np.flatnonzero(first)
+    item_key = key[item_first]
+    run_block = key // n_tiles
+    counts = np.bincount(run_block, minlength=n_blocks)
+    block_start = np.cumsum(counts) - counts
+    slot = np.arange(n_runs, dtype=np.int64) - block_start[run_block]
+    item_end = slot[np.append(item_first[1:], n_runs) - 1] + 1
+    # A block's two words a run are one 1-D SMEM block, which Mosaic
+    # tiles by 1024 words: pad the runs of every block to a multiple of
+    # 512.
+    width = -(-int(counts.max(initial=1)) // 512) * 512
+    table = np.zeros((n_blocks, 2 * width), np.int32)
+    shift = (offset % LANES - lane) % LANES
+    table[run_block, slot] = (
+        (panel % tile_panels) << _RUN_ROW_BITS | row % tile_rows)[order]
+    table[run_block, width + slot] = (
+        offset | shift << _RUN_DEST_BITS
+        | length << (_RUN_DEST_BITS + _RUN_SHIFT_BITS))[order]
+    return AssemblyRuns(
+        item_block=(item_key // n_tiles).astype(np.int32),
+        item_tile=(item_key % n_tiles).astype(np.int32),
+        item_end=item_end.astype(np.int32),
+        table=table, n_runs=n_runs, **geometry,
+    )
+
+
+def decode_assembly_runs(runs: AssemblyRuns) -> Dict[str, np.ndarray]:
+    """Each run of ``runs`` as the kernel reads it, in item order: its
+    output position (``dest``), first source index into the flat panels
+    (``src``), ``length``, first source lane (``lane``), ``panel`` and
+    lane ``row`` in its panel. The source lane is where the kernel's lane
+    rotation puts the output lane from: a run is read right only if
+    ``lane + length <= 128``."""
+    blk = runs.item_block.astype(np.int64)
+    end = runs.item_end.astype(np.int64)
+    begin = np.zeros_like(end)
+    same = np.zeros(blk.shape, bool)
+    same[1:] = blk[1:] == blk[:-1]
+    begin[same] = end[np.flatnonzero(same) - 1]
+    counts = np.maximum(end - begin, 0)
+    item = np.repeat(np.arange(blk.shape[0]), counts)
+    slot = (np.arange(item.shape[0], dtype=np.int64)
+            - np.repeat(np.cumsum(counts) - counts, counts)
+            + begin[item])
+    w0 = runs.table[blk[item], slot].astype(np.int64)
+    w1 = runs.table[blk[item], runs.width + slot].astype(np.int64)
+    tile = runs.item_tile[item].astype(np.int64)
+    offset = w1 & ((1 << _RUN_DEST_BITS) - 1)
+    shift = (w1 >> _RUN_DEST_BITS) & ((1 << _RUN_SHIFT_BITS) - 1)
+    length = w1 >> (_RUN_DEST_BITS + _RUN_SHIFT_BITS)
+    panel = (tile // runs.row_tiles) * runs.tile_panels \
+        + (w0 >> _RUN_ROW_BITS)
+    row = (tile % runs.row_tiles) * runs.tile_rows \
+        + (w0 & ((1 << _RUN_ROW_BITS) - 1))
+    lane = (offset % LANES - shift) % LANES
+    return {
+        "dest": blk[item] * runs.block_rows * LANES + offset,
+        "src": (panel * runs.lane_rows + row) * LANES + lane,
+        "length": length, "lane": lane, "panel": panel, "row": row,
+    }
 
 
 # ---------------------------------------------------------------------------

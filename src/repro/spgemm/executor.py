@@ -15,9 +15,12 @@ chaining three device-side stages under one ``jax.jit``:
 2. **the scheduled kernel**: the Pallas block-Gustavson kernel
    (:func:`repro.kernels.gustavson_spgemm.spgemm_scheduled_impl`) or the
    pure-jnp path (:func:`repro.kernels.ref.spgemm_scheduled_ref`);
-3. **output assembly**: one static gather through the symbolic phase's
-   :class:`~repro.core.schedule.AssemblyMap` — no data-dependent ``nonzero``,
-   no per-panel host loop.
+3. **output assembly**: C's values placed out of the kernel's panels by
+   the symbolic phase's :class:`~repro.core.schedule.AssemblyMap` — no
+   data-dependent ``nonzero``, no per-panel host loop. On pallas backends
+   the run-wise assembly kernel places them run by run in VMEM
+   (:mod:`repro.kernels.assemble_runs`, from the map's run table); on jnp,
+   and in the sharded programs, one static element gather.
 
 Because every stage is shape-static, the core batches over a leading value
 axis (:func:`numeric_core_batch`, the engine behind
@@ -29,8 +32,8 @@ jnp an offset-folded schedule so XLA sees the same op shapes as the
 single-set path. The jitted entry points are module-level with static
 config arguments, so plans sharing shapes share executables;
 :class:`SpGEMMExecutor` wraps them with a plan's device-resident constants
-(schedule arrays, ``[nnz]`` scatter indices, gather map — shipped to
-device once).
+(schedule arrays, ``[nnz]`` scatter indices, the run table or the gather
+map — shipped to device once).
 
 The same shape-static property is what makes the phase meshable:
 :class:`ShardedSpGEMMExecutor` (the numeric phase of
@@ -101,6 +104,7 @@ from repro.core.schedule import (
     stack_shard_schedules,
 )
 from repro.kernels import ref
+from repro.kernels.assemble_runs import assemble_runs_impl, plan_assembly_runs
 from repro.kernels.gustavson_spgemm import (
     compact_csr_indptr_impl,
     pad_schedule_arrays,
@@ -196,7 +200,8 @@ def kernel_interpret(backend: str) -> bool:
     return backend == "pallas_interpret"
 
 
-_STATICS = ("n_panels", "group", "backend", "interpret")
+_STATICS = ("n_panels", "group", "backend", "interpret", "run_nnz")
+_PALLAS = ("pallas", "pallas_interpret")
 
 
 def _stage_slices(arrays, cuts):
@@ -243,41 +248,53 @@ def _bind(vals, scatter, shape, mode="promise_in_bounds"):
             vals, unique_indices=True, mode=mode).reshape(shape)
 
 
-def _assemble(panels, gather, bsz=None):
-    """Output assembly: one static gather of the packed C values out of
-    the kernel's panels (``[bsz, nnz_c]`` per batch element when ``bsz``
-    is given)."""
+def _assemble(panels, asm, bsz=None, run_nnz=None, interpret=False):
+    """Output assembly: the packed C values out of the kernel's panels
+    (``[bsz, nnz_c]`` per batch element when ``bsz`` is given). ``asm`` is
+    the element gather map; or, where ``run_nnz`` gives the values, the
+    run table and its calls' items, which the run-wise kernel places
+    (:func:`~repro.kernels.assemble_runs.assemble_runs_impl`): bitwise the
+    gather's values."""
     with jax.named_scope("spgemm.assemble"):
+        if run_nnz is not None:
+            table, items = asm
+            out = assemble_runs_impl(
+                panels, table, items, bsz=bsz or 1, nnz=run_nnz,
+                interpret=interpret,
+            )
+            return out if bsz is not None else out[0]
         if bsz is None:
-            return panels.reshape(-1)[gather]
-        return panels.reshape(bsz, -1)[:, gather]
+            return panels.reshape(-1)[asm]
+        return panels.reshape(bsz, -1)[:, asm]
 
 
 @functools.partial(jax.jit, static_argnames=_STATICS)
 def numeric_core(
-    a_blocks, b_blocks, sched, gather, *, n_panels, group, backend, interpret
+    a_blocks, b_blocks, sched, asm, *, n_panels, group, backend, interpret,
+    run_nnz=None,
 ):
     """Functional numeric phase: packed blocks -> packed C values."""
     panels = _run_schedule(
         a_blocks, b_blocks, sched,
         n_panels=n_panels, group=group, backend=backend, interpret=interpret,
     )
-    return _assemble(panels, gather)
+    return _assemble(panels, asm, run_nnz=run_nnz, interpret=interpret)
 
 
 @functools.partial(
     jax.jit, static_argnames=_STATICS + ("a_shape", "b_shape")
 )
 def numeric_core_values(
-    a_vals, b_vals, a_scatter, b_scatter, sched, gather, *,
-    a_shape, b_shape, n_panels, group, backend, interpret,
+    a_vals, b_vals, a_scatter, b_scatter, sched, asm, *,
+    a_shape, b_shape, n_panels, group, backend, interpret, run_nnz=None,
 ):
     """Numeric phase from [nnz] value vectors: rebind + kernel + assembly."""
     a_blocks = _bind(a_vals, a_scatter, a_shape)
     b_blocks = _bind(b_vals, b_scatter, b_shape)
     return numeric_core(
-        a_blocks, b_blocks, sched, gather,
+        a_blocks, b_blocks, sched, asm,
         n_panels=n_panels, group=group, backend=backend, interpret=interpret,
+        run_nnz=run_nnz,
     )
 
 
@@ -345,8 +362,9 @@ def _run_schedule_batch(
     static_argnames=("a_shape", "b_shape", "rebind") + _STATICS,
 )
 def numeric_core_batch(
-    a_vals, b_vals, a_scatter, b_scatter, sched, gather, *,
+    a_vals, b_vals, a_scatter, b_scatter, sched, asm, *,
     a_shape, b_shape, rebind, n_panels, group, backend, interpret,
+    run_nnz=None,
 ):
     """Batched numeric phase over a leading value axis.
 
@@ -373,7 +391,7 @@ def numeric_core_batch(
         a_blocks, b_blocks, sched, bsz, a_shape[0], b_shape[0],
         n_panels=n_panels, group=group, backend=backend, interpret=interpret,
     )
-    return _assemble(panels, gather, bsz)
+    return _assemble(panels, asm, bsz, run_nnz=run_nnz, interpret=interpret)
 
 
 # -- the pipeline's bind (the first step of the protocol) -----------------
@@ -402,9 +420,9 @@ def bind_batch_core(vals, scatter, *, shape):
 class SpGEMMExecutor:
     """A plan's numeric phase with device-resident constants.
 
-    Stages the triple schedule, the scatter indices, and the assembly gather
-    map on device once; ``run``/``run_values``/``run_batch`` then call the
-    module-level jitted cores (shared executables across same-shaped plans)
+    Stages the triple schedule, the scatter indices, and the assembly's
+    run table (or gather map) on device once; ``run``/``run_values``/
+    ``run_batch`` then call the module-level jitted cores (shared executables across same-shaped plans)
     with zero per-call host work beyond operand transfer.
 
     Every entry point honors the plan's backend: single-shot calls run the
@@ -443,9 +461,34 @@ class SpGEMMExecutor:
         ) * bm
         # The assembly map is the *active* output map: the plan passes its
         # block-structural map for output="block" and the element-exact
-        # compact map for output="compact" — every path below is a gather
-        # through it, so the compaction is fused into assembly for free.
-        self._gather = jnp.asarray(assembly.gather)
+        # compact map for output="compact" — every path below places C's
+        # values through it, so the compaction is fused into assembly for
+        # free. Pallas plans place them run by run where the panels have a
+        # 128-lane view (their run table); jnp plans, or panels without
+        # the view, gather one value at a time.
+        self.assemble_values = int(assembly.nnz)
+        self.assembly_runs = None
+        self._run_nnz: Optional[int] = None
+        planned = None
+        if backend in _PALLAS and len(a_shape) == 3 and len(b_shape) == 3:
+            planned = plan_assembly_runs(
+                np.asarray(assembly.gather), n_panels=schedule.n_panels,
+                group=schedule.group, bm=bm, bn=self._bn,
+            )
+        if planned is None:
+            self._asm = jnp.asarray(assembly.gather)
+            self.assemble_runs = self.assemble_values
+        else:
+            runs, item_cuts = planned
+            self.assembly_runs = runs
+            self._run_nnz = runs.nnz
+            self._asm = (
+                jnp.asarray(runs.table.reshape(-1)),
+                _stage_slices(
+                    (runs.item_block, runs.item_tile, runs.item_end),
+                    item_cuts),
+            )
+            self.assemble_runs = runs.n_runs
         self._out_rows = int(assembly.shape[0])
         self._indptr_host = np.asarray(assembly.indptr)
         self._row_ids: Optional[jax.Array] = None
@@ -456,7 +499,7 @@ class SpGEMMExecutor:
         cuts = schedule_cuts(schedule.start)
         self.kernel_calls = len(cuts) - 1
         self.triples = schedule.num_triples
-        if backend in ("pallas", "pallas_interpret"):
+        if backend in _PALLAS:
             self._sched = _stage_slices(pad_schedule_arrays(
                 schedule.a_slot, schedule.b_slot, schedule.panel,
                 schedule.sub_row, schedule.start, schedule.n_panels,
@@ -544,19 +587,19 @@ class SpGEMMExecutor:
     def run(self, a_blocks, b_blocks) -> jax.Array:
         """Packed blocks -> packed C values (plan's backend)."""
         return numeric_core(
-            a_blocks, b_blocks, self._sched, self._gather,
+            a_blocks, b_blocks, self._sched, self._asm,
             n_panels=self.n_panels, group=self.group, backend=self.backend,
-            interpret=self._interpret,
+            interpret=self._interpret, run_nnz=self._run_nnz,
         )
 
     def run_values(self, a_vals, b_vals) -> jax.Array:
         """[nnz] value vectors -> packed C values, rebind included."""
         return numeric_core_values(
             a_vals, b_vals, self._a_scatter, self._b_scatter,
-            self._sched, self._gather,
+            self._sched, self._asm,
             a_shape=self.a_shape, b_shape=self.b_shape,
             n_panels=self.n_panels, group=self.group, backend=self.backend,
-            interpret=self._interpret,
+            interpret=self._interpret, run_nnz=self._run_nnz,
         )
 
     def run_batch(self, a_vals, b_vals, *, rebind: bool) -> jax.Array:
@@ -565,10 +608,10 @@ class SpGEMMExecutor:
         return numeric_core_batch(
             jnp.asarray(a_vals), jnp.asarray(b_vals),
             self._a_scatter, self._b_scatter,
-            self._sched, self._gather,
+            self._sched, self._asm,
             a_shape=self.a_shape, b_shape=self.b_shape, rebind=rebind,
             n_panels=self.n_panels, group=self.group, backend=self.backend,
-            interpret=self._interpret,
+            interpret=self._interpret, run_nnz=self._run_nnz,
         )
 
     # -- pipeline protocol (non-blocking until collect) --------------------
@@ -614,10 +657,10 @@ class SpGEMMExecutor:
             return self.run(a_blocks, b_blocks)
         return numeric_core_batch(
             a_blocks, b_blocks, self._a_scatter, self._b_scatter,
-            self._sched, self._gather,
+            self._sched, self._asm,
             a_shape=self.a_shape, b_shape=self.b_shape, rebind=False,
             n_panels=self.n_panels, group=self.group, backend=self.backend,
-            interpret=self._interpret,
+            interpret=self._interpret, run_nnz=self._run_nnz,
         )
 
     def pipe_collect(self, packed, *, mode: str) -> np.ndarray:
@@ -868,6 +911,8 @@ class ShardedSpGEMMExecutor:
         for i, asm in enumerate(assemblies):
             gather[i, : asm.nnz] = asm.gather
         self._gather = put(gather, self._sep)
+        # The shard programs assemble by the element gather.
+        self.assemble_values = self.assemble_runs = sum(self._nnz_c)
 
         # Rebind maps (element plans): per shard, the scatter index of each
         # element of its padded value slice into its own A block array;

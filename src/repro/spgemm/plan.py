@@ -839,7 +839,9 @@ class SpGEMMPlan:
         block slots of the zeroed arrays it scatters them into (their
         ratio is the block fill). ``kernel_calls`` is the Pallas calls the
         call dispatches (the schedule's slices), ``triples`` the block
-        triples it runs."""
+        triples it runs, ``assemble_values`` the C values it assembles and
+        ``assemble_runs`` the runs they are placed in (one a value where
+        the assembly gathers them one at a time)."""
         return TraceAnnotation(
             name, step=step,
             h2d_bytes=sum(x.nbytes for x in sent),
@@ -847,6 +849,8 @@ class SpGEMMPlan:
             bind_slots=bind_sets * self._bind_slots,
             kernel_calls=self._executor.kernel_calls,
             triples=sets * self._executor.triples,
+            assemble_runs=sets * self._executor.assemble_runs,
+            assemble_values=sets * self._executor.assemble_values,
         )
 
     def _run_packed(self, a_vals=None, b_vals=None):

@@ -225,6 +225,34 @@ def test_dispatch_and_run_count_kernel_calls_and_triples(plan, tmp_path):
     assert (args["kernel_calls"], args["triples"]) == (calls, t)
 
 
+def test_dispatch_and_run_count_the_assembly_runs_and_values(plan, tmp_path):
+    """``spgemm.dispatch`` carries the C values a product assembles and
+    the runs they are placed in, the executor's table counts: fewer runs
+    than values where the run-wise kernel places them, one a value where
+    the assembly gathers (a sharded plan's ``spgemm.run``), per set."""
+    ex = plan._executor
+    nnz_c = plan.compact.nnz
+    assert ex.assemble_values == nnz_c
+    assert ex.assemble_runs == ex.assembly_runs.n_runs < nnz_c
+    a_vals, b_vals = _values(plan, 9)
+    found = _traced(tmp_path, lambda: plan.execute(a_vals, b_vals))
+    args = _one(found, "spgemm.dispatch")[3]
+    assert (args["assemble_runs"], args["assemble_values"]) == (
+        ex.assemble_runs, nnz_c)
+    batch = np.stack([a_vals, b_vals])
+    found = _traced(tmp_path, lambda: plan.execute_batch(batch, batch))
+    args = _one(found, "spgemm.dispatch")[3]
+    assert (args["assemble_runs"], args["assemble_values"]) == (
+        2 * ex.assemble_runs, 2 * nnz_c)
+    a = COO(plan.a_pattern.row, plan.a_pattern.col, a_vals, plan.a_pattern.shape)
+    sharded = spgemm_plan(a, a, tile=16, group=2, backend="pallas_interpret",
+                          output="compact", cache=PlanCache(),
+                          mesh=Mesh(np.array(jax.devices()[:1]), ("shard",)))
+    found = _traced(tmp_path, lambda: sharded.execute(a_vals, a_vals))
+    args = _one(found, "spgemm.run")[3]
+    assert args["assemble_runs"] == args["assemble_values"] == nnz_c
+
+
 def test_spans_reduce_to_the_layer_metrics(plan, tmp_path):
     """The readers of ``bench/metrics`` find the spans of a real CPU trace
     (the CPU has no device plane, so device scopes read nothing)."""

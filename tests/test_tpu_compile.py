@@ -24,12 +24,20 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.perfmodel import SCHEDULE_TRIPLES_PER_CALL
+from repro.core.perfmodel import (
+    SCHEDULE_TRIPLES_PER_CALL,
+    assembly_items_per_call,
+)
+from repro.kernels.assemble_runs import BLOCK_ROWS, assemble_runs_impl
 from repro.kernels.gustavson_spgemm import (
     spgemm_scheduled_batch_impl,
     spgemm_scheduled_impl,
 )
-from repro.spgemm.executor import numeric_core, numeric_core_values, shard_program
+from repro.spgemm.executor import (
+    numeric_core,
+    numeric_core_values,
+    shard_program,
+)
 
 # 2cubes_sphere A @ A^T, scale=1.0, tile 128, group 4 (plan report).
 T, NNZB, N_PANELS, TILE, GROUP = 39_841, 5_601, 3_189, 128, 4
@@ -45,6 +53,10 @@ EX56 = dict(t=96_945, nnzb=8_997, n_panels=9_692, nnz=8_214_057,
 # hpcg40-A2 (bench/configs): 64,000^2, the same plan settings.
 HPCG40 = dict(t=23_386, nnzb=3_410, n_panels=4_108, nnz=1_643_032,
               nnz_c=7_301_384)
+# Their run tables (build_assembly_runs of each C pattern at tile 128,
+# group 4): runs a block, padded, and items (grid steps).
+EX56.update(width=3_072, items=14_270)
+HPCG40.update(width=7_168, items=5_637)
 
 
 @pytest.fixture(scope="module")
@@ -241,3 +253,50 @@ def test_compiled_programs_keep_the_stage_scopes(program, one_chip, mesh4,
         ).compile()
     assert _scopes(compiled) == {"spgemm.bind", "spgemm.kernel",
                                  "spgemm.assemble"}
+
+
+def _run_assembly(sharding, shapes, width, *items):
+    """The run-wise assembly of ``shapes``' panels, its items in calls of
+    ``items`` each."""
+    blocks = -(-shapes["nnz_c"] // (BLOCK_ROWS * 128))
+    fn = functools.partial(assemble_runs_impl, bsz=1, nnz=shapes["nnz_c"],
+                           interpret=False)
+    return jax.jit(fn).lower(
+        _sds((shapes["n_panels"], GROUP * TILE, TILE), F32, sharding),
+        _sds((blocks * 2 * width,), I32, sharding),
+        tuple((_sds((n,), I32, sharding),) * 3 for n in items))
+
+
+@pytest.mark.parametrize("shapes", [HPCG40, EX56], ids=["hpcg40", "ex56"])
+def test_run_assembly_compiles(shapes, one_chip, no_compile_cache):
+    """The run-wise assembly at the benchmark's shapes: one call."""
+    _check(_run_assembly(one_chip, shapes, shapes["width"],
+                         shapes["items"]).compile())
+
+
+def test_run_assembly_smem_budget(one_chip, no_compile_cache):
+    """At the widest run table (a block of one-value runs) a call of the
+    budget's items fits SMEM, and one of 6,000 more does not."""
+    width = BLOCK_ROWS * 128
+    budget = assembly_items_per_call(width)
+    _check(_run_assembly(one_chip, EX56, width, budget).compile())
+    with pytest.raises(Exception, match="Ran out of memory in memory space smem"):
+        _run_assembly(one_chip, EX56, width, budget + 6_000).compile()
+
+
+def test_stream_program_with_run_assembly_fits(one_chip, no_compile_cache):
+    """ex56-ne32-A2's stream program as it runs now: two kernel calls into
+    one panel array, then the run-wise assembly's call, within 16 GB."""
+    shape = (EX56["nnzb"], TILE, TILE)
+    blocks = -(-EX56["nnz_c"] // (BLOCK_ROWS * 128))
+    compiled = numeric_core.lower(
+        _sds(shape, F32, one_chip), _sds(shape, F32, one_chip),
+        _slices(one_chip, *EX56_CALLS),
+        (_sds((blocks * 2 * EX56["width"],), I32, one_chip),
+         ((_sds((EX56["items"],), I32, one_chip),) * 3,)),
+        n_panels=EX56["n_panels"], group=GROUP, backend="pallas",
+        interpret=False,
+        run_nnz=EX56["nnz_c"],
+    ).compile()
+    _check(compiled)
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3

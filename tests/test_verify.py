@@ -23,6 +23,7 @@ from _compat_hypothesis import given, settings, st
 from repro.analysis.verify import (
     PlanVerificationError,
     check_assembly,
+    check_assembly_runs,
     check_batch_races,
     check_schedule,
     check_shard_partition,
@@ -354,6 +355,67 @@ class TestAssemblyFaultInjection:
         plan.assembly = dataclasses.replace(plan.assembly, gather=gather)
         rep = verify_plan(plan)
         assert not rep.ok
+        with pytest.raises(PlanVerificationError):
+            rep.raise_if_failed()
+
+
+class TestRunTableFaultInjection:
+    """Family 7: the run-wise assembly's table against the map it places."""
+
+    def _plan(self):
+        a, b = _mats(5, m=160, n=150, k=120, density=0.08)
+        return spgemm_plan(a, b, tile=16, group=2, backend="pallas_interpret",
+                           cache=PlanCache(), output="compact")
+
+    def _run(self, plan, runs):
+        findings = []
+        check_assembly_runs(runs, plan.compact.gather, findings)
+        return _checks(findings)
+
+    def _last_item_of_a_block(self, runs):
+        """An item whose runs end its block, with at least two runs."""
+        blk, end = runs.item_block, runs.item_end
+        last = np.flatnonzero(np.append(blk[1:] != blk[:-1], True))
+        begin = np.where(np.append(False, blk[1:] == blk[:-1]),
+                         np.append(0, end[:-1]), 0)
+        return int(last[np.argmax(end[last] - begin[last] >= 2)])
+
+    def test_pristine_plan_verifies_with_runs(self):
+        plan = self._plan()
+        assert plan._executor.assembly_runs is not None
+        rep = verify_plan(plan)
+        assert rep.ok, rep.summary()
+        assert "runs" in rep.checks_run
+        assert self._run(plan, plan._executor.assembly_runs) == set()
+
+    def test_dropped_run(self):
+        plan = self._plan()
+        runs = plan._executor.assembly_runs
+        i = self._last_item_of_a_block(runs)
+        end = runs.item_end.copy()
+        end[i] -= 1  # the block's last run is no item's any more
+        got = self._run(plan, dataclasses.replace(runs, item_end=end))
+        assert got == {"runs.dropped"}
+
+    def test_duplicated_run(self):
+        plan = self._plan()
+        runs = plan._executor.assembly_runs
+        i = self._last_item_of_a_block(runs)
+        j = int(runs.item_end[i]) - 1
+        table = runs.table.copy()
+        b, w = int(runs.item_block[i]), runs.width
+        table[b, [j, w + j]] = table[b, [j - 1, w + j - 1]]
+        got = self._run(plan, dataclasses.replace(runs, table=table))
+        assert "runs.duplicate" in got and "runs.dropped" in got
+
+    def test_verify_plan_catches_a_planted_run(self):
+        plan = self._plan()
+        runs = plan._executor.assembly_runs
+        end = runs.item_end.copy()
+        end[self._last_item_of_a_block(runs)] -= 1
+        plan._executor.assembly_runs = dataclasses.replace(runs, item_end=end)
+        rep = verify_plan(plan)
+        assert "runs.dropped" in _checks(rep.findings)
         with pytest.raises(PlanVerificationError):
             rep.raise_if_failed()
 
