@@ -14,11 +14,23 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
+    "bind_ref",
     "spgemm_scheduled_ref",
     "bsr_spmm_ref",
     "moe_gmm_ref",
     "flash_attention_ref",
 ]
+
+
+def bind_ref(vals, scatter, shape) -> np.ndarray:
+    """The value bind on the host: one operand's ``[nnz]`` values placed in
+    zeroed packed blocks of ``shape`` at the plan's flat scatter indices
+    (``blocks.flat[scatter] = vals``). The executor's device bind
+    (``repro.spgemm.executor._bind``) must equal it bit for bit."""
+    vals = np.asarray(vals)
+    blocks = np.zeros(shape, vals.dtype)
+    blocks.reshape(-1)[np.asarray(scatter)] = vals
+    return blocks
 
 
 def spgemm_scheduled_ref(

@@ -17,9 +17,9 @@ streaming pipeline, as on the paper's FPGA:
   C's CSR pattern is precomputed, so assembly is a single static device
   gather — no host ``nonzero`` scan, no per-panel Python loop.
 * :meth:`SpGEMMPlan.execute` is a thin stateful wrapper over that core: it
-  keeps the lock / host-value staging / copy-on-stage semantics (no-arg
-  ``execute()`` reuses staged values; plans are shared cache objects) and
-  wraps the packed C values in the precomputed CSR structure.
+  keeps the lock and the plan's own copy of the last values (no-arg
+  ``execute()`` reuses them; plans are shared cache objects) and wraps the
+  packed C values in the precomputed CSR structure.
 * :meth:`SpGEMMPlan.execute_batch` vmaps the functional core over a leading
   value-batch axis — the serving workload, fed by the batch mode of
   :class:`repro.data.pipeline.SpGEMMValueStream`.
@@ -36,9 +36,8 @@ value-independent, which is what makes assembly jittable and batchable.
 Host spans: the served numeric path opens ``jax.profiler.TraceAnnotation``
 spans, so a profiler trace shows each product's host work on the same
 clock as its device ops. ``spgemm.execute`` and ``spgemm.execute_batch``
-(``spgemm.submit`` in the pipeline) hold ``spgemm.rebind`` (the host
-scatter of one operand's values into its block array, element plans),
-``spgemm.dispatch`` (H2D plus the jit enqueue) and ``spgemm.collect``,
+(``spgemm.submit`` in the pipeline) hold ``spgemm.dispatch`` (H2D plus
+the jit enqueue) and ``spgemm.collect``,
 whose children are ``spgemm.wait`` (the device finishing) and
 ``spgemm.d2h`` (the copy to host); the rest of ``spgemm.collect`` is the
 CSR wrap. A sharded plan's ``execute`` and ``execute_batch`` call an
@@ -245,7 +244,11 @@ class SpGEMMPlan:
     * block plans (built from BCSV/BCSR inputs): ``a_vals`` is a packed
       ``[nnzb_a, bm, bk]`` block array, likewise ``b_vals``.
 
-    Passing ``None`` reuses the values staged at build / last execute.
+    The plan holds each operand's values in that form, once: an element
+    plan its own copy of the ``[nnz]`` vectors, which every numeric call
+    binds into blocks on the device; a block plan the packed blocks.
+    Passing ``None`` reuses the values held from the build or the last
+    execute.
     ``execute_batch`` takes the same per-set shapes with a leading batch
     axis and runs the whole batch in one vmapped device call.
 
@@ -257,8 +260,12 @@ class SpGEMMPlan:
         self,
         *,
         schedule: SpGEMMSchedule,
-        a_blocks: np.ndarray,
-        b_blocks: np.ndarray,
+        a_shape: Tuple[int, ...],
+        b_shape: Tuple[int, ...],
+        a_dtype,
+        b_dtype,
+        a_vals,
+        b_vals,
         backend: str,
         out_shape: Tuple[int, int],
         report: PlanReport,
@@ -281,21 +288,32 @@ class SpGEMMPlan:
         self.b_pattern = b_pattern
         self._a_scatter = a_scatter
         self._b_scatter = b_scatter
-        self._a_blocks: Optional[np.ndarray] = a_blocks
-        self._b_blocks: Optional[np.ndarray] = b_blocks
-        # Packed-array geometry survives release_values(): rebinds validate
-        # against (and reallocate to) these.
-        self._a_shape = tuple(a_blocks.shape)
-        self._b_shape = tuple(b_blocks.shape)
-        self._a_dtype = a_blocks.dtype
-        self._b_dtype = b_blocks.dtype
+        # Packed-array geometry: the device bind's zeroed arrays (element
+        # plans), the packed values' shape (block plans).
+        self._a_shape = tuple(a_shape)
+        self._b_shape = tuple(b_shape)
+        self._a_dtype = np.dtype(a_dtype)
+        self._b_dtype = np.dtype(b_dtype)
+        # ``a_vals``/``b_vals`` in the form ``execute`` takes: an element
+        # plan (scatter maps) keeps its own copy of the [nnz] vectors, a
+        # block plan the packed blocks.
+        self._element = a_scatter is not None and b_scatter is not None
+        self._a_vals: Optional[np.ndarray] = None
+        self._b_vals: Optional[np.ndarray] = None
+        self._a_blocks: Optional[np.ndarray] = None
+        self._b_blocks: Optional[np.ndarray] = None
+        if self._element:
+            self._a_vals = _owned(a_vals, a_scatter, self._a_dtype, "a_vals")
+            self._b_vals = _owned(b_vals, b_scatter, self._b_dtype, "b_vals")
+        else:
+            self._a_blocks, self._b_blocks = a_vals, b_vals
         # Block slots a device bind fills per value set (element plans).
         self._bind_slots = int(np.prod(self._a_shape)) + int(
             np.prod(self._b_shape))
         self._m, self._n = out_shape
         self._group = schedule.group
-        self._bm = int(a_blocks.shape[1]) if a_blocks.ndim == 3 else 0
-        self._bn = int(b_blocks.shape[2]) if b_blocks.ndim == 3 else 0
+        self._bm = int(self._a_shape[1]) if len(self._a_shape) == 3 else 0
+        self._bn = int(self._b_shape[2]) if len(self._b_shape) == 3 else 0
         # Symbolic output structure: C's CSR pattern + the panels->CSR
         # gather map. Computed here (plan build) unless rehydrated from
         # persisted artifacts, consumed on device by the executor — the
@@ -336,8 +354,9 @@ class SpGEMMPlan:
         )
         if self._executor is not None:
             report.kernel_calls = self._executor.kernel_calls
-        # Device block values are staged lazily (first execute) so building
-        # a plan never pays H2D for values that are immediately rebound.
+        # A block plan's packed values are staged lazily (first execute) so
+        # building a plan never pays H2D for values that are immediately
+        # rebound.
         self._a_dev = None
         self._b_dev = None
         # Guards value rebinds + report counters: plans are shared objects
@@ -432,11 +451,11 @@ class SpGEMMPlan:
         return int(cfg.pipeline_depth) if cfg is not None else 2
 
     def _stage_a(self, blocks: np.ndarray):
-        """Host packed A blocks -> device layout for ``executor.run``.
+        """A block plan's packed A blocks -> device layout for
+        ``executor.run``.
 
-        copy=True: on CPU backends jnp.asarray may alias the numpy scratch
-        buffer, and a later rebind would mutate an earlier caller's staged
-        values mid-flight.
+        copy=True: on CPU backends jnp.asarray may alias the caller's
+        array, which the caller may reuse while the staged copy is read.
         """
         return jnp.array(blocks, copy=True)
 
@@ -494,8 +513,12 @@ class SpGEMMPlan:
         plan_cls, extra = _resolve_plan_cls(mesh, mesh_axis)
         plan = plan_cls(
             schedule=schedule,
-            a_blocks=a.blocks,
-            b_blocks=b.blocks,
+            a_shape=a.blocks.shape,
+            b_shape=b.blocks.shape,
+            a_dtype=a.blocks.dtype,
+            b_dtype=b.blocks.dtype,
+            a_vals=a.blocks,
+            b_vals=b.blocks,
             backend=backend,
             out_shape=(a.shape[0], b.shape[1]),
             report=report,
@@ -574,12 +597,12 @@ class SpGEMMPlan:
         """Rehydrate a plan from persisted artifacts + this call's values.
 
         The inverse of :meth:`persist_artifacts`: the symbolic phase is
-        **not** re-run (``report.schedule_builds == 0``); the packed block
-        arrays are rebuilt by scattering the caller's ``a_vals``/``b_vals``
-        through the persisted scatter indices (element plans) or taken
-        directly from ``a_blocks``/``b_blocks`` (block plans). Any
-        inconsistency between artifacts and inputs raises — the cache
-        treats that as an unusable entry and falls back to a cold build.
+        **not** re-run (``report.schedule_builds == 0``); an element plan
+        keeps a copy of the caller's ``[nnz]`` ``a_vals``/``b_vals``
+        (checked against the persisted scatter indices), a block plan
+        takes ``a_blocks``/``b_blocks`` as they are. Any inconsistency
+        between artifacts and inputs raises — the cache treats that as an
+        unusable entry and falls back to a cold build.
         """
         backend = resolve_backend(backend)
         kind = meta.get("kind")
@@ -609,28 +632,15 @@ class SpGEMMPlan:
         group = int(meta["group"])
         a_scatter = arrays.get("a_scatter")
         b_scatter = arrays.get("b_scatter")
-
-        def rebuild(vals, scatter, shape, dtype, name):
-            if scatter is None:
-                raise ValueError(f"{name}: persisted scatter missing")
-            vals = np.asarray(vals)
-            scatter = np.asarray(scatter)
-            if vals.shape != (int(scatter.shape[0]),):
-                raise ValueError(
-                    f"{name}: {vals.shape} values vs persisted scatter "
-                    f"of {int(scatter.shape[0])}"
-                )
-            blocks = np.zeros(shape, dtype)
-            blocks.reshape(-1)[scatter] = vals.astype(dtype, copy=False)
-            return blocks
-
         if kind == "element":
             if a_vals is None or b_vals is None:
                 raise ValueError("element plan needs a_vals/b_vals")
-            a_blocks = rebuild(a_vals, a_scatter, a_shape, a_dtype, "a_vals")
-            b_blocks = rebuild(b_vals, b_scatter, b_shape, b_dtype, "b_vals")
-            nnz_a = int(np.asarray(a_scatter).shape[0])
-            nnz_b = int(np.asarray(b_scatter).shape[0])
+            if a_scatter is None or b_scatter is None:
+                raise ValueError("element plan: persisted scatter missing")
+            a_scatter = np.asarray(a_scatter)
+            b_scatter = np.asarray(b_scatter)
+            nnz_a = int(a_scatter.shape[0])
+            nnz_b = int(b_scatter.shape[0])
         else:
             if a_blocks is None or b_blocks is None:
                 raise ValueError("block plan needs a_blocks/b_blocks")
@@ -646,11 +656,12 @@ class SpGEMMPlan:
                     f"b_blocks {b_blocks.shape}/{b_blocks.dtype} vs "
                     f"persisted {b_shape}/{b_dtype}"
                 )
+            a_vals, b_vals = a_blocks, b_blocks
             nnz_a = nnz_b = 0  # bound to staged blocks below (lazy)
         report = _make_report(
             pattern_key, tile, group, backend, out_shape,
-            nnz_a, nnz_b, a_shape[0] if a_blocks.ndim == 3 else 0,
-            b_shape[0] if b_blocks.ndim == 3 else 0, schedule,
+            nnz_a, nnz_b, a_shape[0] if len(a_shape) == 3 else 0,
+            b_shape[0] if len(b_shape) == 3 else 0, schedule,
         )
         report.schedule_builds = 0
         report.loads = 1
@@ -662,13 +673,17 @@ class SpGEMMPlan:
             )
         plan = plan_cls(
             schedule=schedule,
-            a_blocks=a_blocks,
-            b_blocks=b_blocks,
+            a_shape=a_shape,
+            b_shape=b_shape,
+            a_dtype=a_dtype,
+            b_dtype=b_dtype,
+            a_vals=a_vals,
+            b_vals=b_vals,
             backend=backend,
             out_shape=out_shape,
             report=report,
-            a_scatter=None if a_scatter is None else np.asarray(a_scatter),
-            b_scatter=None if b_scatter is None else np.asarray(b_scatter),
+            a_scatter=a_scatter,
+            b_scatter=b_scatter,
             a_pattern=a_pattern,
             b_pattern=b_pattern,
             assembly=assembly,
@@ -691,36 +706,6 @@ class SpGEMMPlan:
 
     # -- numeric phase ----------------------------------------------------
 
-    def _rebind(
-        self,
-        vals,
-        blocks: Optional[np.ndarray],
-        scatter: Optional[np.ndarray],
-        nnz: int,
-        name: str,
-        shape: Tuple[int, ...],
-        dtype,
-    ) -> np.ndarray:
-        vals = np.asarray(vals)
-        if scatter is not None:
-            if vals.shape != (nnz,):
-                raise ValueError(
-                    f"{name}: expected [{nnz}] values in canonical pattern "
-                    f"order, got shape {vals.shape}"
-                )
-            if blocks is None:  # scratch was released; reallocate
-                blocks = np.zeros(shape, dtype)
-            # Positions outside `scatter` are structurally zero and never
-            # written, so in-place rebinding is sound.
-            blocks.reshape(-1)[scatter] = vals.astype(blocks.dtype, copy=False)
-            return blocks
-        if vals.shape != shape:
-            raise ValueError(
-                f"{name}: expected packed blocks of shape {shape}, "
-                f"got {vals.shape}"
-            )
-        return vals
-
     def value_shapes(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
         """Per-set operand shapes the numeric phase accepts:
         ``(want_a, want_b)`` — ``[nnz]`` vectors for element plans, packed
@@ -728,7 +713,7 @@ class SpGEMMPlan:
         same shapes with a shared leading batch axis. This is the
         validation contract serving front ends (the gateway) check
         requests against before queueing them."""
-        if self._a_scatter is not None and self._b_scatter is not None:
+        if self._element:
             return (self.report.nnz_a,), (self.report.nnz_b,)
         return self._a_shape, self._b_shape
 
@@ -867,59 +852,43 @@ class SpGEMMPlan:
                 # The count the increment below makes: this product's
                 # number.
                 step = self.report.executes + 1
-                # report.nnz_* is read only on the scatter (element-plan)
-                # path: block plans keep their lazy count_nonzero report
-                # fields unresolved through executes.
-                if a_vals is not None:
-                    with _rebind_span(step, "a", self._a_scatter,
-                                      self._a_shape):
-                        self._a_blocks = self._rebind(
-                            a_vals, self._a_blocks, self._a_scatter,
-                            self.report.nnz_a if self._a_scatter is not None
-                            else 0,
-                            "a_vals", self._a_shape, self._a_dtype,
-                        )
-                    self._a_dev = None
-                if b_vals is not None:
-                    with _rebind_span(step, "b", self._b_scatter,
-                                      self._b_shape):
-                        self._b_blocks = self._rebind(
-                            b_vals, self._b_blocks, self._b_scatter,
-                            self.report.nnz_b if self._b_scatter is not None
-                            else 0,
-                            "b_vals", self._b_shape, self._b_dtype,
-                        )
-                    self._b_dev = None
-                if self._a_blocks is None or self._b_blocks is None:
+                # Explicit values replace the held ones, operand by operand:
+                # an element plan's own copy of the [nnz] vectors, a block
+                # plan's packed blocks (restaged below).
+                if self._element:
+                    if a_vals is not None:
+                        self._a_vals = _owned(a_vals, self._a_scatter,
+                                              self._a_dtype, "a_vals")
+                    if b_vals is not None:
+                        self._b_vals = _owned(b_vals, self._b_scatter,
+                                              self._b_dtype, "b_vals")
+                    held = (self._a_vals, self._b_vals)
+                else:
+                    if a_vals is not None:
+                        self._a_blocks = _packed(a_vals, self._a_shape,
+                                                 "a_vals")
+                        self._a_dev = None
+                    if b_vals is not None:
+                        self._b_blocks = _packed(b_vals, self._b_shape,
+                                                 "b_vals")
+                        self._b_dev = None
+                    held = (self._a_blocks, self._b_blocks)
+                if held[0] is None or held[1] is None:
                     raise ValueError(
                         "plan values were released (release_values); pass "
                         "a_vals/b_vals to execute"
                     )
-                # Element plans called with both value vectors take the
-                # fully fused device path (rebind + kernel + assembly in one
-                # jit): only [nnz] vectors cross to device, not full packed
-                # blocks. The host rebind above still ran, so no-arg
-                # execute() stays current; device block staging is left to
-                # the next such call.
-                fused_values = (
-                    a_vals is not None and b_vals is not None
-                    and self._a_scatter is not None
-                    and self._b_scatter is not None
-                )
-                if fused_values:
-                    a_send = np.asarray(a_vals, dtype=self._a_dtype)
-                    b_send = np.asarray(b_vals, dtype=self._b_dtype)
-                    sent = (a_send, b_send)
-                else:  # the blocks not on the device yet go down below
-                    sent = tuple(host for host, dev in (
-                        (self._a_blocks, self._a_dev),
-                        (self._b_blocks, self._b_dev)) if dev is None)
+                # An element plan sends its [nnz] vectors, which the device
+                # binds; a block plan the blocks not on the device yet.
+                sent = held if self._element else tuple(
+                    host for host, dev in zip(held, (self._a_dev, self._b_dev))
+                    if dev is None)
                 if self._executor is not None:
                     # Held past the lock, to the end of the executor's call.
                     spans.enter_context(self._dispatch_span(
-                        step, *sent, bind_sets=int(fused_values),
+                        step, *sent, bind_sets=int(self._element),
                         name=self._RUN_SPAN))
-                if not fused_values:
+                if not self._element:
                     if self._a_dev is None:
                         self._a_dev = self._stage_a(self._a_blocks)
                     if self._b_dev is None:
@@ -927,13 +896,13 @@ class SpGEMMPlan:
                     # Snapshot under the lock so a concurrent rebind on
                     # this shared plan cannot mix one caller's A with
                     # another's B.
-                    a_dev, b_dev = self._a_dev, self._b_dev
+                    held = (self._a_dev, self._b_dev)
                 self.report.executes += 1
             if self._executor is None:
                 return None, step
-            if fused_values:
-                return self._executor.run_values(a_send, b_send), step
-            return self._executor.run(a_dev, b_dev), step
+            if self._element:
+                return self._executor.run_values(*held), step
+            return self._executor.run(*held), step
 
     def _run_packed_chained(self, c_packed):
         """Stage ``s >= 2`` of :func:`execute_chain`: the previous stage's
@@ -985,8 +954,8 @@ class SpGEMMPlan:
         (``b_vals`` likewise). Returns a list of ``batch`` CSR results that
         share this plan's precomputed ``indptr``/``indices``.
 
-        Stateless with respect to the plan's staged values: it never touches
-        the buffers no-arg ``execute()`` reuses, so it is safe to interleave
+        Stateless with respect to the plan's held values: it never touches
+        the values no-arg ``execute()`` reuses, so it is safe to interleave
         with single executes and works after ``release_values()``. The
         batch honors the plan's backend: pallas plans run the batch-folded
         Pallas grid, jnp plans the offset-folded scatter-add reference —
@@ -995,8 +964,6 @@ class SpGEMMPlan:
         with TraceAnnotation("spgemm.execute_batch") as span:
             a_vals = np.asarray(a_vals)
             b_vals = np.asarray(b_vals)
-            rebind = (self._a_scatter is not None
-                      and self._b_scatter is not None)
             want_a, want_b = self.value_shapes()
             if a_vals.ndim != len(want_a) + 1 or a_vals.shape[1:] != want_a:
                 raise ValueError(
@@ -1037,9 +1004,10 @@ class SpGEMMPlan:
                 a, b = a_vals[lo:hi], b_vals[lo:hi]
                 with self._dispatch_span(
                         step, a, b, sets=hi - lo,
-                        bind_sets=hi - lo if rebind else 0,
+                        bind_sets=hi - lo if self._element else 0,
                         name=self._RUN_SPAN):
-                    packed = self._executor.run_batch(a, b, rebind=rebind)
+                    packed = self._executor.run_batch(
+                        a, b, rebind=self._element)
                 with TraceAnnotation("spgemm.collect", step=step):
                     packed = self._fetch(packed, step)
                     out.extend(self._wrap_packed(packed[i])
@@ -1109,28 +1077,32 @@ class SpGEMMPlan:
         """Validate one submission and prepare its operands (host work +
         plan-state snapshot only; no device compute is dispatched).
 
-        Stateless w.r.t. the plan's staged values — explicit operands
-        never touch the buffers no-arg ``execute()`` reuses — except that
-        the no-arg form stages (and caches) the plan's own values exactly
-        like ``execute()`` does."""
+        Stateless w.r.t. the plan's held values — explicit operands
+        never touch the values no-arg ``execute()`` reuses. The no-arg form
+        submits the held values: an element plan's [nnz] vectors, bound
+        on the device like any explicit ones; a block plan's packed
+        blocks, staged (and cached) exactly like ``execute()`` does."""
         if (a_vals is None) != (b_vals is None):
             raise ValueError(
                 "submit takes both a_vals and b_vals, or neither "
-                "(to reuse the plan's staged values)"
+                "(to reuse the plan's held values)"
             )
         if a_vals is None:
             with self._lock:
                 self._check_released()
-                if self._a_blocks is None or self._b_blocks is None:
+                held = ((self._a_vals, self._b_vals) if self._element
+                        else (self._a_blocks, self._b_blocks))
+                if held[0] is None or held[1] is None:
                     raise ValueError(
                         "plan values were released (release_values); pass "
                         "a_vals/b_vals to submit"
                     )
+                if self._element:
+                    return _Prepared("values", *held, None, 1)
                 sent = ()
                 if self._executor is not None:
-                    sent = tuple(host for host, dev in (
-                        (self._a_blocks, self._a_dev),
-                        (self._b_blocks, self._b_dev)) if dev is None)
+                    sent = tuple(host for host, dev in zip(
+                        held, (self._a_dev, self._b_dev)) if dev is None)
                     if self._a_dev is None:
                         self._a_dev = self._stage_a(self._a_blocks)
                     if self._b_dev is None:
@@ -1141,7 +1113,6 @@ class SpGEMMPlan:
             self._check_released()
         a_vals = np.asarray(a_vals)
         b_vals = np.asarray(b_vals)
-        rebind = self._a_scatter is not None and self._b_scatter is not None
         want_a, want_b = self.value_shapes()
         single = a_vals.shape == want_a and b_vals.shape == want_b
         batched = (
@@ -1158,7 +1129,7 @@ class SpGEMMPlan:
         a_vals = a_vals.astype(self._a_dtype, copy=False)
         b_vals = b_vals.astype(self._b_dtype, copy=False)
         if single:
-            if rebind:
+            if self._element:
                 return _Prepared("values", a_vals, b_vals, None, 1)
             # Packed-block operands: stage now (copy-on-stage, the
             # executor's device layout) so the caller may reuse buffers.
@@ -1166,7 +1137,7 @@ class SpGEMMPlan:
                 "blocks", self._stage_a(a_vals), self._stage_b(b_vals),
                 None, 1, (a_vals, b_vals),
             )
-        mode = "batch_values" if rebind else "batch_blocks"
+        mode = "batch_values" if self._element else "batch_blocks"
         batch = int(a_vals.shape[0])
         return _Prepared(mode, a_vals, b_vals, batch, batch)
 
@@ -1232,10 +1203,13 @@ class SpGEMMPlan:
     # -- teardown ----------------------------------------------------------
 
     def release_device_values(self) -> None:
-        """Drop only the staged device copies of the packed block values.
+        """Drop only the device copies of the plan's values: a block
+        plan's staged packed blocks and a chained stage's B vector.
 
-        The next execute restages from the host arrays on demand. Refuses
-        while pipeline steps are in flight (they read these buffers).
+        The next execute restages from the host arrays on demand (an
+        element plan sends its [nnz] vectors on every call anyway).
+        Refuses while pipeline steps are in flight (they read these
+        buffers).
         """
         with self._lock:
             self._check_no_inflight("release device values")
@@ -1244,7 +1218,8 @@ class SpGEMMPlan:
             self._b_vals_dev = None
 
     def release_values(self) -> None:
-        """Drop host AND device copies of the packed block values.
+        """Drop host AND device copies of the plan's values: an element
+        plan's [nnz] vectors, a block plan's packed blocks.
 
         Cached plans outlive individual calls; one-shot callers (the
         ``ops.spgemm`` shim) release values after executing so a warm
@@ -1259,8 +1234,8 @@ class SpGEMMPlan:
             self._a_dev = None
             self._b_dev = None
             self._b_vals_dev = None
-            self._a_blocks = None
-            self._b_blocks = None
+            self._a_vals = self._b_vals = None
+            self._a_blocks = self._b_blocks = None
 
     def release(self) -> None:
         """Full teardown: values (host + device) AND the executor's
@@ -1277,8 +1252,8 @@ class SpGEMMPlan:
             self._a_dev = None
             self._b_dev = None
             self._b_vals_dev = None
-            self._a_blocks = None
-            self._b_blocks = None
+            self._a_vals = self._b_vals = None
+            self._a_blocks = self._b_blocks = None
             self._executor = None
             ref = self._cache_ref
         # Self-evict outside the plan lock (eviction re-checks in_flight,
@@ -1298,7 +1273,8 @@ class SpGEMMPlan:
             sch.panel_group, sch.panel_bcol, sch.c_brow, sch.c_bcol,
         ]
         with self._lock:
-            arrays += [self._a_blocks, self._b_blocks]
+            arrays += [self._a_vals, self._b_vals,
+                       self._a_blocks, self._b_blocks]
         arrays += [self._a_scatter, self._b_scatter]
         for pat in (self.a_pattern, self.b_pattern):
             if pat is not None:
@@ -1318,10 +1294,10 @@ class ShardedSpGEMMPlan(SpGEMMPlan):
     symbolic schedule at block-row-group boundaries balanced by **triple
     count** (:func:`~repro.core.schedule.partition_spgemm_schedule`), builds
     each shard's own :class:`~repro.core.schedule.AssemblyMap` slice, and
-    stages each shard's packed A blocks / schedule / gather map on its own
-    device (B replicated). ``execute`` / ``execute_batch`` keep the exact
-    single-device semantics — same lock / staged-value / copy-on-stage
-    behavior, same structural CSR output sharing the plan-wide
+    stages each shard's schedule / gather map on its own device; A's
+    values go to their shard, B's are replicated. ``execute`` /
+    ``execute_batch`` keep the exact single-device semantics — same lock
+    and held values, same structural CSR output sharing the plan-wide
     ``indptr``/``indices`` — because C's per-shard segments are contiguous
     row ranges: the final CSR data is one concatenation along the
     precomputed indptr boundaries.
@@ -1521,16 +1497,29 @@ def _value_dtype(x):
     return None
 
 
-def _rebind_span(step: int, operand: str, scatter, shape):
-    """``spgemm.rebind``: the host scatter of one operand's values into
-    its block array, on element plans (``scatter`` set); nothing on block
-    plans, whose packed values are taken as they are."""
-    if scatter is None:
-        return contextlib.nullcontext()
-    return TraceAnnotation(
-        "spgemm.rebind", step=step, operand=operand,
-        values=int(scatter.shape[0]), slots=int(np.prod(shape)),
-    )
+def _owned(vals, scatter: np.ndarray, dtype, name: str) -> np.ndarray:
+    """The plan's own copy of one operand's ``[nnz]`` values (canonical
+    pattern order, one per ``scatter`` index) at its value dtype: a caller
+    that reuses its buffer changes no later product."""
+    vals = np.asarray(vals)
+    nnz = int(scatter.shape[0])
+    if vals.shape != (nnz,):
+        raise ValueError(
+            f"{name}: expected [{nnz}] values in canonical pattern order, "
+            f"got shape {vals.shape}"
+        )
+    return np.array(vals, dtype=dtype)
+
+
+def _packed(vals, shape: Tuple[int, ...], name: str) -> np.ndarray:
+    """One operand's packed block values (block plans), shape-checked."""
+    vals = np.asarray(vals)
+    if vals.shape != shape:
+        raise ValueError(
+            f"{name}: expected packed blocks of shape {shape}, "
+            f"got {vals.shape}"
+        )
+    return vals
 
 
 def _staged_nnz(plan: "SpGEMMPlan", attr: str, field: str):
@@ -1822,8 +1811,7 @@ def spgemm_plan(
                         or (dt_b is not None and dt_b != plan._b_dtype)):
                     plan = None
         if plan is not None:
-            element = (plan._a_scatter is not None
-                       and plan._b_scatter is not None)
+            element = plan._element
             with plan._lock:
                 plan.report.cache_hits += 1
                 if a is None and b is None:
@@ -1845,18 +1833,10 @@ def spgemm_plan(
                             f"{plan.report.nnz_b}); the token must name "
                             f"this exact sparsity pattern"
                         )
-                    plan._a_blocks = plan._rebind(
-                        a_c.val, plan._a_blocks, plan._a_scatter,
-                        plan.report.nnz_a, "a_vals", plan._a_shape,
-                        plan._a_dtype,
-                    )
-                    plan._a_dev = None
-                    plan._b_blocks = plan._rebind(
-                        b_c.val, plan._b_blocks, plan._b_scatter,
-                        plan.report.nnz_b, "b_vals", plan._b_shape,
-                        plan._b_dtype,
-                    )
-                    plan._b_dev = None
+                    plan._a_vals = _owned(a_c.val, plan._a_scatter,
+                                          plan._a_dtype, "a_vals")
+                    plan._b_vals = _owned(b_c.val, plan._b_scatter,
+                                          plan._b_dtype, "b_vals")
                 elif (not element
                         and isinstance(a, BCSV) and isinstance(b, BCSR)):
                     # Block plans: mirror the digest hit path's rebind of
@@ -1963,10 +1943,16 @@ def spgemm_plan(
             a_coo.nnz, b_coo.nnz, a_bcsv.nnzb, b_bcsr.nnzb, schedule,
         )
         plan_cls, extra = _resolve_plan_cls(mesh, mesh_axis)
+        # The converters' block arrays go with this frame: an element plan
+        # keeps their geometry and binds its [nnz] values on the device.
         return plan_cls(
             schedule=schedule,
-            a_blocks=a_bcsv.blocks,
-            b_blocks=b_bcsr.blocks,
+            a_shape=a_bcsv.blocks.shape,
+            b_shape=b_bcsr.blocks.shape,
+            a_dtype=a_coo.val.dtype,
+            b_dtype=b_coo.val.dtype,
+            a_vals=a_coo.val,
+            b_vals=b_coo.val,
             backend=backend,
             out_shape=(a_coo.shape[0], b_coo.shape[1]),
             report=report,
@@ -1997,18 +1983,11 @@ def spgemm_plan(
         with plan._lock:
             plan.report.cache_hits += 1
             # A cache hit may carry stale values from the previous caller;
-            # the pattern matches by construction, so rebind this call's
-            # values (device staging is lazy — execute pays H2D once).
-            plan._a_blocks = plan._rebind(
-                a_coo.val, plan._a_blocks, plan._a_scatter,
-                plan.report.nnz_a, "a_vals", plan._a_shape, plan._a_dtype,
-            )
-            plan._a_dev = None
-            plan._b_blocks = plan._rebind(
-                b_coo.val, plan._b_blocks, plan._b_scatter,
-                plan.report.nnz_b, "b_vals", plan._b_shape, plan._b_dtype,
-            )
-            plan._b_dev = None
+            # the pattern matches by construction, so hold this call's.
+            plan._a_vals = _owned(a_coo.val, plan._a_scatter,
+                                  plan._a_dtype, "a_vals")
+            plan._b_vals = _owned(b_coo.val, plan._b_scatter,
+                                  plan._b_dtype, "b_vals")
     if validate == "deep":
         _deep_verify(plan)
     return plan
@@ -2247,10 +2226,16 @@ def plan_from_structural_pattern(
             a_coo.nnz, b_coo.nnz, a_bcsv.nnzb, b_bcsr.nnzb, schedule,
         )
         plan_cls, extra = _resolve_plan_cls(mesh, mesh_axis)
+        # The converters' block arrays go with this frame: an element plan
+        # keeps their geometry and binds its [nnz] values on the device.
         return plan_cls(
             schedule=schedule,
-            a_blocks=a_bcsv.blocks,
-            b_blocks=b_bcsr.blocks,
+            a_shape=a_bcsv.blocks.shape,
+            b_shape=b_bcsr.blocks.shape,
+            a_dtype=a_coo.val.dtype,
+            b_dtype=b_coo.val.dtype,
+            a_vals=a_coo.val,
+            b_vals=b_coo.val,
             backend=backend,
             out_shape=(c_pattern.shape[0], b_coo.shape[1]),
             report=report,
@@ -2278,15 +2263,12 @@ def plan_from_structural_pattern(
         with plan._lock:
             plan.report.cache_hits += 1
             # Pattern-equal hit serving a possibly different B operand:
-            # rebind this call's B values (blocks + the chained-stage
-            # device copy) so both standalone and chained executes see
-            # them. A-side placeholders are untouched — chain runs bind A
-            # per execute, on device.
-            plan._b_blocks = plan._rebind(
-                b_coo.val, plan._b_blocks, plan._b_scatter,
-                plan.report.nnz_b, "b_vals", plan._b_shape, plan._b_dtype,
-            )
-            plan._b_dev = None
+            # hold this call's B values (and drop the chained-stage device
+            # copy) so both standalone and chained executes see them.
+            # A-side placeholders are untouched — chain runs bind A per
+            # execute, on device.
+            plan._b_vals = _owned(b_coo.val, plan._b_scatter,
+                                  plan._b_dtype, "b_vals")
             plan._b_vals_dev = None
             plan.b_pattern = b_coo
     if validate == "deep":

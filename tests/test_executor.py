@@ -62,11 +62,12 @@ def _host_assemble(plan, panels: np.ndarray) -> CSR:
 
 
 def _kernel_panels(plan) -> np.ndarray:
-    """Run only the scheduled kernel (jnp path) on the plan's staged
-    blocks, bypassing the executor's fused assembly."""
+    """Run only the scheduled kernel (jnp path) on the plan's held values,
+    bound on the host, bypassing the executor's fused assembly."""
     sch = plan.schedule
     return np.asarray(ref.spgemm_scheduled_ref(
-        plan._a_blocks, plan._b_blocks,
+        ref.bind_ref(plan._a_vals, plan._a_scatter, plan._a_shape),
+        ref.bind_ref(plan._b_vals, plan._b_scatter, plan._b_shape),
         sch.a_slot, sch.b_slot, sch.panel, sch.sub_row,
         sch.n_panels, sch.group,
     ))
@@ -130,13 +131,11 @@ def _bits(x) -> np.ndarray:
 
 
 def _host_blocks(plan, a_vals, b_vals):
-    """The host ``_rebind`` of fresh values into fresh block arrays: the
-    reference the device bind must reproduce bit for bit."""
+    """The host bind (``ref.bind_ref``) of fresh values into fresh block
+    arrays: the reference the device bind must reproduce bit for bit."""
     return (
-        plan._rebind(a_vals, None, plan._a_scatter, plan.report.nnz_a, "a",
-                     plan._a_shape, plan._a_dtype),
-        plan._rebind(b_vals, None, plan._b_scatter, plan.report.nnz_b, "b",
-                     plan._b_shape, plan._b_dtype),
+        ref.bind_ref(a_vals, plan._a_scatter, plan._a_shape),
+        ref.bind_ref(b_vals, plan._b_scatter, plan._b_shape),
     )
 
 
@@ -167,7 +166,7 @@ def _dropped_b_row_pattern(seed):
 
 class TestDeviceBind:
     """The device bind scatters each operand's [nnz] values into zeroed
-    block arrays; it must equal the host rebind bit for bit."""
+    block arrays; it must equal the host bind bit for bit."""
 
     @pytest.mark.parametrize("form", ["single", "batch"])
     def test_matches_host_rebind(self, form):

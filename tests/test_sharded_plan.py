@@ -228,6 +228,7 @@ print("RAGGED_EMPTY_BLOCK_OK")
 DROPPED_ELEMENTS = """
 import numpy as np
 import jax
+from repro.kernels import ref
 from repro.sparse.random import random_coo
 from repro.sparse.formats import COO
 from repro.launch.mesh import make_shard_mesh
@@ -258,10 +259,8 @@ for i in range(4):
     dropped += int((a_map[i, : e_hi - e_lo] >= flat_a).sum())
 assert dropped > 0, "expected elements outside a shard's slot range"
 assert all(len(np.unique(row)) == row.size for row in a_map)
-ha = plan._rebind(av, None, plan._a_scatter, plan.report.nnz_a, "a",
-                  plan._a_shape, plan._a_dtype)
-hb = plan._rebind(bv, None, plan._b_scatter, plan.report.nnz_b, "b",
-                  plan._b_shape, plan._b_dtype)
+ha = ref.bind_ref(av, plan._a_scatter, plan._a_shape)
+hb = ref.bind_ref(bv, plan._b_scatter, plan._b_shape)
 bits = lambda x: np.ascontiguousarray(np.asarray(x, np.float32)).view(np.uint32)
 ga, gb = ex.pipe_stage(av, bv, mode="values")
 assert np.array_equal(bits(ga), bits(ex._stack_a(ha)))
@@ -269,8 +268,7 @@ assert np.array_equal(bits(gb), bits(hb))
 gba, gbb = ex.pipe_stage(np.stack([av, -av]), np.stack([bv, bv]),
                          mode="batch_values")
 assert np.array_equal(bits(np.asarray(gba)[:, 0]), bits(ex._stack_a(ha)))
-ha2 = plan._rebind(-av, None, plan._a_scatter, plan.report.nnz_a, "a",
-                   plan._a_shape, plan._a_dtype)
+ha2 = ref.bind_ref(-av, plan._a_scatter, plan._a_shape)
 assert np.array_equal(bits(np.asarray(gba)[:, 1]), bits(ex._stack_a(ha2)))
 assert np.array_equal(bits(gbb), bits(np.stack([hb, hb])))
 c, c0 = plan.execute(av, bv), single.execute(av, bv)

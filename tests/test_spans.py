@@ -24,6 +24,7 @@ sys.path.insert(0, ROOT)
 from bench import spans, trace  # noqa: E402
 from bench.matrices import hpcg27  # noqa: E402
 from repro.core import perfmodel  # noqa: E402
+from repro.sparse.convert import to_bcsr, to_bcsv  # noqa: E402
 from repro.sparse.formats import COO  # noqa: E402
 from repro.spgemm import PlanCache, spgemm_plan  # noqa: E402
 from repro.spgemm.executor import (  # noqa: E402
@@ -91,41 +92,69 @@ def test_execute_spans_nest_and_share_the_step(plan, tmp_path):
     assert {sp[3]["step"] for sp in found} == {step}
     top = _one(found, "spgemm.execute")
     assert all(_inside(sp, top) for sp in found)
-    rebinds = [sp for sp in found if sp[0] == "spgemm.rebind"]
-    slots = [int(np.prod(plan._a_shape)), int(np.prod(plan._b_shape))]
-    assert [(sp[3]["operand"], sp[3]["values"], sp[3]["slots"]) for sp in rebinds] == [
-        ("a", plan.report.nnz_a, slots[0]), ("b", plan.report.nnz_b, slots[1])]
+    # An element plan binds on the device alone: no host span scatters.
+    assert "spgemm.rebind" not in {sp[0] for sp in found}
     dispatch = _one(found, "spgemm.dispatch")
-    nnz = plan.report.nnz_a + plan.report.nnz_b
-    assert dispatch[3]["h2d_bytes"] == 4 * nnz
-    assert (dispatch[3]["bind_values"], dispatch[3]["bind_slots"]) == (nnz, sum(slots))
+    _check_sends_the_vectors(plan, dispatch)
     collect = _one(found, "spgemm.collect")
-    assert rebinds[-1][1] + rebinds[-1][2] <= dispatch[1]
     assert dispatch[1] + dispatch[2] <= collect[1]
     (d2h,) = _check_collect(found, collect)
     assert d2h[3]["d2h_bytes"] == 4 * plan.compact.nnz
 
 
-def test_block_staged_execute_has_no_host_rebind(plan, tmp_path):
-    found = _traced(tmp_path, plan.execute)
+def _check_sends_the_vectors(plan, dispatch):
+    """An element plan's dispatch sends both held ``[nnz]`` vectors and
+    counts the values the device binds and the block slots it fills."""
+    nnz = plan.report.nnz_a + plan.report.nnz_b
+    slots = int(np.prod(plan._a_shape)) + int(np.prod(plan._b_shape))
+    assert dispatch[3]["h2d_bytes"] == 4 * nnz
+    assert (dispatch[3]["bind_values"], dispatch[3]["bind_slots"]) == (nnz, slots)
+
+
+@pytest.fixture(scope="module")
+def block_plan(plan):
+    """A block plan (BCSV/BCSR in hand) of the element plan's pattern."""
+    a = COO(plan.a_pattern.row, plan.a_pattern.col, plan.a_pattern.val,
+            plan.a_pattern.shape)
+    return spgemm_plan(to_bcsv(a, (16, 16), 2), to_bcsr(a, (16, 16)),
+                       backend="pallas_interpret", output="compact",
+                       cache=PlanCache())
+
+
+@pytest.mark.parametrize("kind", ["element", "block"])
+def test_no_arg_execute_has_no_host_rebind(plan, block_plan, kind, tmp_path):
+    """A no-argument ``execute`` sends what the device lacks: an element
+    plan its held vectors, bound on the device; a block plan nothing, its
+    blocks staged by the call before the trace."""
+    p = plan if kind == "element" else block_plan
+    found = _traced(tmp_path, p.execute)
     assert "spgemm.rebind" not in {sp[0] for sp in found}
     dispatch = _one(found, "spgemm.dispatch")
-    assert dispatch[3]["bind_values"] == dispatch[3]["bind_slots"] == 0
-    assert dispatch[3]["h2d_bytes"] == 0  # staged by the call before the trace
-    assert {sp[3]["step"] for sp in found} == {plan.report.executes}
+    if kind == "element":
+        _check_sends_the_vectors(p, dispatch)
+    else:
+        assert dispatch[3]["bind_values"] == dispatch[3]["bind_slots"] == 0
+        assert dispatch[3]["h2d_bytes"] == 0
+    assert {sp[3]["step"] for sp in found} == {p.report.executes}
 
 
-def test_staged_blocks_count_in_the_dispatch(plan, tmp_path):
-    """Fresh A values alone rebind A on the host and stage its blocks
+@pytest.mark.parametrize("kind", ["element", "block"])
+def test_one_operand_execute_counts_what_it_sends(plan, block_plan, kind,
+                                                   tmp_path):
+    """Fresh A values alone: an element plan sends them with its held B
+    vector, both bound on the device; a block plan stages A's blocks
     inside ``spgemm.dispatch``, which counts their bytes."""
-    a_vals, _ = _values(plan, 4)
-    found = _traced(tmp_path, lambda: plan.execute(a_vals))
-    (rebind,) = [sp for sp in found if sp[0] == "spgemm.rebind"]
-    assert rebind[3]["operand"] == "a"
-    dispatch = _one(found, "spgemm.dispatch")
-    assert dispatch[3]["h2d_bytes"] == 4 * int(np.prod(plan._a_shape))
-    assert dispatch[3]["bind_values"] == dispatch[3]["bind_slots"] == 0
-    assert rebind[1] + rebind[2] <= dispatch[1]
+    if kind == "element":
+        a_vals, _ = _values(plan, 4)
+        found = _traced(tmp_path, lambda: plan.execute(a_vals))
+        _check_sends_the_vectors(plan, _one(found, "spgemm.dispatch"))
+    else:
+        a_blocks = block_plan._a_blocks * np.float32(2)
+        found = _traced(tmp_path, lambda: block_plan.execute(a_blocks))
+        dispatch = _one(found, "spgemm.dispatch")
+        assert dispatch[3]["h2d_bytes"] == 4 * int(np.prod(block_plan._a_shape))
+        assert dispatch[3]["bind_values"] == dispatch[3]["bind_slots"] == 0
+    assert "spgemm.rebind" not in {sp[0] for sp in found}
 
 
 def test_sharded_execute_runs_in_one_span(tmp_path):
@@ -269,9 +298,10 @@ def test_spans_reduce_to_the_layer_metrics(plan, tmp_path):
     events["devices"] = {"/device:TPU:0": []}
     events["scopes"] = {"/device:TPU:0": []}
     red = spans.reduce(events)
-    for name in ("spgemm.execute", "spgemm.rebind", "spgemm.dispatch",
+    for name in ("spgemm.execute", "spgemm.dispatch",
                  "spgemm.collect", "spgemm.wait", "spgemm.d2h"):
         assert red["span_s"][name] > 0
+    assert "spgemm.rebind" not in red["span_s"]
     args = red["span_args"]["spgemm.dispatch"]
     assert 0 < args["bind_values"] < args["bind_slots"]
     assert red["scope_s"] == {}

@@ -507,3 +507,116 @@ class TestPatternToken:
         assert len(cache) == 1  # fresh survived
         assert spgemm_plan(a, b, tile=8, group=2, backend="jnp",
                            cache=cache) is fresh
+
+
+def _bits(x) -> np.ndarray:
+    """Bit pattern of a float32 array: equality here is bitwise."""
+    return np.ascontiguousarray(np.asarray(x, np.float32)).view(np.uint32)
+
+
+def _canonical_pair(seed):
+    """A (40 x 32) and B (32 x 24) in canonical order, so the value
+    vectors built here line up with the plan's pattern; tile 8 divides
+    every side, so a block plan of the pattern pads nothing."""
+    return (_int_coo(40, 32, 0.12, seed).sum_duplicates(),
+            _int_coo(32, 24, 0.12, seed + 1).sum_duplicates())
+
+
+class TestHeldValues:
+    """An element plan holds its operand values once, as its own copy of
+    the ``[nnz]`` vectors, and every numeric call binds them on the
+    device: single and sharded plans, on both CPU backends."""
+
+    @staticmethod
+    def _plan(a, b, backend, sharded, cache, **kw):
+        from repro.launch.mesh import make_shard_mesh
+
+        return spgemm_plan(a, b, tile=8, group=2, backend=backend,
+                           cache=cache,
+                           mesh=make_shard_mesh(1) if sharded else None, **kw)
+
+    @pytest.mark.parametrize(
+        "after", ["values", "a_only", "cache_hit", "store_load",
+                  "release_device"])
+    @pytest.mark.parametrize("sharded", [False, True],
+                             ids=["single", "sharded"])
+    @pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
+    def test_no_arg_call_equals_the_explicit_call(self, backend, sharded,
+                                                  after, tmp_path):
+        """A no-argument ``execute()`` or ``submit()`` runs the values the
+        last call, cache hit or store load left, bit for bit as the
+        explicit call with them."""
+        a, b = _canonical_pair(3)
+        rng = np.random.default_rng(5)
+        av = rng.standard_normal(a.nnz).astype(np.float32)
+        bv = rng.standard_normal(b.nnz).astype(np.float32)
+        disk = {"disk_dir": str(tmp_path)} if after == "store_load" else {}
+        cache = PlanCache(**disk)
+        plan = self._plan(a, b, backend, sharded, cache)
+        if after == "values":
+            plan.execute(av, bv)
+        elif after == "a_only":
+            plan.execute(av)
+            bv = b.val
+        elif after == "release_device":
+            plan.execute(av, bv)
+            plan.release_device_values()
+        else:  # the same pattern, with this call's values
+            if after == "store_load":
+                cache = PlanCache(**disk)
+            plan = self._plan(COO(a.row, a.col, av, a.shape),
+                              COO(b.row, b.col, bv, b.shape),
+                              backend, sharded, cache)
+            assert (plan.report.loads, plan.report.cache_hits) == (
+                (1, 0) if after == "store_load" else (0, 1))
+        got = [plan.execute(), plan.execute_async().result()]
+        want = plan.execute(av, bv)
+        for c in got:
+            assert np.array_equal(c.indptr, want.indptr)
+            assert np.array_equal(_bits(c.data), _bits(want.data))
+
+    @pytest.mark.parametrize("sharded", [False, True],
+                             ids=["single", "sharded"])
+    @pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
+    def test_caller_buffers_do_not_reach_the_held_values(self, backend,
+                                                         sharded):
+        """A caller that reuses its value buffers after ``execute``, or
+        after a token hit that took its canonical COO as it is, changes
+        no later no-argument call."""
+        a, b = _canonical_pair(7)
+        cache = PlanCache()
+        plan = self._plan(a, b, backend, sharded, cache, pattern_token="t")
+        av, bv = a.val * np.float32(2), b.val * np.float32(3)
+        want = plan.execute(av, bv)
+        av[:] = 0
+        bv[:] = 0
+        assert np.array_equal(_bits(plan.execute().data), _bits(want.data))
+        a2 = COO(a.row, a.col, a.val * np.float32(5), a.shape)
+        b2 = COO(b.row, b.col, b.val * np.float32(7), b.shape)
+        assert self._plan(a2, b2, backend, sharded, cache,
+                          pattern_token="t") is plan
+        want = plan.execute(a2.val.copy(), b2.val.copy())
+        a2.val[:] = 0
+        b2.val[:] = 0
+        assert np.array_equal(_bits(plan.execute().data), _bits(want.data))
+
+    @pytest.mark.parametrize("sharded", [False, True],
+                             ids=["single", "sharded"])
+    @pytest.mark.parametrize("backend", ["jnp", "pallas_interpret"])
+    def test_element_plan_holds_no_block_arrays(self, backend, sharded):
+        """Against a block plan of the same pattern, an element plan's
+        ``host_nbytes()`` is less the packed blocks and more only its
+        vectors, scatter maps and patterns."""
+        a, b = _canonical_pair(11)
+        elem = self._plan(a, b, backend, sharded, PlanCache())
+        blk = self._plan(to_bcsv(a, (8, 8), 2), to_bcsr(b, (8, 8)),
+                         backend, sharded, PlanCache())
+        assert elem._a_blocks is None and elem._b_blocks is None
+        assert blk._a_vals is None and blk._b_vals is None
+        blocks = blk._a_blocks.nbytes + blk._b_blocks.nbytes
+        held = [elem._a_vals, elem._b_vals, elem._a_scatter, elem._b_scatter]
+        held += [x for p in (elem.a_pattern, elem.b_pattern)
+                 for x in (p.row, p.col, p.val)]
+        assert elem.host_nbytes() == (
+            blk.host_nbytes() - blocks + sum(x.nbytes for x in held))
+        assert blocks > sum(x.nbytes for x in held[:2])
